@@ -107,11 +107,7 @@ def run(scale: str = "quick", seed: int = 0, probe_grid=(3, 6, 9, 12, 18),
     ids_ref = np.stack([r.doc_ids for r in ref_resp])
     for name in backends:
         reqs = _mlt_requests(qids, spec, probes=mid, backend=name)
-        try:
-            t_b, responses = timed(lambda r=reqs: retriever.search(r))
-        except Exception as e:
-            print(f"# {name} skipped: {e}")
-            continue
+        t_b, responses = timed(lambda r=reqs: retriever.search(r))
         ids_b = np.stack([r.doc_ids for r in responses])
         dc = float(np.mean([r.n_scored for r in responses]))
         match = bool(np.array_equal(ids_b, ids_ref))

@@ -156,11 +156,7 @@ def run_engines():
     print("backend,ms_per_query,matches_reference,n_scored_mean")
     ref = get_engine(idx, "reference").search(qw, probes=9, k=10, exclude=ex)
     for name in available_backends():
-        try:
-            eng = get_engine(idx, name)
-        except Exception as e:  # backend unavailable on this host
-            print(f"# {name} skipped: {e}")
-            continue
+        eng = get_engine(idx, name)
         t, (s, i, ns) = timed(
             lambda e=eng: e.search(qw, probes=9, k=10, exclude=ex)
         )

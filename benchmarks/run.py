@@ -35,6 +35,9 @@ def _persist(path: Path, payload: dict) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     scale = "quick"
     if "--scale" in sys.argv:
         scale = sys.argv[sys.argv.index("--scale") + 1]

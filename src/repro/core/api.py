@@ -505,7 +505,8 @@ def _decompose(docs, qw, ids, *, spec: FieldSpec):
     safe = jnp.where(ids >= 0, ids, 0)
     hitvecs = docs[safe]                                 # (nq, k, D)
     parts = [
-        jnp.einsum("qkd,qd->qk", hitvecs[..., sl], qw[..., sl])
+        jnp.einsum("qkd,qd->qk", hitvecs[..., sl], qw[..., sl],
+                   precision=jax.lax.Precision.HIGHEST)
         for sl in spec.slices()
     ]
     out = jnp.stack(parts, axis=-1)                      # (nq, k, s)

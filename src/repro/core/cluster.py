@@ -163,7 +163,7 @@ def fpf_centers(x: jnp.ndarray, k: int, key: jax.Array) -> jnp.ndarray:
     def body(i, carry):
         idxs, maxsim = carry
         cvec = x[idxs[i - 1]]
-        sim = x @ cvec
+        sim = jnp.dot(x, cvec, precision=jax.lax.Precision.HIGHEST)
         maxsim = jnp.maximum(maxsim, sim)
         nxt = jnp.argmin(maxsim).astype(jnp.int32)
         return idxs.at[i].set(nxt), maxsim
@@ -359,21 +359,17 @@ class FusedFPFClusterer(FPFClusterer):
         sample_size: int | None = None,
         refine_iters: int = 1,
         chunk: int = 16384,
-        block_m: int = 1024,
         interpret: bool | None = None,
     ):
         super().__init__(
             sample_size=sample_size, refine_iters=refine_iters, chunk=chunk
         )
-        self.block_m = block_m
         self.interpret = interpret
 
     def _centers(self, xs, k, key):
         from ..kernels.fpf_iter import fpf_centers_fused
 
-        return fpf_centers_fused(
-            xs, k, key, block_m=self.block_m, interpret=self.interpret
-        )
+        return fpf_centers_fused(xs, k, key, interpret=self.interpret)
 
 
 @register_clusterer("kmeans")

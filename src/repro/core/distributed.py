@@ -52,7 +52,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = [
     "local_topk",
@@ -112,7 +111,7 @@ def _brute_local(docs_l, qw, exclude, offset, *, k, n_valid):
     """Score a local shard exhaustively and return its top-k (global ids)."""
     n_l = docs_l.shape[0]
     ids = offset + jnp.arange(n_l, dtype=jnp.int32)
-    s = qw @ docs_l.T                                    # (nq, n_l)
+    s = jnp.dot(qw, docs_l.T, precision=jax.lax.Precision.HIGHEST)  # (nq, n_l)
     s = jnp.where(ids[None, :] >= n_valid, -jnp.inf, s)  # sentinel pad rows
     s = jnp.where(ids[None, :] == exclude[:, None], -jnp.inf, s)
     return local_topk(s, jnp.broadcast_to(ids, s.shape), k)
@@ -155,12 +154,12 @@ def distributed_brute_topk(
         i_all = jnp.moveaxis(i_all, 0, -2)
         return merge_topk(s_all, i_all, k)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P(axes, None), P(None, None), P(None)),
         out_specs=(P(None, None), P(None, None)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)(docs, qw, exclude)
 
@@ -182,7 +181,8 @@ def _navigate(leaders, nav, probes_t):
     in as a replicated operand instead of being re-derived per shard.
     """
     k_clusters = leaders.shape[1]
-    lsims = jnp.einsum("tkd,qd->qtk", leaders, nav)
+    lsims = jnp.einsum("tkd,qd->qtk", leaders, nav,
+                       precision=jax.lax.Precision.HIGHEST)
     parts = []
     for t, p in enumerate(probes_t):
         if p == 0:
@@ -259,7 +259,8 @@ def distributed_index_search(
         safe = jnp.where(valid, cand, 0)
         cvec = docs_l[safe]                               # (nq, m|L, D)
         s = jnp.einsum(
-            "qmd,qd->qm", cvec, qw_r, preferred_element_type=jnp.float32
+            "qmd,qd->qm", cvec, qw_r, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )
         gids = jnp.where(valid, cand + offset, -1)
         s = jnp.where(valid, s, -jnp.inf)
@@ -284,12 +285,12 @@ def distributed_index_search(
     if two_stage:
         in_specs += [P(axes, None), P(None, None)]
         args += [docs_proj, qw_proj]
-    fn = shard_map(
+    fn = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(None, None), P(None, None)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)(*args)
 
@@ -322,7 +323,7 @@ def _bucket_score_fn(mesh, axes, k, k_out, n_local, interpret):
         i_all = jnp.moveaxis(jax.lax.all_gather(gi, axes, axis=0), 0, -2)
         return merge_topk(s_all, i_all, k_out)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(
@@ -330,7 +331,7 @@ def _bucket_score_fn(mesh, axes, k, k_out, n_local, interpret):
             P(None, None), P(None, None), P(None, None, None), P(None),
         ),
         out_specs=(P(None, None), P(None, None)),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -407,7 +408,8 @@ def _exact_rescore_fn(mesh, axes, k, n_local):
         safe = jnp.where(owned, loc, 0)
         cvecs = docs_l[safe]                             # (nq, R, D) local
         s = jnp.einsum(
-            "qrd,qd->qr", cvecs, qw_r, preferred_element_type=jnp.float32
+            "qrd,qd->qr", cvecs, qw_r, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )
         s = jnp.where(owned, s, -jnp.inf)
         # every candidate is owned by exactly one shard: a max all-reduce
@@ -419,12 +421,12 @@ def _exact_rescore_fn(mesh, axes, k, n_local):
         extra = jnp.sum(ids_r >= 0, axis=-1).astype(jnp.int32)
         return top_s, top_i, extra
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P(axes, None), P(None, None), P(None, None)),
         out_specs=(P(None, None), P(None, None), P(None)),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -493,6 +495,22 @@ def build_local_buckets(assign_global, n, n_shards, k_clusters):
     return out
 
 
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _pack_shard(docs_l, ids_l, *, dtype):
+    """One shard's slice of the pack: ``(n_local, D)`` rows + ``(T·K, B_l)``
+    local ids -> ``(1, T·K, B_l, D)`` data and ``(1, T·K)`` int8 scales |
+    None, with the shard axis already in place so the slice can join a
+    global array without a copy."""
+    from ..kernels.bucket_score.ops import quantize_bucket_major
+
+    data = docs_l[jnp.where(ids_l >= 0, ids_l, 0)][None]
+    if dtype == "int8":
+        return quantize_bucket_major(data)
+    if dtype is not None:
+        data = data.astype(jnp.dtype(dtype))
+    return data, None
+
+
 def pack_local_bucket_major(
     docs: jnp.ndarray,       # (n, D) fp32 corpus
     assign: np.ndarray,      # (T, n) global assignments (-1 = removed)
@@ -500,6 +518,7 @@ def pack_local_bucket_major(
     n_shards: int,
     *,
     dtype=None,
+    sharding: NamedSharding | None = None,
 ):
     """Shard-local bucket-major pack: the fused v2 layout, one slice per shard.
 
@@ -522,8 +541,13 @@ def pack_local_bucket_major(
     ``B_l`` is the max local bucket size over all shards (sublane-padded),
     typically ``~B / n_shards`` — a smaller per-shard block, which buys the
     fused kernel a LARGER query tile out of the same VMEM budget.
+
+    ``sharding`` (a ``NamedSharding`` of the 4-D data over the shard axes)
+    builds each shard's slice ON the device that holds it and returns
+    arrays already placed on the mesh, so no device ever holds more than
+    its own share of the pack. Without it the slices are stacked on the
+    default device.
     """
-    from ..kernels.bucket_score.ops import quantize_bucket_major
     from .index import validate_pack_dtype
 
     dtype = validate_pack_dtype(dtype)
@@ -534,16 +558,38 @@ def pack_local_bucket_major(
     a_pad = np.pad(assign, ((0, 0), (0, n_pad - n)), constant_values=-1)
     bl = build_local_buckets(a_pad, n_pad, n_shards, k_clusters)
     b_l = bl.shape[-1]
-    bk = jnp.asarray(bl.reshape(n_shards, t_cl * k_clusters, b_l))
-    ids = jnp.where(bk < n_local, bk, -1).astype(jnp.int32)
-    docs_sh = jnp.pad(docs, ((0, n_pad - n), (0, 0))).reshape(
-        n_shards, n_local, -1
-    )
-    safe = jnp.where(ids >= 0, ids, 0)
-    data = jax.vmap(lambda d, s: d[s])(docs_sh, safe)    # (S, T·K, B_l, D)
+    bk = bl.reshape(n_shards, t_cl * k_clusters, b_l)
+    ids = np.where(bk < n_local, bk, -1).astype(np.int32)
+    targets = [(s, None) for s in range(n_shards)]
+    if sharding is not None:   # (shard, device) for every device, replicas too
+        shape = (n_shards, t_cl * k_clusters, b_l, int(docs.shape[1]))
+        targets = [
+            (idx[0].start or 0, dev)
+            for dev, idx in sharding.devices_indices_map(shape).items()
+        ]
+    parts = []
+    for s, dev in targets:
+        rows = docs[s * n_local:(s + 1) * n_local]
+        if rows.shape[0] < n_local:               # sentinel pad rows
+            rows = jnp.pad(rows, ((0, n_local - rows.shape[0]), (0, 0)))
+        ids_s = ids[s]
+        if dev is not None:
+            rows, ids_s = jax.device_put((rows, ids_s), dev)
+        parts.append(_pack_shard(rows, jnp.asarray(ids_s), dtype=dtype))
     scales = None
+    if sharding is None:
+        data = jnp.concatenate([d for d, _ in parts])
+        if dtype == "int8":
+            scales = jnp.concatenate([sc for _, sc in parts])
+        return data, jnp.asarray(ids), scales, n_local
+    mesh, spec = sharding.mesh, sharding.spec
+    data = jax.make_array_from_single_device_arrays(
+        (n_shards, *parts[0][0].shape[1:]), sharding, [d for d, _ in parts]
+    )
     if dtype == "int8":
-        data, scales = quantize_bucket_major(data)       # scales (S, T·K)
-    elif dtype is not None:
-        data = data.astype(jnp.dtype(dtype))
+        scales = jax.make_array_from_single_device_arrays(
+            (n_shards, t_cl * k_clusters),
+            NamedSharding(mesh, P(spec[0], None)), [sc for _, sc in parts],
+        )
+    ids = jax.device_put(ids, NamedSharding(mesh, P(spec[0], None, None)))
     return data, ids, scales, n_local

@@ -306,7 +306,8 @@ class _EngineBase:
         """Navigate: (nq, P) flattened (t*K + cluster) probe list."""
         leaders = self.index.leaders                       # (T, K, D)
         k_clusters = leaders.shape[1]
-        lsims = jnp.einsum("tkd,qd->qtk", leaders, nav)
+        lsims = jnp.einsum("tkd,qd->qtk", leaders, nav,
+                           precision=jax.lax.Precision.HIGHEST)
         parts = []
         for t, p in enumerate(probes_t):
             if p == 0:
@@ -473,7 +474,8 @@ def _exact_rescore(docs, qw, ids, k):
     safe = jnp.where(valid, ids, 0)
     cvecs = docs[safe]                                   # (nq, R, D)
     s = jnp.einsum(
-        "qrd,qd->qr", cvecs, qw, preferred_element_type=jnp.float32
+        "qrd,qd->qr", cvecs, qw, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
     s = jnp.where(valid, s, -jnp.inf)
     top_s, pos = jax.lax.top_k(s, k)
@@ -542,7 +544,8 @@ def _search_block(
 ):
     """One query block: probe -> gather buckets -> score union -> dedup top-k."""
     n = docs.shape[0]
-    lsims = jnp.einsum("tkd,qd->qtk", leaders, nav)  # (bq, T, K)
+    lsims = jnp.einsum("tkd,qd->qtk", leaders, nav,
+                       precision=jax.lax.Precision.HIGHEST)  # (bq, T, K)
 
     cand_parts = []
     for t, p in enumerate(probes_t):
@@ -555,7 +558,8 @@ def _search_block(
     valid = cand < n
     safe = jnp.where(valid, cand, 0)
     cvecs = docs[safe]                                        # (bq, m, D)
-    scores = jnp.einsum("qmd,qd->qm", cvecs, qw)
+    scores = jnp.einsum("qmd,qd->qm", cvecs, qw,
+                        precision=jax.lax.Precision.HIGHEST)
     scores = jnp.where(valid, scores, -jnp.inf)
     scores = jnp.where(cand == exclude[:, None], -jnp.inf, scores)
 
@@ -607,9 +611,9 @@ class FusedEngine(_EngineBase):
     (:func:`~repro.kernels.bucket_score.ops.schedule_length`), so the hot
     path never synchronises the probe tensor HBM→host→HBM. Padded schedule
     slots all target bucket 0 with zero membership — consecutive equal
-    block indices, so the Pallas pipeline skips their repeat DMAs and the
-    dedup win survives the static upper bound. Runs interpreted off-TPU
-    (bit-compatible, slow — tests/CI only).
+    block indices, meant to let the Pallas pipeline skip their repeat DMAs
+    so the static upper bound costs no block reads (not yet measured).
+    Runs interpreted off-TPU (tests only).
     """
 
     uses_packed_storage = True
@@ -639,10 +643,11 @@ class FusedEngine(_EngineBase):
         from ..kernels.common import pad_to
 
         qw, nav, exclude, single = self._canonical(qw, nav_query, exclude)
-        # (T*K, B, D), (T*K, B), (T*K,) | None
+        # (T, K, B, D), (T*K, B), (T*K,) | None
         data, ids, scales = self.index.ensure_bucket_major()
         flat = self._flat_probes(nav, self._probes_t(probes))
-        n_buckets, b, d = (int(x) for x in data.shape)
+        n_buckets, b = (int(x) for x in ids.shape)
+        d = int(data.shape[-1])
         qt = self.query_tile
         if qt is None:
             # VMEM budget caps the tile (a reduced-precision pack shrinks
@@ -739,11 +744,13 @@ class ShardedEngine(_EngineBase):
 
         from .distributed import shard_docs
 
-        data, ids, scales, n_local = self.index.ensure_local_bucket_major(
-            self.n_shards
-        )
         mesh, axes = self.mesh, self.shard_axes
         sh = lambda *spec: NamedSharding(mesh, P(*spec))
+        # each device builds its own slice: the pack never sits whole on one
+        # device (the puts below are no-ops for a pack placed that way)
+        data, ids, scales, n_local = self.index.ensure_local_bucket_major(
+            self.n_shards, sharding=sh(axes, None, None, None)
+        )
         data = jax.device_put(data, sh(axes, None, None, None))
         ids = jax.device_put(ids, sh(axes, None, None))
         if scales is not None:

@@ -490,13 +490,16 @@ class ClusterPruneIndex:
     def ensure_bucket_major(
         self,
     ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray | None]:
-        """Bucket-major view for the fused backend: ``((T*K, B, D) data,
+        """Bucket-major view for the fused backend: ``((T, K, B, D) data,
         (T*K, B) ids with -1 padding, (T*K,) fp32 scales | None)``.
         Materialises the data tensor if the build deferred it — in
         ``pack_dtype`` storage precision when the index carries one (bf16
         halves the packed HBM bytes, int8 quarters them and fills the
-        per-bucket dequantisation scales) — and caches the flattened view so
-        the serving hot path pays no per-query layout work."""
+        per-bucket dequantisation scales) — and caches the flattened ids and
+        scales so the serving hot path pays no per-query layout work. The
+        data keeps its ``(T, K)`` axes: flattening it here would copy the
+        pack, and :func:`~repro.kernels.bucket_score.ops.bucket_score_tiled`
+        flattens it inside its jit for free."""
         cached = getattr(self, "_bucket_major_flat", None)
         if cached is not None:
             return cached
@@ -505,10 +508,10 @@ class ClusterPruneIndex:
             self.bucket_data, self.bucket_scales = pack_buckets_major(
                 self.docs, self.buckets, self.n_docs, dtype=self.pack_dtype
             )
-        t, k_clusters, b, d = self.bucket_data.shape
+        t, k_clusters, b, _ = self.bucket_data.shape
         ids = jnp.where(self.buckets < self.n_docs, self.buckets, -1)
         self._bucket_major_flat = (
-            self.bucket_data.reshape(t * k_clusters, b, d),
+            self.bucket_data,
             ids.reshape(t * k_clusters, b).astype(jnp.int32),
             (
                 None if self.bucket_scales is None
@@ -518,7 +521,7 @@ class ClusterPruneIndex:
         return self._bucket_major_flat
 
     def ensure_local_bucket_major(
-        self, n_shards: int
+        self, n_shards: int, *, sharding=None
     ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray | None, int]:
         """Shard-local bucket-major pack for the sharded-fused backend:
         ``((S, T*K, B_l, D) data, (S, T*K, B_l) LOCAL ids with -1 padding,
@@ -531,6 +534,8 @@ class ClusterPruneIndex:
         sharded-fused search — same coherence contract as
         :meth:`ensure_bucket_major`. Corpora whose size does not divide
         ``n_shards`` pad with sentinel rows no bucket references.
+        ``sharding`` places the pack on a mesh as it is built, one slice per
+        device (:func:`~repro.core.distributed.pack_local_bucket_major`).
         """
         from .distributed import pack_local_bucket_major
 
@@ -543,7 +548,7 @@ class ClusterPruneIndex:
         k_clusters = int(self.buckets.shape[1])
         cache[n_shards] = pack_local_bucket_major(
             self.docs, self.assignments(), k_clusters, n_shards,
-            dtype=self.pack_dtype,
+            dtype=self.pack_dtype, sharding=sharding,
         )
         return cache[n_shards]
 

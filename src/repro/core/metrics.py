@@ -56,7 +56,9 @@ def _exhaustive_topk(
         block = jax.lax.dynamic_slice_in_dim(docs_p, i * chunk, chunk, 0)
         mk = jax.lax.dynamic_slice_in_dim(mask_p, i * chunk, chunk, 0)
         ids = i * chunk + jnp.arange(chunk, dtype=jnp.int32)
-        s = sign * (qw @ block.T)                       # (nq, chunk)
+        s = sign * jnp.dot(
+            qw, block.T, precision=jax.lax.Precision.HIGHEST
+        )                                               # (nq, chunk)
         s = jnp.where((ids[None, :] < n) & mk[None, :], s, -jnp.inf)
         s = jnp.where(ids[None, :] == exclude[:, None], -jnp.inf, s)
         cat_s = jnp.concatenate([best_s, s], axis=-1)

@@ -22,12 +22,11 @@ Schedules come in two flavours with identical semantics:
     *bucketed static* schedule length ``S`` (:func:`schedule_length`, powers
     of two) — the serving path, so large-batch search never round-trips the
     probe tensor HBM→host→HBM. Padded slots all point at bucket 0 with zero
-    membership; because they are consecutive and equal, the Pallas pipeline
-    skips their repeat block fetches.
+    membership; they are consecutive and equal so that the Pallas pipeline
+    need not fetch their block again (its effect on time is not measured).
 
-``pick_query_tile`` sizes QT from the per-step VMEM working set
-``QT·D + B·D·(itemsize/4) + QT·B + 2·QT·k_pad`` fp32 words (the bucket block
-term shrinks with the pack dtype); ``pack_bucket_major`` materialises the
+``pick_query_tile`` sizes QT from the per-step VMEM bytes the TPU compiler
+counts (:func:`tile_vmem_bytes`); ``pack_bucket_major`` materialises the
 bucket-major tensor (optionally quantised / reduced precision).
 """
 
@@ -51,6 +50,7 @@ __all__ = [
     "build_probe_schedule_device",
     "schedule_length",
     "pick_query_tile",
+    "tile_vmem_bytes",
     "schedule_block_reads",
     "pack_bucket_major",
     "quantize_bucket_major",
@@ -120,7 +120,7 @@ def bucket_score(
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def bucket_score_tiled(
     queries: jnp.ndarray,        # (nq, D) fp32
-    bucket_data: jnp.ndarray,    # (K, B, D) bucket-major (fp32/bf16/int8)
+    bucket_data: jnp.ndarray,    # (..., B, D) bucket-major (fp32/bf16/int8)
     bucket_ids: jnp.ndarray,     # (K, B) int32, -1 padding
     schedule: jnp.ndarray,       # (n_tiles, S) int32 dedup'd bucket schedule
     member: jnp.ndarray,         # (n_tiles, S, QT) int32 membership mask
@@ -150,11 +150,17 @@ def bucket_score_tiled(
     Queries, exclude, and outputs are ragged-tail padded to ``n_tiles·QT``
     internally; the pad rows have an all-zero membership mask, so they score
     nothing and come back as ``(-inf, -1)`` before being sliced off.
+
+    ``bucket_data`` may keep leading axes (the index's ``(T, K, B, D)``
+    pack): they are flattened to ``K`` inside this jit, where the reshape is
+    free, rather than by the caller, where it would copy the whole pack.
     """
     if interpret is None:
         interpret = use_interpret()
     nq, d = queries.shape
-    n_clusters, b, _ = bucket_data.shape
+    b = bucket_data.shape[-2]
+    bucket_data = bucket_data.reshape(-1, b, d)
+    n_clusters = bucket_data.shape[0]
     n_tiles, s_len = schedule.shape
     qt = member.shape[-1]
     if n_tiles * qt < nq:
@@ -179,41 +185,78 @@ def bucket_score_tiled(
     s, i = pl.pallas_call(
         bucket_score_tiled_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((qt, d), lambda t, ss, sc: (t, 0)),
-                pl.BlockSpec((1, b, d), lambda t, ss, sc: (sc[t, ss], 0, 0)),
-                pl.BlockSpec((1, b), lambda t, ss, sc: (sc[t, ss], 0)),
-                pl.BlockSpec((1, 1), lambda t, ss, sc: (sc[t, ss], 0)),
-                pl.BlockSpec((1, 1, qt), lambda t, ss, sc: (t, ss, 0)),
-                pl.BlockSpec((qt, 1), lambda t, ss, sc: (t, 0)),
+                pl.BlockSpec((qt, d), lambda t, ss, sc, _: (t, 0)),
+                pl.BlockSpec(
+                    (1, b, d), lambda t, ss, sc, _: (sc[t, ss], 0, 0)
+                ),
+                pl.BlockSpec(
+                    (1, 1, b), lambda t, ss, sc, _: (sc[t, ss], 0, 0)
+                ),
+                pl.BlockSpec(
+                    (1, qt, 1), lambda t, ss, sc, _: (t * s_len + ss, 0, 0)
+                ),
+                pl.BlockSpec((qt, 1), lambda t, ss, sc, _: (t, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((qt, k_pad), lambda t, ss, sc: (t, 0)),
-                pl.BlockSpec((qt, k_pad), lambda t, ss, sc: (t, 0)),
+                pl.BlockSpec((qt, k_pad), lambda t, ss, sc, _: (t, 0)),
+                pl.BlockSpec((qt, k_pad), lambda t, ss, sc, _: (t, 0)),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((n_tiles * qt, k_pad), jnp.float32),
             jax.ShapeDtypeStruct((n_tiles * qt, k_pad), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=tile_vmem_bytes(
+                qt, d, b, k_pad, bucket_data.dtype.itemsize
+            ) + TILE_VMEM_HEADROOM,
+        ),
         interpret=interpret,
     )(
         schedule.astype(jnp.int32),
+        scales.astype(jnp.float32),
         qp,
         bucket_data,
-        bucket_ids.astype(jnp.int32),
-        scales.astype(jnp.float32)[:, None],
-        member.astype(jnp.int32),
+        bucket_ids.astype(jnp.int32)[:, None, :],
+        member.astype(jnp.int32).reshape(n_tiles * s_len, qt, 1),
         ep[:, None],
     )
     return s[:nq, :k], i[:nq, :k]
 
 
-# Per-step VMEM working set the tiled kernel may occupy (half of a 16 MB
-# VMEM core, leaving room for double-buffered DMA of the next bucket block).
-TILE_VMEM_BUDGET = 8 * 2**20
+# VMEM one grid step of the tiled kernel may claim, as counted by
+# :func:`tile_vmem_bytes`: a quarter of a v5e core's 128 MiB of VMEM.
+TILE_VMEM_BUDGET = 32 * 2**20
+
+# What the tiled kernel's scoped-VMEM limit adds to :func:`tile_vmem_bytes`.
+TILE_VMEM_HEADROOM = 2 * 2**20
+
+
+def tile_vmem_bytes(
+    qt: int, d: int, b: int, k_pad: int, pack_itemsize: int = 4
+) -> int:
+    """VMEM bytes one step of the tiled kernel needs, counted the way the
+    TPU compiler counts them against the scoped-VMEM limit:
+
+    - the ``(B, D)`` bucket block in the pack dtype, double-buffered by the
+      pipeline;
+    - the ``(QT, D)`` fp32 query tile;
+    - the ``(QT, B)`` score block, the ``(QT, k_pad)`` accumulators and the
+      merge's temporaries, four fp32 words per element;
+    - for an fp32 pack, the ``Precision.HIGHEST`` matmul's temporaries:
+      the query tile split into bf16 parts, and a fixed MiB.
+
+    Fitted to the smallest limits an ahead-of-time compile for v5e at
+    D=4096 accepts, over B in {312, 568}, QT in {8, 128} and every pack
+    dtype; ``tests/test_tpu_compile.py`` compiles at
+    :func:`pick_query_tile`'s choice."""
+    n = 2 * b * d * pack_itemsize + 4 * qt * d + 16 * qt * (b + k_pad)
+    if pack_itemsize == 4:
+        n += 13 * qt * d + 2**20
+    return n
 
 
 def pick_query_tile(
@@ -227,17 +270,16 @@ def pick_query_tile(
 ) -> int:
     """Size the query tile QT from the v2 kernel's VMEM working set.
 
-    One grid step holds ``QT·D`` query words, the bucket block
-    (``B·D·pack_itemsize`` bytes — a bf16 pack halves it, int8 quarters it,
-    so reduced-precision storage buys a LARGER tile at the same budget), the
-    ``(QT, B)`` score tile and two ``(QT, k_pad)`` accumulators (fp32
-    words): solve ``QT·D + B·D·itemsize/4 + QT·B + 2·QT·k_pad <= budget/4``
-    for QT, then clamp to ``[8, max_tile]`` and round down to a sublane
-    multiple of 8. A bucket block larger than the whole budget still yields
-    the minimum tile (the kernel remains correct; residency just degrades).
+    Solves :func:`tile_vmem_bytes` ``<= budget_bytes`` for QT, then clamps
+    to ``[8, max_tile]`` and rounds down to a sublane multiple of 8. A
+    reduced-precision pack shrinks the bucket block and so buys a LARGER
+    tile at the same budget. A bucket block larger than the whole budget
+    still yields the minimum tile; the kernel's scoped-VMEM limit follows
+    the tile either way.
     """
-    free = budget_bytes // 4 - (b * d * pack_itemsize) // 4
-    per_query = d + b + 2 * k_pad
+    fixed = tile_vmem_bytes(0, d, b, k_pad, pack_itemsize)
+    per_query = tile_vmem_bytes(1, d, b, k_pad, pack_itemsize) - fixed
+    free = budget_bytes - fixed
     qt = free // per_query if free > 0 else 0
     qt = max(8, min(max_tile, (qt // 8) * 8))
     return int(qt)
@@ -323,8 +365,8 @@ def build_probe_schedule_device(
     (power-of-two bucket of ``min(QT·P, n_buckets)``, an upper bound on any
     tile's unique count, so the scatter can never overflow). Unused slots
     keep bucket 0 with zero membership, exactly like the host builder —
-    being consecutive and equal, their repeat block fetches are skipped by
-    the Pallas pipeline.
+    consecutive and equal, so that the Pallas pipeline need not fetch
+    their block again.
     """
     nq, p = probes.shape
     qt = int(query_tile)

@@ -42,17 +42,20 @@ def fpf_iter_kernel(
         run_val[0] = jnp.inf
         run_idx[0] = -1
 
-    sim = jnp.dot(
-        x_ref[...], c_ref[...].T, preferred_element_type=jnp.float32
-    )                                                  # (TM, 1)
+    sim = jax.lax.dot_general(                         # (TM, 1) = x · cᵀ
+        x_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
     new_ms = jnp.maximum(ms_ref[...], sim)
     out_ms[...] = new_ms
 
-    # Tile-local argmin of max-similarity == furthest point in this tile.
+    # Tile-local argmin of max-similarity == furthest point in this tile:
+    # a masked min over the index iota, so a tie goes to the lowest index.
     ids = i * block_m + jax.lax.broadcasted_iota(jnp.int32, new_ms.shape, 0)
     masked = jnp.where(ids < m_points, new_ms, jnp.inf)   # padding mask
     tile_min = jnp.min(masked)
-    tile_arg = ids[jnp.argmin(masked[:, 0]), 0]
+    tile_arg = jnp.min(jnp.where(masked == tile_min, ids, m_points))
 
     better = tile_min < run_val[0]
     run_val[0] = jnp.where(better, tile_min, run_val[0])
@@ -60,5 +63,5 @@ def fpf_iter_kernel(
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _fin():
-        out_idx[0, 0] = run_idx[0]
-        out_val[0, 0] = run_val[0]
+        out_idx[...] = jnp.full(out_idx.shape, run_idx[0], jnp.int32)
+        out_val[...] = jnp.full(out_val.shape, run_val[0], jnp.float32)

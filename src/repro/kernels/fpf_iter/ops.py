@@ -12,7 +12,20 @@ from jax.experimental.pallas import tpu as pltpu
 from ..common import pad_to, use_interpret
 from .kernel import fpf_iter_kernel
 
-__all__ = ["fpf_iter", "fpf_centers_fused"]
+__all__ = ["fpf_iter", "fpf_centers_fused", "pick_block_m"]
+
+
+# VMEM the double-buffered (TM, D) point tile may take: half of the 16 MiB
+# scoped VMEM default, leaving the rest to the (TM, 1) columns.
+FPF_TILE_VMEM_BYTES = 8 * 2**20
+
+
+def pick_block_m(d: int, *, max_rows: int = 1024) -> int:
+    """Rows per FPF tile: the most (a multiple of 8, at most ``max_rows``)
+    whose double-buffered fp32 ``(TM, D)`` tile fits
+    :data:`FPF_TILE_VMEM_BYTES`."""
+    rows = FPF_TILE_VMEM_BYTES // (2 * 4 * int(d))
+    return int(max(8, min(max_rows, rows // 8 * 8)))
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
@@ -21,13 +34,17 @@ def fpf_iter(
     center: jnp.ndarray,   # (D,)
     maxsim: jnp.ndarray,   # (m,)
     *,
-    block_m: int = 1024,
+    block_m: int | None = None,
     interpret: bool | None = None,
 ):
-    """One fused FPF round. Returns ``(new_maxsim (m,), next_idx, next_val)``."""
+    """One fused FPF round. Returns ``(new_maxsim (m,), next_idx, next_val)``.
+
+    ``block_m`` defaults to :func:`pick_block_m` of the point width."""
     if interpret is None:
         interpret = use_interpret()
     m, d = x.shape
+    if block_m is None:
+        block_m = pick_block_m(d)
     block_m = min(block_m, pad_to(m, 8))
     m_p = pad_to(m, block_m)
     x_p = jnp.pad(x, ((0, m_p - m), (0, 0)))
@@ -61,8 +78,7 @@ def fpf_iter(
 
 
 def fpf_centers_fused(
-    x: jnp.ndarray, k: int, key: jax.Array, *, block_m: int = 1024,
-    interpret: bool | None = None,
+    x: jnp.ndarray, k: int, key: jax.Array, *, interpret: bool | None = None,
 ):
     """Full Gonzalez FPF on the fused round kernel (drop-in for
     :func:`repro.core.cluster.fpf_centers` — the ``fpf_fused`` registered
@@ -73,9 +89,7 @@ def fpf_centers_fused(
     maxsim = jnp.full((m,), -jnp.inf, jnp.float32)
     cur = first
     for _ in range(k - 1):
-        maxsim, nxt, _ = fpf_iter(
-            x, x[cur], maxsim, block_m=block_m, interpret=interpret
-        )
+        maxsim, nxt, _ = fpf_iter(x, x[cur], maxsim, interpret=interpret)
         idxs.append(nxt)
         cur = nxt
     return jnp.stack(idxs)

@@ -12,18 +12,12 @@ traffic), 'model' stays intra-pod (ICI).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 explicit-sharding API; older versions are Auto-only
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_single_device_mesh", "make_host_mesh"]
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

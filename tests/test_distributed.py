@@ -71,8 +71,7 @@ def step(p, t):
     return tf.loss_fn(p, t, t, cfg, use)[0]
 shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), rules,
                          is_leaf=lambda x: isinstance(x, P))
-mesh_ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-with mesh_ctx:
+with jax.set_mesh(mesh):
     c = jax.jit(step, in_shardings=(shardings, NamedSharding(mesh, P(("pod", "data"), None)))).lower(specs, toks).compile()
 assert c.cost_analysis() is not None
 print("DISTRIBUTED_OK")

@@ -303,7 +303,7 @@ def test_int8_pack_quarters_bucket_major_bytes(built_index, int8_index):
     assert d8.nbytes * 4 == d32.nbytes
     assert np.array_equal(np.asarray(i8), np.asarray(i32))
     assert sc32 is None
-    assert sc8 is not None and sc8.shape == (d8.shape[0],)
+    assert sc8 is not None and sc8.shape == (i8.shape[0],)
     assert np.all(np.asarray(sc8) > 0)
 
 
@@ -433,7 +433,7 @@ def test_device_schedule_matches_host_schedule_end_to_end(
     data, ids, scales = built_index.ensure_bucket_major()
     flat = eng._flat_probes(qw, eng._probes_t(6))
     hs, hm = build_probe_schedule(np.asarray(flat), QT)
-    s_len = schedule_length(QT, int(flat.shape[1]), int(data.shape[0]))
+    s_len = schedule_length(QT, int(flat.shape[1]), int(ids.shape[0]))
     ds, dm = build_probe_schedule_device(flat, query_tile=QT, s_len=s_len)
     host = bucket_score_tiled(qw, data, ids, jnp.asarray(hs),
                               jnp.asarray(hm), k=10, exclude=ex,

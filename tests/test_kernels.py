@@ -298,6 +298,39 @@ def test_bucket_score_dedups_across_clusterings():
     assert sorted(live) == [3, 7]
 
 
+def test_bucket_score_tiled_ties_keep_accumulator_then_lane_order():
+    """Every candidate scores the same: the tiled kernel's merge must keep
+    the accumulator ahead of the block and the lower lane ahead within
+    each, drop the id already held (cross-clustering dup), and fill the
+    accumulator's last ``-inf`` slot from the block."""
+    D = 16
+    doc = jnp.ones((1, D)) / jnp.sqrt(D)
+    bd = jnp.tile(doc, (3, 4, 1))                  # 3 buckets, equal vectors
+    bi = jnp.asarray(
+        [[10, 11, 12, 13], [20, 11, 22, 23], [30, 31, 32, 33]], jnp.int32
+    )
+    sched, member = build_probe_schedule(np.asarray([[0, 1, 2]]), 8)
+    s, i = bucket_score_tiled(
+        doc, bd, bi, jnp.asarray(sched), jnp.asarray(member), k=8
+    )
+    assert np.asarray(i)[0].tolist() == [10, 11, 12, 13, 20, 22, 23, 30]
+    assert np.all(np.asarray(s) == np.asarray(s)[0, 0])
+
+
+@pytest.mark.parametrize("tied", [(70, 100), (70, 100, 150), (130, 20)])
+def test_fpf_iter_tie_takes_lowest_index(tied):
+    """Points equally far from the center set, inside one tile and across
+    tiles: the next center is the lowest index, as ``jnp.argmin`` picks."""
+    m, d = 200, 16
+    x = jax.random.normal(jax.random.PRNGKey(1), (m, d))
+    x = x / jnp.linalg.norm(x, axis=1, keepdims=True)
+    x = x.at[jnp.asarray(tied)].set(-x[0])         # the farthest points
+    ms = jnp.full((m,), -jnp.inf)
+    _, idx, _ = fpf_iter(x, x[0], ms, block_m=64)
+    _, ridx, _ = fpf_iter_ref(x, x[0], ms)
+    assert int(idx) == int(ridx) == min(tied)
+
+
 @pytest.mark.parametrize("m,d", [(64, 16), (200, 32), (1000, 128)])
 def test_fpf_iter_sweep(m, d):
     x = jax.random.normal(jax.random.PRNGKey(m), (m, d))
