@@ -1,0 +1,18 @@
+"""Host milliseconds per dispatch in request planning (``core/api.py``):
+the seconds of the program's ``repro.search.prepare`` spans that start in
+the window, over the window's dispatches. None where the trace holds none
+of them (a program without spans)."""
+
+import numpy as np
+
+SPANS = ("repro.search.prepare",)
+
+
+def read(r):
+    names, starts, ends = r.trace.host
+    t0, t1 = r.window_ns
+    sel = np.asarray([n.split(": ", 1)[-1] in SPANS for n in names], bool)
+    sel &= (starts >= t0) & (starts < t1)
+    if not sel.any() or r.dispatches <= 0:
+        return None
+    return float(np.sum(ends[sel] - starts[sel])) / 1e6 / r.dispatches

@@ -62,6 +62,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
+from ..tracing import span
 from .fields import FieldSpec, normalize_fields
 from .index import ClusterPruneIndex
 from .weights import validate_weights, weighted_query
@@ -851,159 +853,177 @@ class Retriever:
 
         if not reqs:
             return []
-        self._sync_version()
-        index, spec = self.index, self.spec
+        with span(tracing.SEARCH_BATCH, n=len(reqs)) as batch_span:
+            with span(tracing.SEARCH_PREPARE):
+                self._sync_version()
+                index, spec = self.index, self.spec
 
-        # Whole-response memoisation: a byte-identical repeat of a cacheable
-        # (more-like-this) request is answered without touching the engine.
-        # Cached responses keep their original latency/batch stats — they
-        # describe the engine call that produced the answer.
-        keys = [self._request_key(r) for r in reqs]
-        out: list[SearchResponse | None] = [
-            self._response_cache.get(key) if key is not None else None
-            for key in keys
-        ]
-        miss = [i for i, resp in enumerate(out) if resp is None]
-        if not miss:
-            return out  # type: ignore[return-value]
-        mreqs = [reqs[i] for i in miss]
+                # Whole-response memoisation: a byte-identical repeat of a
+                # cacheable (more-like-this) request is answered without
+                # touching the engine. Cached responses keep their original
+                # latency/batch stats — they describe the engine call that
+                # produced the answer.
+                keys = [self._request_key(r) for r in reqs]
+                out: list[SearchResponse | None] = [
+                    self._response_cache.get(key) if key is not None else None
+                    for key in keys
+                ]
+                miss = [i for i, resp in enumerate(out) if resp is None]
+                if not miss:
+                    return out  # type: ignore[return-value]
+                mreqs = [reqs[i] for i in miss]
 
-        # Resolve the misses up front (vectorised where it matters): the
-        # (like, weights) -> qw §4 reduction is memoised per pair — repeat
-        # users cost one cache probe — and the remainder resolve in ONE
-        # corpus gather (all-MLT fast path) + ONE weighted_query call.
-        qkeys = [
-            (int(r.like), self._weights_key(r.weights))
-            if r.like is not None else None
-            for r in mreqs
-        ]
-        rows_qw: list[jnp.ndarray | None] = [
-            self._qw_cache.get(qk) if qk is not None else None for qk in qkeys
-        ]
-        todo = [j for j, row in enumerate(rows_qw) if row is None]
-        if todo:
-            treqs = [mreqs[j] for j in todo]
-            if all(r.like is not None for r in treqs):
-                likes = [int(r.like) for r in treqs]
-                bad = [l for l in likes if l >= index.n_docs]
-                if bad:
-                    raise ValueError(
-                        f"like={bad[0]} out of range for a corpus of "
-                        f"{index.n_docs} documents"
-                    )
-                removed = getattr(index, "removed", None)
-                if removed is not None:
-                    gone = [l for l in likes if bool(removed[l])]
-                    if gone:
-                        raise ValueError(
-                            f"like={gone[0]} refers to a removed document; "
-                            "more-like-this cannot seed from a tombstoned doc"
+                # Resolve the misses up front (vectorised where it matters):
+                # the (like, weights) -> qw §4 reduction is memoised per pair
+                # — repeat users cost one cache probe — and the remainder
+                # resolve in ONE corpus gather (all-MLT fast path) + ONE
+                # weighted_query call.
+                qkeys = [
+                    (int(r.like), self._weights_key(r.weights))
+                    if r.like is not None else None
+                    for r in mreqs
+                ]
+                rows_qw: list[jnp.ndarray | None] = [
+                    self._qw_cache.get(qk) if qk is not None else None
+                    for qk in qkeys
+                ]
+                todo = [j for j, row in enumerate(rows_qw) if row is None]
+                if todo:
+                    treqs = [mreqs[j] for j in todo]
+                    if all(r.like is not None for r in treqs):
+                        likes = [int(r.like) for r in treqs]
+                        bad = [l for l in likes if l >= index.n_docs]
+                        if bad:
+                            raise ValueError(
+                                f"like={bad[0]} out of range for a corpus of "
+                                f"{index.n_docs} documents"
+                            )
+                        removed = getattr(index, "removed", None)
+                        if removed is not None:
+                            gone = [l for l in likes if bool(removed[l])]
+                            if gone:
+                                raise ValueError(
+                                    f"like={gone[0]} refers to a removed "
+                                    "document; more-like-this cannot seed "
+                                    "from a tombstoned doc"
+                                )
+                        q_all = index.docs[jnp.asarray(likes)]
+                    else:
+                        q_all = jnp.stack(
+                            [r.resolve_query(index) for r in treqs]
                         )
-                q_all = index.docs[jnp.asarray(likes)]
-            else:
-                q_all = jnp.stack([r.resolve_query(index) for r in treqs])
-            w_rows = np.stack([r.resolve_weights(spec) for r in treqs])
-            qw_new = weighted_query(q_all, jnp.asarray(w_rows), spec)
-            for jj, j in enumerate(todo):
-                rows_qw[j] = qw_new[jj]
-                if qkeys[j] is not None:
-                    self._cache_put(
-                        self._qw_cache, self._QW_CACHE_MAX, qkeys[j],
-                        qw_new[jj],
-                    )
-        # cold batch (no qw-cache hits): qw_new already IS the batch tensor
-        qw_all = (
-            qw_new if todo and len(todo) == len(mreqs)
-            else jnp.stack(rows_qw)
-        )                                                 # (n_miss, D)
-        excl_all = np.asarray(
-            [r.resolve_exclude() for r in mreqs], np.int32
-        )
-        plans = [self._plan(r) for r in mreqs]
+                    w_rows = np.stack([r.resolve_weights(spec) for r in treqs])
+                    qw_new = weighted_query(q_all, jnp.asarray(w_rows), spec)
+                    for jj, j in enumerate(todo):
+                        rows_qw[j] = qw_new[jj]
+                        if qkeys[j] is not None:
+                            self._cache_put(
+                                self._qw_cache, self._QW_CACHE_MAX, qkeys[j],
+                                qw_new[jj],
+                            )
+                # cold batch (no qw-cache hits): qw_new already IS the batch
+                # tensor
+                qw_all = (
+                    qw_new if todo and len(todo) == len(mreqs)
+                    else jnp.stack(rows_qw)
+                )                                             # (n_miss, D)
+                excl_all = np.asarray(
+                    [r.resolve_exclude() for r in mreqs], np.int32
+                )
+                plans = [self._plan(r) for r in mreqs]
 
-        # Group by execution shape; each group is one engine call.
-        groups: dict[ExecShape, list[int]] = {}
-        for j, (shape, _) in enumerate(plans):
-            groups.setdefault(shape, []).append(j)
+                # Group by execution shape; each group is one engine call.
+                groups: dict[ExecShape, list[int]] = {}
+                for j, (shape, _) in enumerate(plans):
+                    groups.setdefault(shape, []).append(j)
+            batch_span.set_metadata(groups=len(groups))
 
-        for shape, rows in groups.items():
-            backend, probes, k, rescore = (
-                shape.backend, shape.probes, shape.k, shape.rescore,
-            )
-            opts = self.engine_opts if backend == self.backend else {}
-            engine = get_engine(index, backend, **opts)
-            qw = qw_all[jnp.asarray(rows)]
-            excl = jnp.asarray(excl_all[rows])
-            t0 = time.perf_counter()
-            tier, escalations, pred_served = "approx", 0, None
-            if shape.tier == "exact":
-                scores, ids, n_scored = engine.search_exact(
-                    qw, k=k, exclude=excl, rescore=rescore
-                )
-                tier, pred_served = "exact", 1.0
-            elif shape.tier == "escalate":
-                scores, ids, n_scored, info = engine.search_escalating(
-                    qw, probes=probes, k=k, min_recall=shape.min_recall,
-                    exclude=excl, rescore=rescore,
-                )
-                tier = info["tier"]
-                escalations = info["escalations"]
-                probes = info["probes"]
-                pred_served = info["predicted_recall"]
-            else:
-                scores, ids, n_scored = engine.search(
-                    qw, probes=probes, k=k, exclude=excl, rescore=rescore
-                )
-            jax.block_until_ready(scores)
-            fields = decompose_scores(qw, index.docs, ids, spec)
-            scores_np = np.asarray(scores, np.float32)
-            ids_np = np.asarray(ids, np.int32)
-            n_np = np.asarray(n_scored, np.int32)
-            fields_np = np.asarray(fields, np.float32)
-            # compute time covers everything the group's riders wait on:
-            # the engine call AND the shared decompose/host transfer.
-            dt = time.perf_counter() - t0
-            for jj, j in enumerate(rows):
-                hits = tuple(
-                    Hit(
-                        doc_id=int(ids_np[jj, c]),
-                        score=float(scores_np[jj, c]),
-                        field_scores={
-                            name: float(fields_np[jj, c, f])
-                            for f, name in enumerate(spec.names)
-                        },
+            for shape, rows in groups.items():
+                with span(tracing.SEARCH_PREPARE):
+                    backend, probes, k, rescore = (
+                        shape.backend, shape.probes, shape.k, shape.rescore,
                     )
-                    for c in range(k)
-                    if ids_np[jj, c] >= 0
-                )
-                resp = SearchResponse(
-                    hits=hits,
-                    doc_ids=ids_np[jj],
-                    scores=scores_np[jj],
-                    n_scored=int(n_np[jj]),
-                    latency_s=dt,
-                    backend=engine.name,
-                    probes=probes,
-                    batch_size=len(rows),
-                    predicted_recall=(
-                        pred_served if pred_served is not None
-                        else plans[j][1]
-                    ),
-                    queue_wait_s=0.0,
-                    compute_s=dt,
-                    tier=tier,
-                    escalations=escalations,
-                )
-                i = miss[j]
-                out[i] = resp
-                if keys[i] is not None:
-                    # the cached object is shared with every future repeat
-                    # caller: freeze its array views so an in-place edit by
-                    # one consumer cannot poison later cache hits
-                    resp.doc_ids.flags.writeable = False
-                    resp.scores.flags.writeable = False
-                    self._cache_put(
-                        self._response_cache, self._RESPONSE_CACHE_MAX,
-                        keys[i], resp,
-                    )
+                    opts = self.engine_opts if backend == self.backend else {}
+                    engine = get_engine(index, backend, **opts)
+                    qw = qw_all[jnp.asarray(rows)]
+                    excl = jnp.asarray(excl_all[rows])
+                t0 = time.perf_counter()
+                with span(tracing.SEARCH_ENGINE):
+                    tier, escalations, pred_served = "approx", 0, None
+                    if shape.tier == "exact":
+                        scores, ids, n_scored = engine.search_exact(
+                            qw, k=k, exclude=excl, rescore=rescore
+                        )
+                        tier, pred_served = "exact", 1.0
+                    elif shape.tier == "escalate":
+                        scores, ids, n_scored, info = engine.search_escalating(
+                            qw, probes=probes, k=k,
+                            min_recall=shape.min_recall, exclude=excl,
+                            rescore=rescore,
+                        )
+                        tier = info["tier"]
+                        escalations = info["escalations"]
+                        probes = info["probes"]
+                        pred_served = info["predicted_recall"]
+                    else:
+                        scores, ids, n_scored = engine.search(
+                            qw, probes=probes, k=k, exclude=excl,
+                            rescore=rescore,
+                        )
+                with span(tracing.SEARCH_WAIT):
+                    jax.block_until_ready(scores)
+                with span(tracing.SEARCH_FETCH):
+                    fields = decompose_scores(qw, index.docs, ids, spec)
+                    scores_np = np.asarray(scores, np.float32)
+                    ids_np = np.asarray(ids, np.int32)
+                    n_np = np.asarray(n_scored, np.int32)
+                    fields_np = np.asarray(fields, np.float32)
+                # compute time covers everything the group's riders wait on:
+                # the engine call AND the shared decompose/host transfer.
+                dt = time.perf_counter() - t0
+                with span(tracing.SEARCH_ASSEMBLE):
+                    for jj, j in enumerate(rows):
+                        hits = tuple(
+                            Hit(
+                                doc_id=int(ids_np[jj, c]),
+                                score=float(scores_np[jj, c]),
+                                field_scores={
+                                    name: float(fields_np[jj, c, f])
+                                    for f, name in enumerate(spec.names)
+                                },
+                            )
+                            for c in range(k)
+                            if ids_np[jj, c] >= 0
+                        )
+                        resp = SearchResponse(
+                            hits=hits,
+                            doc_ids=ids_np[jj],
+                            scores=scores_np[jj],
+                            n_scored=int(n_np[jj]),
+                            latency_s=dt,
+                            backend=engine.name,
+                            probes=probes,
+                            batch_size=len(rows),
+                            predicted_recall=(
+                                pred_served if pred_served is not None
+                                else plans[j][1]
+                            ),
+                            queue_wait_s=0.0,
+                            compute_s=dt,
+                            tier=tier,
+                            escalations=escalations,
+                        )
+                        i = miss[j]
+                        out[i] = resp
+                        if keys[i] is not None:
+                            # the cached object is shared with every future
+                            # repeat caller: freeze its array views so an
+                            # in-place edit by one consumer cannot poison
+                            # later cache hits
+                            resp.doc_ids.flags.writeable = False
+                            resp.scores.flags.writeable = False
+                            self._cache_put(
+                                self._response_cache,
+                                self._RESPONSE_CACHE_MAX, keys[i], resp,
+                            )
         return out  # type: ignore[return-value]

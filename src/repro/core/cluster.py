@@ -58,6 +58,9 @@ from typing import Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
+from ..tracing import span
+
 __all__ = [
     "ClusteringResult",
     "Clusterer",
@@ -328,19 +331,21 @@ class FPFClusterer(_ClustererBase):
         return fpf_centers(xs, k, key)
 
     def cluster(self, x, k, key) -> ClusteringResult:
-        n = x.shape[0]
-        sample_size = self.sample_size
-        if sample_size is None:
-            sample_size = int(jnp.ceil(jnp.sqrt(k * n)))
-        sample_size = max(min(sample_size, n), k)
-        skey, fkey = jax.random.split(key)
-        sample_idx = jax.random.permutation(skey, n)[:sample_size]
-        centers_in_sample = self._centers(x[sample_idx], k, fkey)
-        reps = x[sample_idx[centers_in_sample]]
-        return assign_refine(
-            x, k, reps, refine_iters=self.refine_iters, rep_update="medoid",
-            chunk=self.chunk,
-        )
+        with span(tracing.BUILD_FPF):
+            n = x.shape[0]
+            sample_size = self.sample_size
+            if sample_size is None:
+                sample_size = int(jnp.ceil(jnp.sqrt(k * n)))
+            sample_size = max(min(sample_size, n), k)
+            skey, fkey = jax.random.split(key)
+            sample_idx = jax.random.permutation(skey, n)[:sample_size]
+            centers_in_sample = self._centers(x[sample_idx], k, fkey)
+        with span(tracing.BUILD_ASSIGN):
+            reps = x[sample_idx[centers_in_sample]]
+            return assign_refine(
+                x, k, reps, refine_iters=self.refine_iters,
+                rep_update="medoid", chunk=self.chunk,
+            )
 
 
 @register_clusterer("fpf_fused")

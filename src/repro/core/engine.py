@@ -80,6 +80,8 @@ from typing import Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
+from ..tracing import span
 from .weights import weighted_query
 
 __all__ = [
@@ -449,8 +451,9 @@ class _EngineBase:
         s, ids, n_scored = self.search(
             qw2, probes=probes, k=rescore, exclude=exclude, nav_query=nav
         )
-        rs, ri, extra = self._rescore_candidates(qw2, ids, k)
-        return self._finish(single, rs, ri, n_scored + extra)
+        with span(tracing.ENGINE_RESCORE):
+            rs, ri, extra = self._rescore_candidates(qw2, ids, k)
+            return self._finish(single, rs, ri, n_scored + extra)
 
     def _rescore_candidates(self, qw, ids, k):
         """Exact fp32 re-rank of candidate ids — the rescore tail's scoring
@@ -645,33 +648,37 @@ class FusedEngine(_EngineBase):
         qw, nav, exclude, single = self._canonical(qw, nav_query, exclude)
         # (T, K, B, D), (T*K, B), (T*K,) | None
         data, ids, scales = self.index.ensure_bucket_major()
-        flat = self._flat_probes(nav, self._probes_t(probes))
-        n_buckets, b = (int(x) for x in ids.shape)
-        d = int(data.shape[-1])
-        qt = self.query_tile
-        if qt is None:
-            # VMEM budget caps the tile (a reduced-precision pack shrinks
-            # the bucket block and buys a larger tile); the batch floors it
-            # — a small batch padded to a large tile would matmul and top-k
-            # mostly dead rows per scheduled bucket.
-            qt = min(
-                pick_query_tile(
-                    d, b, k_pad=pad_to(k, 8),
-                    pack_itemsize=data.dtype.itemsize,
-                ),
-                pad_to(qw.shape[0], 8),
+        with span(tracing.ENGINE_NAVIGATE):
+            flat = self._flat_probes(nav, self._probes_t(probes))
+        with span(tracing.ENGINE_SCHEDULE):
+            n_buckets, b = (int(x) for x in ids.shape)
+            d = int(data.shape[-1])
+            qt = self.query_tile
+            if qt is None:
+                # VMEM budget caps the tile (a reduced-precision pack
+                # shrinks the bucket block and buys a larger tile); the
+                # batch floors it — a small batch padded to a large tile
+                # would matmul and top-k mostly dead rows per scheduled
+                # bucket.
+                qt = min(
+                    pick_query_tile(
+                        d, b, k_pad=pad_to(k, 8),
+                        pack_itemsize=data.dtype.itemsize,
+                    ),
+                    pad_to(qw.shape[0], 8),
+                )
+            # Jitted dedup with bucketed static S — no host numpy round-trip.
+            s_len = schedule_length(qt, int(flat.shape[1]), n_buckets)
+            sched, member = build_probe_schedule_device(
+                flat, query_tile=qt, s_len=s_len
             )
-        # Jitted dedup with bucketed static S — no host numpy round-trip.
-        s_len = schedule_length(qt, int(flat.shape[1]), n_buckets)
-        sched, member = build_probe_schedule_device(
-            flat, query_tile=qt, s_len=s_len
-        )
-        s, i = bucket_score_tiled(
-            qw, data, ids, sched, member,
-            k=k, exclude=exclude, scales=scales, interpret=self.interpret,
-        )
-        i = jnp.where(jnp.isfinite(s), i, -1)
-        return self._finish(single, s, i, self._n_scored(flat))
+        with span(tracing.ENGINE_SCORE):
+            s, i = bucket_score_tiled(
+                qw, data, ids, sched, member,
+                k=k, exclude=exclude, scales=scales, interpret=self.interpret,
+            )
+            i = jnp.where(jnp.isfinite(s), i, -1)
+            return self._finish(single, s, i, self._n_scored(flat))
 
 
 # -------------------------------------------------------------------- sharded
@@ -790,29 +797,32 @@ class ShardedEngine(_EngineBase):
         data, ids, scales, n_local = self._ensure_placed()
         # Navigate ONCE: the flat probe tensor feeds the (replicated)
         # schedule AND the n_scored accounting below.
-        flat = self._flat_probes(nav, self._probes_t(probes))
-        _, n_buckets, b_l, d = (int(x) for x in data.shape)
-        qt = self.query_tile
-        if qt is None:
-            qt = min(
-                pick_query_tile(
-                    d, b_l, k_pad=pad_to(k, 8),
-                    pack_itemsize=data.dtype.itemsize,
-                ),
-                pad_to(qw.shape[0], 8),
+        with span(tracing.ENGINE_NAVIGATE):
+            flat = self._flat_probes(nav, self._probes_t(probes))
+        with span(tracing.ENGINE_SCHEDULE):
+            _, n_buckets, b_l, d = (int(x) for x in data.shape)
+            qt = self.query_tile
+            if qt is None:
+                qt = min(
+                    pick_query_tile(
+                        d, b_l, k_pad=pad_to(k, 8),
+                        pack_itemsize=data.dtype.itemsize,
+                    ),
+                    pad_to(qw.shape[0], 8),
+                )
+            s_len = schedule_length(qt, int(flat.shape[1]), n_buckets)
+            sched, member = build_probe_schedule_device(
+                flat, query_tile=qt, s_len=s_len
             )
-        s_len = schedule_length(qt, int(flat.shape[1]), n_buckets)
-        sched, member = build_probe_schedule_device(
-            flat, query_tile=qt, s_len=s_len
-        )
-        s, i = distributed_bucket_score(
-            self.mesh, data, ids, scales, qw, sched, member,
-            k=k, n_local=n_local, shard_axes=self.shard_axes,
-            exclude=exclude, interpret=self.interpret,
-        )
-        if s.shape[-1] < k:   # shards × schedule can't surface k candidates
-            pad = k - s.shape[-1]
-            s = jnp.pad(s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
-            i = jnp.pad(i, ((0, 0), (0, pad)), constant_values=-1)
-        i = jnp.where(jnp.isfinite(s), i, -1)
-        return self._finish(single, s, i, self._n_scored(flat))
+        with span(tracing.ENGINE_SCORE):
+            s, i = distributed_bucket_score(
+                self.mesh, data, ids, scales, qw, sched, member,
+                k=k, n_local=n_local, shard_axes=self.shard_axes,
+                exclude=exclude, interpret=self.interpret,
+            )
+            if s.shape[-1] < k:   # shards × schedule can't surface k
+                pad = k - s.shape[-1]
+                s = jnp.pad(s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+                i = jnp.pad(i, ((0, 0), (0, pad)), constant_values=-1)
+            i = jnp.where(jnp.isfinite(s), i, -1)
+            return self._finish(single, s, i, self._n_scored(flat))

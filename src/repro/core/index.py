@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
+from ..tracing import span
 from .cluster import assign_to_centers_multi, get_clusterer
 from .fields import FieldSpec, normalize_fields
 from .weights import weighted_query
@@ -246,19 +248,22 @@ class ClusterPruneIndex:
         clusterer = get_clusterer(method, **clusterer_kwargs)
         reps_l, ids_l, counts_l, assign_l = [], [], [], []
         for t, sub in enumerate(jax.random.split(key, n_clusterings)):
-            res = clusterer.cluster(docs, k_clusters, sub)
-            reps_l.append(res.reps)
-            assign = np.asarray(res.assign)
-            assign_l.append(assign)
-            ids, counts = pack_buckets(assign, k_clusters, n)
-            ids_l.append(ids)
-            counts_l.append(counts)
-        b = max(ids.shape[1] for ids in ids_l)
-        ids_l = [
-            np.pad(ids, ((0, 0), (0, b - ids.shape[1])), constant_values=n)
-            for ids in ids_l
-        ]
-        buckets = jnp.asarray(np.stack(ids_l))
+            with span(tracing.BUILD_CLUSTER, t=t):
+                res = clusterer.cluster(docs, k_clusters, sub)
+                reps_l.append(res.reps)
+                assign = np.asarray(res.assign)
+                assign_l.append(assign)
+            with span(tracing.BUILD_BUCKETS):
+                ids, counts = pack_buckets(assign, k_clusters, n)
+                ids_l.append(ids)
+                counts_l.append(counts)
+        with span(tracing.BUILD_BUCKETS):
+            b = max(ids.shape[1] for ids in ids_l)
+            ids_l = [
+                np.pad(ids, ((0, 0), (0, b - ids.shape[1])), constant_values=n)
+                for ids in ids_l
+            ]
+            buckets = jnp.asarray(np.stack(ids_l))
         pack_dtype = validate_pack_dtype(pack_dtype)
         if pack_major is None:
             itemsize = (
@@ -270,10 +275,12 @@ class ClusterPruneIndex:
                 and buckets.size * docs.shape[1] * itemsize
                 <= _PACK_MAJOR_AUTO_BYTES
             )
-        bucket_data, bucket_scales = (
-            pack_buckets_major(docs, buckets, n, dtype=pack_dtype)
-            if pack_major else (None, None)
-        )
+        bucket_data, bucket_scales = None, None
+        if pack_major:
+            with span(tracing.INDEX_PACK):
+                bucket_data, bucket_scales = pack_buckets_major(
+                    docs, buckets, n, dtype=pack_dtype
+                )
         index = cls(
             spec=spec,
             docs=docs,
@@ -505,9 +512,11 @@ class ClusterPruneIndex:
             return cached
         self.pack_dtype = validate_pack_dtype(self.pack_dtype)
         if self.bucket_data is None:
-            self.bucket_data, self.bucket_scales = pack_buckets_major(
-                self.docs, self.buckets, self.n_docs, dtype=self.pack_dtype
-            )
+            with span(tracing.INDEX_PACK):
+                self.bucket_data, self.bucket_scales = pack_buckets_major(
+                    self.docs, self.buckets, self.n_docs,
+                    dtype=self.pack_dtype,
+                )
         t, k_clusters, b, _ = self.bucket_data.shape
         ids = jnp.where(self.buckets < self.n_docs, self.buckets, -1)
         self._bucket_major_flat = (
@@ -546,10 +555,11 @@ class ClusterPruneIndex:
             return hit
         self.pack_dtype = validate_pack_dtype(self.pack_dtype)
         k_clusters = int(self.buckets.shape[1])
-        cache[n_shards] = pack_local_bucket_major(
-            self.docs, self.assignments(), k_clusters, n_shards,
-            dtype=self.pack_dtype, sharding=sharding,
-        )
+        with span(tracing.INDEX_PACK):
+            cache[n_shards] = pack_local_bucket_major(
+                self.docs, self.assignments(), k_clusters, n_shards,
+                dtype=self.pack_dtype, sharding=sharding,
+            )
         return cache[n_shards]
 
     # ------------------------------------------------------------ persistence

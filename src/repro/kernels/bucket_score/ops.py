@@ -184,6 +184,7 @@ def bucket_score_tiled(
     grid = (n_tiles, s_len)
     s, i = pl.pallas_call(
         bucket_score_tiled_kernel,
+        name="bucket_score_tiled",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,

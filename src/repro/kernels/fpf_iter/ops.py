@@ -52,6 +52,7 @@ def fpf_iter(
 
     new_ms, idx, val = pl.pallas_call(
         functools.partial(fpf_iter_kernel, m_points=m, block_m=block_m),
+        name="fpf_iter",
         grid=(m_p // block_m,),
         in_specs=[
             pl.BlockSpec((block_m, d), lambda i: (i, 0)),

@@ -35,7 +35,10 @@ never answers. The raw ``(scores, ids,
 n_scored)`` tuple surface lives only inside :mod:`repro.core.engine` — this
 driver speaks requests and responses exclusively. LM serving
 (prefill/decode) lives in examples/serve_lm.py; this driver is the paper's
-own serving loop.
+own serving loop. ``--profile-port N`` starts JAX's profiler server, from
+which ``python -m jax.collect_profile N <ms> --log_dir <dir>`` (or
+TensorBoard's profile plugin) records the program's layer spans
+(:mod:`repro.tracing`) beside the device's trace.
 """
 
 from __future__ import annotations
@@ -235,7 +238,14 @@ def main():
                          "retriever.add (incremental bucket maintenance, no "
                          "rebuild), verify they are retrievable, then remove "
                          "them and verify they are gone")
+    ap.add_argument("--profile-port", type=int, default=None, metavar="N",
+                    help="start JAX's profiler server on port N, so that a "
+                         "profiler client can capture the program's spans "
+                         "(repro.tracing) and the device trace from the "
+                         "live process")
     args = ap.parse_args()
+    if args.profile_port is not None:
+        jax.profiler.start_server(args.profile_port)
     if args.exact and (args.recall_target is not None
                        or args.min_recall is not None):
         ap.error("--exact already guarantees recall 1.0; it cannot combine "

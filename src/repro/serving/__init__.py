@@ -66,6 +66,28 @@ Copy-paste usage::
 Load-test the tier with ``python -m benchmarks.loadtest`` (open/closed
 loop, heterogeneous mixes, QPS + p50/p99 into ``BENCH_query.json``) or
 drive it end to end with ``python -m repro.launch.serve --serve``.
+
+Tracing. Every dispatch leaves named host spans in the JAX profiler's
+trace, on the device planes' clock (:mod:`repro.tracing`; always on, a
+microsecond or two each when no profiler runs):
+
+    repro.serve.flush     event loop: ready queues drained into dispatches
+    repro.serve.call      executor thread: one replica call (dispatch,
+                          replica, n); holds the retriever's spans:
+      repro.search.batch    Retriever.search (n, groups), and within it
+                            .prepare, .engine, .wait, .fetch, .assemble;
+                            repro.engine.navigate/.schedule/.score/.rescore
+                            inside .engine
+    repro.serve.respond   event loop: tickets stamped and resolved
+                          (dispatch, the same number as its call)
+
+The build adds ``repro.build.cluster`` (per clustering, ``t``) holding
+``repro.build.fpf`` and ``repro.build.assign``, ``repro.build.buckets``
+and ``repro.index.pack``; each compile leaves a ``repro.compile`` marker
+(``seconds``) in the span that compiled. To capture them from a live
+server, start it with ``python -m repro.launch.serve --serve
+--profile-port 9999`` and record with ``python -m jax.collect_profile 9999
+2000 --log_dir <dir>``.
 """
 
 from .batcher import Batcher, ShapeQueue

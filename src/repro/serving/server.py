@@ -55,7 +55,9 @@ import itertools
 import random
 import time
 
+from .. import tracing
 from ..core.api import ExecShape, Retriever, SearchRequest, SearchResponse
+from ..tracing import span
 from .batcher import Batcher
 from .health import ReplicaHealth, ResilienceConfig, RetryBudget, degrade_batch
 from .scheduler import (
@@ -74,6 +76,14 @@ __all__ = ["SearchServer", "ReplicaPool", "Replica", "default_max_batch"]
 # retrying these on another replica can only reproduce them, so the batch
 # fails immediately with the original message instead of burning retries.
 _NON_RETRYABLE = (ValueError, TypeError, KeyError, IndexError)
+
+
+def _call(rep: Replica, requests: list[SearchRequest], dispatch: int):
+    """One replica call on an executor thread, in its span: ``dispatch``
+    links it to the loop's spans of the same dispatch."""
+    with span(tracing.SERVE_CALL, dispatch=dispatch, replica=rep.idx,
+              n=len(requests)):
+        return rep.call(requests)
 
 
 def _engine_query_tile(retriever: Retriever) -> int | None:
@@ -431,6 +441,7 @@ class SearchServer:
         self.log_interval_s = log_interval_s
         self._rng = random.Random(self.config.seed)   # backoff jitter
         self._seq = itertools.count()
+        self._dispatch_seq = itertools.count()   # the spans' ``dispatch``
         self._wake: asyncio.Event | None = None
         self._loop_task: asyncio.Task | None = None
         self._log_task: asyncio.Task | None = None
@@ -574,13 +585,20 @@ class SearchServer:
             capacity = self.pool.idle_count() - self._acquiring
             if capacity > 0:
                 ready = self.batcher.ready(now, flush_all=self._draining)
-                for q in self.scheduler.flush_order(ready)[:capacity]:
-                    tickets = q.drain(self.batcher.max_batch)
-                    if tickets:
-                        self._acquiring += 1
-                        task = asyncio.create_task(self._dispatch(tickets))
-                        self._inflight.add(task)
-                        task.add_done_callback(self._dispatch_done)
+                # a span only where something flushes: the loop wakes on
+                # every submit, and a span per wake would be one per request
+                if ready:
+                    with span(tracing.SERVE_FLUSH):
+                        order = self.scheduler.flush_order(ready)
+                        for q in order[:capacity]:
+                            tickets = q.drain(self.batcher.max_batch)
+                            if tickets:
+                                self._acquiring += 1
+                                task = asyncio.create_task(
+                                    self._dispatch(tickets)
+                                )
+                                self._inflight.add(task)
+                                task.add_done_callback(self._dispatch_done)
             if self._draining and not self.batcher.pending():
                 return
             if capacity <= 0:
@@ -658,6 +676,7 @@ class SearchServer:
         timeout: float,
         hedge_after: float | None,
         exclude: set,
+        dispatch: int,
     ):
         """One dispatch attempt, optionally hedged.
 
@@ -672,7 +691,9 @@ class SearchServer:
         procs: list[tuple] = []    # (future, replica, t0, order)
 
         def launch(rep: Replica) -> None:
-            f = loop.run_in_executor(self._executor, rep.call, requests)
+            f = loop.run_in_executor(
+                self._executor, _call, rep, requests, dispatch
+            )
             procs.append((f, rep, loop.time(), len(procs)))
 
         launch(replica)
@@ -747,6 +768,7 @@ class SearchServer:
         loop = asyncio.get_running_loop()
         cfg = self.config
         leased_once = False
+        dispatch = next(self._dispatch_seq)
         try:
             live = self._prune_expired(list(tickets), loop.time())
             if not live:
@@ -817,7 +839,8 @@ class SearchServer:
                     if hedge_after >= timeout:
                         hedge_after = None
                 status, payload, failed = await self._attempt(
-                    shape, requests, replica, timeout, hedge_after, tried
+                    shape, requests, replica, timeout, hedge_after, tried,
+                    dispatch,
                 )
                 attempt += 1
                 tried |= failed
@@ -893,34 +916,35 @@ class SearchServer:
                 self.stats.record_failed(len(live))
                 return
 
-            responses, compute = result
-            t_done = loop.time()
-            waits = []
-            n_degraded = 0
-            for t, resp, lab in zip(live, responses, labels):
-                wait = max(0.0, (t_done - t.t_enqueue) - compute)
-                waits.append(wait)
-                if lab:
-                    n_degraded += 1
-                    resp = dataclasses.replace(
-                        resp,
-                        degraded=True,
-                        degradation=tuple(lab),
-                        queue_wait_s=wait,
-                        compute_s=compute,
-                        latency_s=wait + compute,
-                    )
-                else:
-                    resp = dataclasses.replace(
-                        resp,
-                        queue_wait_s=wait,
-                        compute_s=compute,
-                        latency_s=wait + compute,
-                    )
-                t.resolve(resp)
-            if n_degraded:
-                self.stats.record_degraded(n_degraded)
-            self.stats.record_batch(waits, compute)
+            with span(tracing.SERVE_RESPOND, dispatch=dispatch):
+                responses, compute = result
+                t_done = loop.time()
+                waits = []
+                n_degraded = 0
+                for t, resp, lab in zip(live, responses, labels):
+                    wait = max(0.0, (t_done - t.t_enqueue) - compute)
+                    waits.append(wait)
+                    if lab:
+                        n_degraded += 1
+                        resp = dataclasses.replace(
+                            resp,
+                            degraded=True,
+                            degradation=tuple(lab),
+                            queue_wait_s=wait,
+                            compute_s=compute,
+                            latency_s=wait + compute,
+                        )
+                    else:
+                        resp = dataclasses.replace(
+                            resp,
+                            queue_wait_s=wait,
+                            compute_s=compute,
+                            latency_s=wait + compute,
+                        )
+                    t.resolve(resp)
+                if n_degraded:
+                    self.stats.record_degraded(n_degraded)
+                self.stats.record_batch(waits, compute)
         finally:
             if not leased_once:
                 self._acquiring -= 1
