@@ -36,12 +36,12 @@ wrong one for users. This module is the seam between the two:
     in place (incremental bucket maintenance, no rebuild) and invalidate
     every retriever-level cache.
 
-    The facade memoises two things for the serving hot path: the resolved
-    ``(like, weights)`` -> weighted-query reduction (the §4 fold repeats
-    per user across sessions), and complete responses for byte-identical
-    repeat requests. Both caches key off ``index.version``, so a mutation
-    — through this facade or directly on the index — flushes them; a
-    ladder refit flushes the response cache too (planned budgets change).
+    The facade memoises complete responses for byte-identical repeat
+    more-like-this requests. The cache keys off ``index.version``, so a
+    mutation — through this facade or directly on the index — flushes it,
+    and so does a ladder refit (planned budgets change). Every miss of a
+    batch resolves its weighted query in one step: an all-``like=`` batch
+    is one program over the whole batch (:func:`_mlt_weighted_query`).
 
 The raw tuple surface survives only inside :mod:`repro.core.engine`; every
 consumer above it (serving driver, examples, benchmarks) speaks requests and
@@ -528,6 +528,13 @@ def decompose_scores(
     return _decompose(docs, jnp.atleast_2d(qw), jnp.atleast_2d(ids), spec=spec)
 
 
+@functools.partial(jax.jit, static_argnames=("spec",))
+def _mlt_weighted_query(docs, likes, w_rows, *, spec: FieldSpec):
+    """``Q'_w`` of a batch of more-like-this requests as one program: the
+    seed documents ``docs[likes]`` weighted by ``w_rows`` ``(nq, s)``."""
+    return weighted_query(docs[likes], w_rows, spec)
+
+
 # ------------------------------------------------------------------ retriever
 class Retriever:
     """Facade over index + engines: typed requests in, typed responses out.
@@ -540,10 +547,8 @@ class Retriever:
     allow, and responses come back in request order.
     """
 
-    # Cache bounds: FIFO-evicted OrderedDicts. qw rows are (D,) floats
-    # (~4 KB at D=1024), responses are a few KB of hits — both caps keep
-    # the caches at tens of MB worst case.
-    _QW_CACHE_MAX = 8192
+    # Cache bound: a FIFO-evicted OrderedDict. Responses are a few KB of
+    # hits, so the cap keeps the cache at tens of MB worst case.
     _RESPONSE_CACHE_MAX = 2048
 
     def __init__(self, index: ClusterPruneIndex, *, backend: str = "auto",
@@ -581,12 +586,10 @@ class Retriever:
         self._plan_ladder: object | None = index.ladder
         self._warned_static = False
         self._warned_stale = False
-        # request memoisation (ROADMAP "batch caching"): resolved
-        # (like, weights)->qw reductions and whole repeat-request responses,
-        # valid for exactly one index version.
+        # request memoisation (ROADMAP "batch caching"): whole
+        # repeat-request responses, valid for exactly one index version.
         from collections import OrderedDict
 
-        self._qw_cache: "OrderedDict[tuple, jnp.ndarray]" = OrderedDict()
         self._response_cache: "OrderedDict[tuple, SearchResponse]" = (
             OrderedDict()
         )
@@ -673,7 +676,6 @@ class Retriever:
     remove_documents = remove
 
     def _flush_request_caches(self) -> None:
-        self._qw_cache.clear()
         self._response_cache.clear()
         self._plan_cache.clear()
         self._cache_version = getattr(self.index, "version", 0)
@@ -873,60 +875,36 @@ class Retriever:
                     return out  # type: ignore[return-value]
                 mreqs = [reqs[i] for i in miss]
 
-                # Resolve the misses up front (vectorised where it matters):
-                # the (like, weights) -> qw §4 reduction is memoised per pair
-                # — repeat users cost one cache probe — and the remainder
-                # resolve in ONE corpus gather (all-MLT fast path) + ONE
-                # weighted_query call.
-                qkeys = [
-                    (int(r.like), self._weights_key(r.weights))
-                    if r.like is not None else None
-                    for r in mreqs
-                ]
-                rows_qw: list[jnp.ndarray | None] = [
-                    self._qw_cache.get(qk) if qk is not None else None
-                    for qk in qkeys
-                ]
-                todo = [j for j, row in enumerate(rows_qw) if row is None]
-                if todo:
-                    treqs = [mreqs[j] for j in todo]
-                    if all(r.like is not None for r in treqs):
-                        likes = [int(r.like) for r in treqs]
-                        bad = [l for l in likes if l >= index.n_docs]
-                        if bad:
-                            raise ValueError(
-                                f"like={bad[0]} out of range for a corpus of "
-                                f"{index.n_docs} documents"
-                            )
-                        removed = getattr(index, "removed", None)
-                        if removed is not None:
-                            gone = [l for l in likes if bool(removed[l])]
-                            if gone:
-                                raise ValueError(
-                                    f"like={gone[0]} refers to a removed "
-                                    "document; more-like-this cannot seed "
-                                    "from a tombstoned doc"
-                                )
-                        q_all = index.docs[jnp.asarray(likes)]
-                    else:
-                        q_all = jnp.stack(
-                            [r.resolve_query(index) for r in treqs]
+                # Resolve every miss in one step, with no device work per
+                # row: an all-MLT batch is ONE program (corpus gather + §4
+                # reduction) over the whole batch; a batch with a raw vector
+                # resolves each query, then makes ONE weighted_query call.
+                if all(r.like is not None for r in mreqs):
+                    likes = [int(r.like) for r in mreqs]
+                    bad = [l for l in likes if l >= index.n_docs]
+                    if bad:
+                        raise ValueError(
+                            f"like={bad[0]} out of range for a corpus of "
+                            f"{index.n_docs} documents"
                         )
-                    w_rows = np.stack([r.resolve_weights(spec) for r in treqs])
-                    qw_new = weighted_query(q_all, jnp.asarray(w_rows), spec)
-                    for jj, j in enumerate(todo):
-                        rows_qw[j] = qw_new[jj]
-                        if qkeys[j] is not None:
-                            self._cache_put(
-                                self._qw_cache, self._QW_CACHE_MAX, qkeys[j],
-                                qw_new[jj],
+                    removed = getattr(index, "removed", None)
+                    if removed is not None:
+                        gone = [l for l in likes if bool(removed[l])]
+                        if gone:
+                            raise ValueError(
+                                f"like={gone[0]} refers to a removed "
+                                "document; more-like-this cannot seed "
+                                "from a tombstoned doc"
                             )
-                # cold batch (no qw-cache hits): qw_new already IS the batch
-                # tensor
-                qw_all = (
-                    qw_new if todo and len(todo) == len(mreqs)
-                    else jnp.stack(rows_qw)
-                )                                             # (n_miss, D)
+                    w_rows = np.stack([r.resolve_weights(spec) for r in mreqs])
+                    qw_all = _mlt_weighted_query(
+                        index.docs, np.asarray(likes, np.int32), w_rows,
+                        spec=spec,
+                    )                                     # (n_miss, D)
+                else:
+                    q_all = jnp.stack([r.resolve_query(index) for r in mreqs])
+                    w_rows = np.stack([r.resolve_weights(spec) for r in mreqs])
+                    qw_all = weighted_query(q_all, jnp.asarray(w_rows), spec)
                 excl_all = np.asarray(
                     [r.resolve_exclude() for r in mreqs], np.int32
                 )
@@ -945,8 +923,11 @@ class Retriever:
                     )
                     opts = self.engine_opts if backend == self.backend else {}
                     engine = get_engine(index, backend, **opts)
-                    qw = qw_all[jnp.asarray(rows)]
-                    excl = jnp.asarray(excl_all[rows])
+                    if len(rows) == len(mreqs):   # the whole batch, in order
+                        qw, excl = qw_all, excl_all
+                    else:
+                        qw, excl = qw_all[jnp.asarray(rows)], excl_all[rows]
+                    excl = jnp.asarray(excl)
                 t0 = time.perf_counter()
                 with span(tracing.SEARCH_ENGINE):
                     tier, escalations, pred_served = "approx", 0, None

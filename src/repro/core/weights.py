@@ -76,9 +76,12 @@ def validate_weights(w, spec: FieldSpec | None = None) -> np.ndarray:
 
 
 def expand_weights(w: jnp.ndarray, spec: FieldSpec) -> jnp.ndarray:
-    """Expand per-field weights ``(..., s)`` to concat coords ``(..., D)``."""
+    """Expand per-field weights ``(..., s)`` to concat coords ``(..., D)``.
+
+    The repeat counts stay a host constant, so a traced caller compiles
+    them into its program instead of uploading them on every call."""
     return jnp.repeat(
-        w, jnp.asarray(spec.dims), axis=-1, total_repeat_length=spec.total_dim
+        w, np.asarray(spec.dims), axis=-1, total_repeat_length=spec.total_dim
     )
 
 

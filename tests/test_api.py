@@ -445,13 +445,103 @@ def test_repeat_request_served_from_cache(fresh_retriever):
     assert retriever.search(vec) is not retriever.search(vec)
 
 
-def test_qw_reduction_memoised(fresh_retriever):
+def test_planning_runs_no_per_row_device_work(fresh_retriever, monkeypatch):
+    """An all-MLT batch is planned by ONE resolve program whatever its size:
+    a batch of 8 and a batch of 64 start the same device programs inside
+    the planning spans, and none of them indexes a device array by row."""
+    import contextlib
+
+    from repro import tracing
+    from repro.core import api
+
     retriever, docs, spec = fresh_retriever
-    reqs = [SearchRequest(like=5, weights=(0.2, 0.3, 0.5), probes=6, k=4),
-            SearchRequest(like=5, weights=(0.2, 0.3, 0.5), probes=12, k=4)]
-    retriever.search(reqs)     # same (like, weights) key, different probes
-    assert len(retriever._qw_cache) == 1
-    assert len(retriever._response_cache) == 2
+    counts = {"resolve": 0, "getitem": 0}
+    planning = [False]
+
+    real_span = api.span
+
+    @contextlib.contextmanager
+    def tagged_span(name, **meta):
+        prior = planning[0]
+        planning[0] = name == tracing.SEARCH_PREPARE
+        try:
+            with real_span(name, **meta) as sp:
+                yield sp
+        finally:
+            planning[0] = prior
+
+    real_resolve = api._mlt_weighted_query
+
+    def counted_resolve(*args, **kwargs):
+        counts["resolve"] += planning[0]
+        return real_resolve(*args, **kwargs)
+
+    array_type = type(jnp.zeros(()))
+    real_getitem = array_type.__getitem__
+
+    def counted_getitem(self, idx):
+        counts["getitem"] += planning[0]
+        return real_getitem(self, idx)
+
+    monkeypatch.setattr(api, "span", tagged_span)
+    monkeypatch.setattr(api, "_mlt_weighted_query", counted_resolve)
+    monkeypatch.setattr(array_type, "__getitem__", counted_getitem)
+
+    rng = np.random.default_rng(3)
+    seen = {}
+    for n in (8, 64):
+        counts.update(resolve=0, getitem=0)
+        reqs = [
+            SearchRequest(like=int(like), weights=tuple(w), probes=6, k=4)
+            for like, w in zip(rng.choice(500, n, replace=False),
+                               rng.dirichlet(np.ones(3), n))
+        ]
+        out = retriever.search(reqs)
+        assert [r.batch_size for r in out] == [n] * n
+        seen[n] = dict(counts)
+    assert seen[8] == seen[64] == {"resolve": 1, "getitem": 0}
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("with_vector", (True, False))
+def test_batched_resolve_matches_solo(fresh_retriever, backend, with_vector):
+    """Every miss of a batch resolves in one step: a repeated (like,
+    weights) pair under two budgets, another weight draw and (optionally)
+    a raw vector, planned together across two engine groups, get the doc
+    ids and scores each request gets alone; the pair still caches two
+    responses."""
+    retriever, docs, spec = fresh_retriever
+    w = (0.2, 0.3, 0.5)
+    reqs = [
+        SearchRequest(like=5, weights=w, probes=6, k=4, backend=backend),
+        SearchRequest(like=5, weights=w, probes=12, k=4, backend=backend),
+        SearchRequest(like=5, weights=(0.6, 0.3, 0.1), probes=6, k=4,
+                      backend=backend),
+    ]
+    if with_vector:
+        reqs.append(SearchRequest(query=docs[9], weights=w, probes=6, k=4,
+                                  exclude=9, backend=backend))
+    batched = retriever.search(reqs)
+    assert [r.batch_size for r in batched] == [len(reqs) - 1, 1] + [
+        len(reqs) - 1] * (len(reqs) - 2)
+    pair = [key for key in retriever._response_cache
+            if key[:2] == (5, retriever._weights_key(w))]
+    assert len(pair) == 2
+    assert len(retriever._response_cache) == 3
+
+    retriever._flush_request_caches()
+    for req, got in zip(reqs, batched):
+        solo = retriever.search(req)
+        assert solo is not got
+        assert np.array_equal(got.doc_ids, solo.doc_ids)
+        assert np.array_equal(got.scores, solo.scores)
+        # the split is one einsum over the group, so its float32 sums
+        # round by the group's size (a few ulp), as in the routing test
+        for hg, hs in zip(got.hits, solo.hits):
+            assert hg.field_scores.keys() == hs.field_scores.keys()
+            np.testing.assert_allclose(
+                list(hg.field_scores.values()),
+                list(hs.field_scores.values()), rtol=0, atol=1e-6)
 
 
 def test_cache_invalidated_by_mutation(fresh_retriever):
