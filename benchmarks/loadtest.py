@@ -642,14 +642,11 @@ def run(scale: str = "quick", seed: int = 0, *, backend: str = "auto",
     n_docs = n_docs or sz["n_docs"]
     n_requests = n_requests or sz["n_requests"]
 
-    from repro.core import pick_backend
-
-    picked = pick_backend() if backend in (None, "auto") else backend
     retriever, docs, spec = build_retriever(
-        n_docs, backend=backend, seed=seed,
-        pack_major=True if picked == "fused" else None,
-        pack_dtype=pack_dtype,
+        n_docs, backend=backend, seed=seed, pack_dtype=pack_dtype,
     )
+    if retriever.backend == "fused":
+        retriever.index.ensure_bucket_major()
     requests = make_mix(n_docs, spec, n_requests, seed=seed)
     served = retriever.backend
     platform = jax.default_backend()

@@ -2,7 +2,7 @@
 """Bring-up smoke test: the cluster-pruned search end to end on a TPU.
 
     python chip_smoke.py            # one chip, the paper's TS1 corpus
-    python chip_smoke.py --chips 4  # four chips, TS2 on the sharded backend
+    python chip_smoke.py --chips 4  # four chips, TS2 (the pick shards it)
 
 One chip builds the TS1 corpus (53,722 docs, D=4096 over three fields,
 K=500, T=3, fp32, seeded) through ``Retriever.build`` with the defaults the
@@ -20,10 +20,10 @@ platform picks (``fpf_fused`` builds, ``fused`` serves), then:
 (e) prints the device kind, the compile seconds and the device bytes in use.
 
 ``--chips 4`` runs only the multi-chip path and what it is compared with:
-TS2 (100,000 docs, K=1000; its fp32 pack does not fit one chip) served by
-``backend="sharded"``, checked against ``reference`` at ``probes=12`` and
-against brute force on the exact tier, with each device's share of the pack
-and its bytes in use printed.
+TS2 (100,000 docs, K=1000; its fp32 pack does not fit one chip) built with
+``backend="auto"``, which has to resolve to ``sharded``, then phases (a)-(e)
+as above, with each device's share of the pack and its bytes in use
+printed.
 
 Any failed check raises, so the script exits non-zero. It also exits
 non-zero, printing no result, where JAX finds no TPU. The last line of a
@@ -111,7 +111,6 @@ def run_phases(
     *,
     method: str = "auto",
     backend: str = "auto",
-    serve: bool = True,
     n_requests: int = 64,
     probes: int = 12,
     k: int = 10,
@@ -120,8 +119,8 @@ def run_phases(
 ) -> dict:
     """Phases (a)-(e) over the corpus ``cfg`` (:func:`corpus_config`
     keys). ``method``/``backend`` go to ``Retriever.build`` unchanged —
-    ``"auto"`` is the platform's own pick. ``serve=False`` skips the async
-    tier. Returns what was measured; raises on any failed check."""
+    ``"auto"`` is the platform's own pick. Returns what was measured;
+    raises on any failed check."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -211,20 +210,19 @@ def run_phases(
         f"force ({wrong} beyond near-ties)")
 
     # (d) async serving tier vs the synchronous answers -----------------
-    if serve:
-        retriever._flush_request_caches()       # answer from the engine
-        t0 = time.perf_counter()
-        async_resps, stats, _ = serve_async(retriever, reqs)
-        a_ids = np.stack([r.doc_ids for r in async_resps])
-        a_s = np.stack([r.scores for r in async_resps])
-        same_ids = int(np.sum(np.all(a_ids == ids, axis=-1)))
-        close = np.allclose(a_s, scores, rtol=SCORE_RTOL, atol=SCORE_ATOL)
-        _check(same_ids == n_requests and close,
-               f"async tier: {n_requests - same_ids} answers differ from "
-               "the synchronous path")
-        out["async_s"] = time.perf_counter() - t0
-        log(f"[d] SearchServer: {same_ids}/{n_requests} id- and "
-            f"score-identical to sync search ({stats})")
+    retriever._flush_request_caches()       # answer from the engine
+    t0 = time.perf_counter()
+    async_resps, stats, _ = serve_async(retriever, reqs)
+    a_ids = np.stack([r.doc_ids for r in async_resps])
+    a_s = np.stack([r.scores for r in async_resps])
+    same_ids = int(np.sum(np.all(a_ids == ids, axis=-1)))
+    close = np.allclose(a_s, scores, rtol=SCORE_RTOL, atol=SCORE_ATOL)
+    _check(same_ids == n_requests and close,
+           f"async tier: {n_requests - same_ids} answers differ from "
+           "the synchronous path")
+    out["async_s"] = time.perf_counter() - t0
+    log(f"[d] SearchServer: {same_ids}/{n_requests} id- and "
+        f"score-identical to sync search ({stats})")
 
     # (e) device, compile time, memory ----------------------------------
     devs = jax.devices()
@@ -282,7 +280,10 @@ def main(argv=None) -> int:
                f"platform picked {out['clusterer']} + {out['backend']}, "
                "not fpf_fused + fused")
     else:
-        run_phases(corpus_config("ts2"), backend="sharded", serve=False)
+        out = run_phases(corpus_config("ts2"))
+        _check(out["backend"] == "sharded",
+               f"TS2 on {len(devs)} chips resolved to {out['backend']}, "
+               "not sharded")
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs),

@@ -161,6 +161,7 @@ def exec_shape(
     plan_target: Callable[[float], int] | None = None,
     total_probes: int | None = None,
     predict_recall: Callable[[int], float | None] | None = None,
+    index: ClusterPruneIndex | None = None,
 ) -> ExecShape:
     """Resolve one request to its :class:`ExecShape` grouping key.
 
@@ -176,7 +177,8 @@ def exec_shape(
     concrete backend name, so auto requests share a group with
     default-backend requests instead of batching separately under the
     literal string (which would also bypass the retriever's
-    ``engine_opts`` and cache a duplicate engine).
+    ``engine_opts`` and cache a duplicate engine). The pick is by platform
+    and by ``index``'s size (:func:`~repro.core.engine.pick_backend`).
 
     ``total_probes`` (= T·K; a retriever passes its index's) clamps
     explicit budgets to the "probe everything = exact search" ceiling and
@@ -193,7 +195,7 @@ def exec_shape(
     if backend in (None, "auto"):
         from .engine import pick_backend
 
-        backend = pick_backend()
+        backend = pick_backend(index)
     if req.exact:
         if total_probes is None:
             raise ValueError(
@@ -761,6 +763,7 @@ class Retriever:
             plan_target=lambda t: self._plan_target(t)[0],
             total_probes=self._tk[0] * self._tk[1],
             predict_recall=self._predict_recall,
+            index=self.index,
         )
 
     def _plan(self, req: SearchRequest) -> tuple[ExecShape, float | None]:

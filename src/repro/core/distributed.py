@@ -455,7 +455,11 @@ def distributed_exact_rescore(
     """
     axes = tuple(shard_axes)
     fn = _exact_rescore_fn(mesh, axes, int(k), int(n_local))
-    return fn(docs_sh, qw, ids.astype(jnp.int32))
+    # the candidates may come committed to one device (the engine hands its
+    # answers on from the corpus's device): replicate them onto the mesh
+    qw, ids = jax.device_put((qw, ids.astype(jnp.int32)),
+                             NamedSharding(mesh, P()))
+    return fn(docs_sh, qw, ids)
 
 
 # --------------------------------------------------- shard-local bucket packs

@@ -61,8 +61,9 @@ the next calibrated rung — ultimately the exact tier — charging every
 tier's candidates cumulatively to ``n_scored``.
 
 Select a backend by name or let :func:`pick_backend` choose from the
-platform (TPU -> ``fused``, multi-device -> ``sharded``, else
-``reference``)::
+platform and the index's size (TPU -> ``fused`` while the pack fits one
+device, ``sharded`` over the host's devices where it does not;
+multi-device -> ``sharded``, else ``reference``)::
 
     engine = get_engine(index, "auto")
     scores, ids, n_scored = engine.search(qw, probes=12, k=10)
@@ -75,6 +76,7 @@ search backends").
 from __future__ import annotations
 
 import functools
+import math
 from typing import Protocol, runtime_checkable
 
 import jax
@@ -143,19 +145,57 @@ def available_backends() -> tuple[str, ...]:
     return tuple(BACKENDS)
 
 
-def pick_backend(index=None) -> str:
-    """Platform auto-pick: TPU -> fused, multi-device -> sharded, else ref.
+# Share of one device's memory the fused backend's serving state (the
+# bucket-major pack at its dtype, plus the fp32 corpus) may take. The rest
+# holds the kernel's working set, the probe schedule and XLA's temporaries;
+# an index above it is sharded where the host has several devices.
+_FUSED_MEMORY_SHARE = 0.75
 
-    Any corpus size shards cleanly (the sharded backend pads with sentinel
-    rows), so multi-device always picks ``sharded``; ``index`` is accepted
-    for backward compatibility but no longer gates the choice.
+
+def pack_bytes(index) -> int:
+    """Bytes of ``index``'s whole bucket-major pack at its ``pack_dtype``:
+    T·K·B·D·itemsize, from the shapes alone (nothing is materialised)."""
+    itemsize = jnp.dtype(index.pack_dtype or index.docs.dtype).itemsize
+    d = int(index.docs.shape[-1])
+    return math.prod(int(x) for x in index.buckets.shape) * d * itemsize
+
+
+def _host() -> tuple[str, int, int]:
+    """``(platform, device count, bytes one device may hold)``; the limit is
+    0 where the backend does not report it."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return (jax.default_backend(), jax.device_count(),
+            int(stats.get("bytes_limit", 0)))
+
+
+def pick_backend(index=None) -> str:
+    """The backend ``"auto"`` resolves to, by platform and by size.
+
+    On a TPU: ``fused`` while ``index``'s serving state (its bucket-major
+    pack at ``pack_dtype`` plus the fp32 corpus) fits one device within
+    :data:`_FUSED_MEMORY_SHARE` of its byte limit, else ``sharded`` over all
+    of the host's devices where it has several. Elsewhere: ``sharded`` on a
+    multi-device host, else ``reference``. Without an index, or where the
+    device reports no limit, the state counts as fitting. Leaves a
+    :data:`~repro.tracing.ENGINE_PICK` marker with the backend, the pack's
+    bytes, the device's byte limit and the device count.
     """
-    del index
-    if jax.default_backend() == "tpu":
-        return "fused"
-    if jax.device_count() > 1:
-        return "sharded"
-    return "reference"
+    platform, n_devices, limit = _host()
+    pack = state = 0
+    if index is not None:
+        docs = index.docs
+        pack = pack_bytes(index)
+        state = pack + math.prod(docs.shape) * docs.dtype.itemsize
+    if platform != "tpu":
+        name = "sharded" if n_devices > 1 else "reference"
+    elif n_devices > 1 and limit and state > _FUSED_MEMORY_SHARE * limit:
+        name = "sharded"
+    else:
+        name = "fused"
+    with span(tracing.ENGINE_PICK, backend=name, pack_bytes=pack,
+              bytes_limit=limit, devices=n_devices):
+        pass
+    return name
 
 
 def get_engine(index, backend: str = "auto", **opts) -> SearchEngine:
@@ -814,7 +854,7 @@ class ShardedEngine(_EngineBase):
             sched, member = build_probe_schedule_device(
                 flat, query_tile=qt, s_len=s_len
             )
-        with span(tracing.ENGINE_SCORE):
+        with span(tracing.ENGINE_SCORE, shards=self.n_shards):
             s, i = distributed_bucket_score(
                 self.mesh, data, ids, scales, qw, sched, member,
                 k=k, n_local=n_local, shard_axes=self.shard_axes,
@@ -824,5 +864,11 @@ class ShardedEngine(_EngineBase):
                 pad = k - s.shape[-1]
                 s = jnp.pad(s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
                 i = jnp.pad(i, ((0, 0), (0, pad)), constant_values=-1)
+            # The merge leaves the answer replicated over the mesh. Hand it
+            # on from the corpus's device: the field decomposition that
+            # reads it next would otherwise copy the corpus to every chip.
+            docs = self.index.docs
+            if isinstance(docs, jax.Array) and len(docs.devices()) == 1:
+                s, i = jax.device_put((s, i), docs.sharding)
             i = jnp.where(jnp.isfinite(s), i, -1)
             return self._finish(single, s, i, self._n_scored(flat))

@@ -108,7 +108,9 @@ LADDER_DRIFT_THRESHOLD = 0.1
 
 # Auto-materialise the bucket-major tensor at build (TPU only, where the
 # fused backend serves by default) when it costs less than this; otherwise
-# defer to the first fused search (ensure_bucket_major).
+# defer to the first fused search (ensure_bucket_major). The bound lies far
+# below the size at which pick_backend shards an index, so a build that
+# resolves to ``sharded`` never holds the whole pack on one device.
 _PACK_MAJOR_AUTO_BYTES = 256 * 2**20
 
 

@@ -58,7 +58,6 @@ from repro.core import (
     brute_force_topk,
     competitive_recall,
     normalized_aggregate_goodness,
-    pick_backend,
     weighted_query,
 )
 from repro.data import CorpusConfig, make_corpus
@@ -261,14 +260,14 @@ def main():
 
     # Materialise the bucket-major layout at build time whenever the fused
     # backend may serve — the engine would otherwise do it on first search.
-    picked = pick_backend() if args.backend == "auto" else args.backend
-    need_major = args.compare or picked == "fused"
     t0 = time.time()
     retriever, docs, spec = build_retriever(
         args.docs, backend=args.backend, seed=args.seed,
-        pack_major=True if need_major else None,
+        pack_major=True if args.compare else None,
     )
     index = retriever.index
+    if retriever.backend == "fused":
+        index.ensure_bucket_major()
     print(f"[serve] index built in {time.time() - t0:.1f}s "
           f"(K={index.leaders.shape[1]}, T={index.leaders.shape[0]}"
           f"{', bucket-major packed' if index.bucket_data is not None else ''})")

@@ -36,6 +36,7 @@ SEARCH_FETCH = "repro.search.fetch"      # decompose + device-to-host copies
 SEARCH_ASSEMBLE = "repro.search.assemble"
 
 # engine internals (core/engine.py)
+ENGINE_PICK = "repro.engine.pick"        # marker: where "auto" resolves
 ENGINE_NAVIGATE = "repro.engine.navigate"
 ENGINE_SCHEDULE = "repro.engine.schedule"
 ENGINE_SCORE = "repro.engine.score"

@@ -46,17 +46,18 @@ def test_phases_pass_on_a_tiny_corpus():
 
 
 def test_sharded_phases_pass_on_four_cpu_devices():
-    """The ``--chips 4`` path at a tiny size: the sharded backend on a
-    forced four-device CPU mesh (a fresh process, so the device count can
-    be set), its pack built one slice per device."""
+    """The ``--chips 4`` path at a tiny size: ``auto`` resolves to the
+    sharded backend on a forced four-device CPU mesh (a fresh process, so
+    the device count can be set), its pack built one slice per device, and
+    the async tier answers as the synchronous path does."""
     code = (
         "import importlib.util, json\n"
         f"spec = importlib.util.spec_from_file_location('s', {str(ROOT / 'chip_smoke.py')!r})\n"
         "m = importlib.util.module_from_spec(spec); spec.loader.exec_module(m)\n"
-        f"out = m.run_phases({dict(TINY, n_docs=601)!r}, backend='sharded',\n"
-        "                   serve=False, n_requests=8)\n"
+        f"out = m.run_phases({dict(TINY, n_docs=601)!r}, n_requests=8)\n"
         "print(json.dumps({k: out[k] for k in\n"
-        "      ('backend', 'exact_mismatches', 'pack_share_bytes')}))\n"
+        "      ('backend', 'exact_mismatches', 'pack_share_bytes',\n"
+        "       'async_s')}))\n"
     )
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
@@ -66,7 +67,7 @@ def test_sharded_phases_pass_on_four_cpu_devices():
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["backend"] == "sharded"
     assert out["exact_mismatches"] == 0
-    assert out["pack_share_bytes"] > 0
+    assert out["pack_share_bytes"] > 0 and out["async_s"] > 0
 
 
 def test_compare_tells_near_ties_from_wrong_answers():

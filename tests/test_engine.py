@@ -2,6 +2,8 @@
 algorithm executed three ways — identical top-k ids, scores (to float
 tolerance), and n_scored cost accounting on the same built index."""
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -148,6 +150,80 @@ def test_registry_and_autopick():
     assert pick_backend() in available_backends()
     with pytest.raises(ValueError, match="unknown backend"):
         get_engine(object(), "no-such-backend")
+
+
+# ------------------------------------------------------- size-aware pick
+# Index metadata only: the pick reads shapes, never the arrays.
+V5E_LIMIT = 16_900_000_000   # about what one v5e chip reports as bytes_limit
+
+
+def _meta(n, k_clusters, b, pack_dtype=None, t=3, d=4096):
+    return types.SimpleNamespace(
+        docs=jax.ShapeDtypeStruct((n, d), jnp.float32),
+        buckets=jax.ShapeDtypeStruct((t, k_clusters, b), jnp.int32),
+        pack_dtype=pack_dtype,
+    )
+
+
+TS1 = _meta(50_000, 500, 312)       # 7.67 GB fp32 pack
+TS2 = _meta(100_000, 1000, 568)     # 27.9 GB fp32 pack
+TS2_INT8 = _meta(100_000, 1000, 568, "int8")
+
+
+@pytest.mark.parametrize("platform, devices, limit, index, expected", [
+    ("tpu", 1, V5E_LIMIT, TS1, "fused"),
+    ("tpu", 1, V5E_LIMIT, TS2, "fused"),       # nothing to shard over
+    ("tpu", 4, V5E_LIMIT, TS1, "fused"),       # fits one chip
+    ("tpu", 4, V5E_LIMIT, TS2, "sharded"),
+    ("tpu", 4, V5E_LIMIT, TS2_INT8, "fused"),  # a quarter of the bytes
+    ("tpu", 4, V5E_LIMIT, None, "fused"),
+    ("tpu", 4, 0, TS2, "fused"),               # no limit reported
+    ("cpu", 1, 0, TS2, "reference"),
+    ("cpu", 4, 0, TS1, "sharded"),
+    ("cpu", 4, 0, None, "sharded"),
+])
+def test_pick_by_platform_and_size(monkeypatch, platform, devices, limit,
+                                   index, expected):
+    from repro.core import engine
+
+    monkeypatch.setattr(engine, "_host", lambda: (platform, devices, limit))
+    assert pick_backend(index) == expected
+
+
+def test_exec_shape_picks_through_the_index(monkeypatch):
+    from repro.core import SearchRequest, exec_shape
+    from repro.core import engine
+
+    monkeypatch.setattr(engine, "_host", lambda: ("tpu", 4, V5E_LIMIT))
+    req = SearchRequest(query=np.ones(4096, np.float32))
+    shape = lambda index: exec_shape(req, default_backend="auto",
+                                     default_probes=12, index=index).backend
+    assert (shape(TS1), shape(TS2)) == ("fused", "sharded")
+
+
+def test_pick_leaves_its_marker(monkeypatch):
+    from repro import tracing
+    from repro.core import engine
+
+    marks = []
+
+    class Recorder:
+        def __init__(self, name, **args):
+            marks.append((name, args))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(engine, "_host", lambda: ("tpu", 4, V5E_LIMIT))
+    monkeypatch.setattr(engine, "span", Recorder)
+    assert pick_backend(TS2) == "sharded"
+    assert engine.pack_bytes(TS2) == 3 * 1000 * 568 * 4096 * 4
+    assert marks == [(tracing.ENGINE_PICK, {
+        "backend": "sharded", "pack_bytes": 3 * 1000 * 568 * 4096 * 4,
+        "bytes_limit": V5E_LIMIT, "devices": 4})]
 
 
 # --------------------------------------------------------- v2 query tiling

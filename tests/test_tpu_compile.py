@@ -132,14 +132,15 @@ def test_probe_schedule_device_compiles(one_chip):
 
 def test_sharded_bucket_score_compiles(one_chip, topo):
     """The TS2 deployment's hot path: a 4-way row-sharded fp32 pack, one
-    kernel per shard, one all-gather + merge of the per-shard top-k."""
+    kernel per shard, one all-gather + merge of the per-shard top-k, at the
+    served batch of 128 (one full query tile)."""
     devices = np.asarray(topo.devices[:4])
     mesh = Mesh(devices, ("data",))
     n_shards = devices.size
     k_clusters, b = CORPORA["ts2"]
     b_l = pad_to(-(-b // n_shards), 8)                 # per-shard bucket rows
     n_local = 100_000 // n_shards
-    nq = 64
+    nq = 128
     qt = min(pick_query_tile(D, b_l, k_pad=pad_to(K_TOP, 8)), nq)
     n_tiles = -(-nq // qt)
     s_len = schedule_length(qt, PROBES, T * k_clusters)

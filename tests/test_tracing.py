@@ -1,8 +1,8 @@
 """The program's layer spans (``repro.tracing``) read back from a profiler
 trace: every span is recorded, the spans nest as the layers call each
 other, a dispatch's spans on the event loop and on the executor thread
-carry the same number, and a compile leaves its marker inside the span
-that compiled."""
+carry the same number, a compile leaves its marker inside the span that
+compiled, and the backend pick leaves its marker with what it chose by."""
 
 import asyncio
 import tempfile
@@ -48,6 +48,7 @@ def events():
     try:
         r = Retriever.build(docs, spec, 6, backend="fused",
                             key=jax.random.PRNGKey(1))
+        Retriever(r.index)                  # "auto": leaves the pick marker
         r.search(_requests(rng, 6, spec.names)
                  + _requests(rng, 2, spec.names, rescore=8))
         with tracing.span(FORCED):
@@ -87,7 +88,7 @@ def _inside(inner, outers):
 
 def test_every_span_is_recorded(events):
     names = {e[1] for e in events}
-    assert len(SPANS) == 19
+    assert len(SPANS) == 20
     assert SPANS <= names, sorted(SPANS - names)
 
 
@@ -95,7 +96,7 @@ def test_search_spans_nest_in_the_batch_and_the_batch_in_the_call(events):
     batches = _named(events, tracing.SEARCH_BATCH)
     inner = [e for e in events
              if e[1].startswith(("repro.search.", "repro.engine."))
-             and e[1] != tracing.SEARCH_BATCH]
+             and e[1] not in (tracing.SEARCH_BATCH, tracing.ENGINE_PICK)]
     assert inner and batches
     for e in inner:
         assert _inside(e, batches), e[:4]
@@ -134,3 +135,13 @@ def test_compile_marker_lands_in_the_span_that_compiled(events):
     # the first fused search compiled the kernel under the score span
     assert any(_inside(m, _named(events, tracing.ENGINE_SCORE))
                for m in marks)
+
+
+def test_pick_marker_carries_what_it_chose_by(events):
+    pick, = _named(events, tracing.ENGINE_PICK)
+    stats = pick[4]
+    assert set(stats) == {"backend", "pack_bytes", "bytes_limit", "devices"}
+    assert stats["backend"] == "reference" and stats["devices"] == 1
+    # the 400-document index: T=3 clusterings of 6 buckets, 64 dims, fp32
+    assert stats["pack_bytes"] > 0 and stats["pack_bytes"] % (3 * 6 * 64 * 4) == 0
+    assert stats["bytes_limit"] == 0          # the CPU reports no limit
