@@ -653,10 +653,12 @@ class FusedEngine(_EngineBase):
     ``S = pow2ceil(min(QT·P, n_buckets))``
     (:func:`~repro.kernels.bucket_score.ops.schedule_length`), so the hot
     path never synchronises the probe tensor HBM→host→HBM. Padded schedule
-    slots all target bucket 0 with zero membership — consecutive equal
-    block indices, meant to let the Pallas pipeline skip their repeat DMAs
-    so the static upper bound costs no block reads (not yet measured).
-    Runs interpreted off-TPU (tests only).
+    slots all target bucket 0 with zero membership and follow the live
+    ones; the kernel skips their grid steps (no DMA, no matmul, no merge),
+    so the static upper bound costs only a predicate a step. The
+    ``repro.engine.score`` span carries ``slots``, the grid steps launched
+    (``n_tiles · S``), beside which the dispatch's live buckets give the
+    share skipped. Runs interpreted off-TPU (tests only).
     """
 
     uses_packed_storage = True
@@ -712,7 +714,7 @@ class FusedEngine(_EngineBase):
             sched, member = build_probe_schedule_device(
                 flat, query_tile=qt, s_len=s_len
             )
-        with span(tracing.ENGINE_SCORE):
+        with span(tracing.ENGINE_SCORE, slots=sched.size):
             s, i = bucket_score_tiled(
                 qw, data, ids, sched, member,
                 k=k, exclude=exclude, scales=scales, interpret=self.interpret,
@@ -854,7 +856,8 @@ class ShardedEngine(_EngineBase):
             sched, member = build_probe_schedule_device(
                 flat, query_tile=qt, s_len=s_len
             )
-        with span(tracing.ENGINE_SCORE, shards=self.n_shards):
+        with span(tracing.ENGINE_SCORE, shards=self.n_shards,
+                  slots=sched.size):
             s, i = distributed_bucket_score(
                 self.mesh, data, ids, scales, qw, sched, member,
                 k=k, n_local=n_local, shard_axes=self.shard_axes,

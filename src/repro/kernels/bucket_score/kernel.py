@@ -28,6 +28,16 @@ Two generations of the kernel live here:
     selects the block; a per-step ``(QT, 1)`` membership mask keeps each
     query's candidate set exactly its own probed buckets.
 
+    ``S`` is a static upper bound, so a tile's row ends in padded slots
+    that no query probes. Each row holds its live slots first, and a
+    scalar-prefetched per-tile live count gates the step body: a padded
+    step runs no matmul, no masks and no merge, and its index maps repeat
+    the last live block so the pipeline fetches nothing. That is exact,
+    not approximate — a padded slot's membership is all zero, so every
+    score of its block would be ``-inf``, and a merge of an all ``-inf``
+    block returns the accumulator unchanged (the accumulator wins ties,
+    and an ``-inf`` slot keeps id ``-1``).
+
 Quantised packs: an int8 bucket block stores symmetric per-bucket
 quantised values ``q = round(v / scale)`` with ``scale = absmax / 127``.
 Every int8 value is exactly representable in bf16, so the kernel casts
@@ -102,6 +112,7 @@ def bucket_score_kernel(
 def bucket_score_tiled_kernel(
     sched_ref,    # (n_tiles, S) int32 SMEM — scalar-prefetched schedules
     sc_ref,       # (n_buckets,) fp32 SMEM — per-bucket dequantisation scales
+    live_ref,     # (n_tiles,) int32 SMEM — live schedule slots per tile
     q_ref,        # (QT, D) VMEM — this tile's queries (fp32)
     bd_ref,       # (1, B, D) VMEM — the scheduled bucket (fp32/bf16/int8)
     bi_ref,       # (1, 1, B) int32 VMEM — its global doc ids (-1 pad)
@@ -118,33 +129,39 @@ def bucket_score_tiled_kernel(
         s_out[...] = jnp.full_like(s_out, -jnp.inf)
         i_out[...] = jnp.full_like(i_out, -1)
 
-    data = bd_ref[0]                                   # (B, D)
-    ids = bi_ref[0]                                    # (1, B)
-    q = q_ref[...]                                     # (QT, D)
-    precision = None
-    if data.dtype == jnp.int8:
-        # int8 pack: values in [-127, 127] are exact in bf16 — cast both
-        # operands, accumulate fp32, then dequantise the score block with
-        # the bucket's scalar scale (scale · Σ qᵀv, no extra rounding).
-        q = q.astype(jnp.bfloat16)
-        data = data.astype(jnp.bfloat16)
-    elif data.dtype != q.dtype:
-        # Half-precision pack: feed the MXU the storage dtype on both sides
-        # and accumulate fp32 (preferred_element_type).
-        q = q.astype(data.dtype)
-    else:
-        precision = jax.lax.Precision.HIGHEST
-    s = jax.lax.dot_general(                           # (QT, B) = q · dataᵀ
-        q, data, (((1,), (1,)), ((), ())),
-        precision=precision, preferred_element_type=jnp.float32,
-    )
-    if bd_ref.dtype == jnp.int8:
-        s = s * sc_ref[sched_ref[t_idx, s_idx]]
-    member = mb_ref[0] != 0                            # (QT, 1)
-    s = jnp.where(member, s, -jnp.inf)                 # not this query's probe
-    s = jnp.where(ids >= 0, s, -jnp.inf)               # bucket padding
-    s = jnp.where(ids == ex_ref[...], -jnp.inf, s)     # per-query exclusion
-    s_out[...], i_out[...] = _merge_topk(s_out[...], i_out[...], s, ids)
+    # Steps from the tile's live count on are schedule padding, which no
+    # query of the tile probes: skipping them changes no answer (module
+    # docstring).
+    @pl.when(s_idx < live_ref[t_idx])
+    def _score():
+        data = bd_ref[0]                               # (B, D)
+        ids = bi_ref[0]                                # (1, B)
+        q = q_ref[...]                                 # (QT, D)
+        precision = None
+        if data.dtype == jnp.int8:
+            # int8 pack: values in [-127, 127] are exact in bf16 — cast
+            # both operands, accumulate fp32, then dequantise the score
+            # block with the bucket's scalar scale (scale · Σ qᵀv, no
+            # extra rounding).
+            q = q.astype(jnp.bfloat16)
+            data = data.astype(jnp.bfloat16)
+        elif data.dtype != q.dtype:
+            # Half-precision pack: feed the MXU the storage dtype on both
+            # sides and accumulate fp32 (preferred_element_type).
+            q = q.astype(data.dtype)
+        else:
+            precision = jax.lax.Precision.HIGHEST
+        s = jax.lax.dot_general(                       # (QT, B) = q · dataᵀ
+            q, data, (((1,), (1,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32,
+        )
+        if bd_ref.dtype == jnp.int8:
+            s = s * sc_ref[sched_ref[t_idx, s_idx]]
+        member = mb_ref[0] != 0                        # (QT, 1)
+        s = jnp.where(member, s, -jnp.inf)             # not this query's probe
+        s = jnp.where(ids >= 0, s, -jnp.inf)           # bucket padding
+        s = jnp.where(ids == ex_ref[...], -jnp.inf, s) # per-query exclusion
+        s_out[...], i_out[...] = _merge_topk(s_out[...], i_out[...], s, ids)
 
 
 def _merge_topk(acc_s, acc_i, s, ids):

@@ -22,8 +22,11 @@ Schedules come in two flavours with identical semantics:
     *bucketed static* schedule length ``S`` (:func:`schedule_length`, powers
     of two) — the serving path, so large-batch search never round-trips the
     probe tensor HBM→host→HBM. Padded slots all point at bucket 0 with zero
-    membership; they are consecutive and equal so that the Pallas pipeline
-    need not fetch their block again (its effect on time is not measured).
+    membership and follow every live slot of their tile.
+
+``bucket_score_tiled`` counts each tile's live slots from ``member`` and
+skips the grid steps past them: no DMA, no matmul, no merge. A padded slot
+is one no query of the tile probes, so skipping it changes no answer.
 
 ``pick_query_tile`` sizes QT from the per-step VMEM bytes the TPU compiler
 counts (:func:`tile_vmem_bytes`); ``pack_bucket_major`` materialises the
@@ -151,6 +154,12 @@ def bucket_score_tiled(
     internally; the pad rows have an all-zero membership mask, so they score
     nothing and come back as ``(-inf, -1)`` before being sliced off.
 
+    The kernel runs the body of a grid step only up to the tile's last slot
+    that some query probes (``n_live``, counted here from ``member``), and
+    the steps past it repeat that slot's block indices, so the pipeline
+    fetches nothing for them. Both schedule builders put every live slot
+    before every padded one, so the steps skipped are exactly the padding.
+
     ``bucket_data`` may keep leading axes (the index's ``(T, K, B, D)``
     pack): they are flattened to ``K`` inside this jit, where the reshape is
     free, rather than by the caller, where it would copy the whole pack.
@@ -176,6 +185,18 @@ def bucket_score_tiled(
         scales = jnp.ones((n_clusters,), jnp.float32)
     if exclude is None:
         exclude = jnp.full((nq,), -1, jnp.int32)
+    member = member.astype(jnp.int32)
+    # one past each tile's last live slot (its live count: live slots come
+    # first); a slot past it has no member query and its step is skipped
+    n_live = jnp.max(
+        jnp.where(member.any(axis=-1),
+                  jnp.arange(1, s_len + 1, dtype=jnp.int32), 0),
+        axis=-1,
+    )
+
+    def live(t, ss, nl):         # a dead step repeats the last live block
+        return jnp.minimum(ss, jnp.maximum(nl[t] - 1, 0))
+
     pad = n_tiles * qt - nq
     qp = jnp.pad(queries, ((0, pad), (0, 0)))
     ep = jnp.pad(exclude.astype(jnp.int32), (0, pad), constant_values=-1)
@@ -186,24 +207,28 @@ def bucket_score_tiled(
         bucket_score_tiled_kernel,
         name="bucket_score_tiled",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((qt, d), lambda t, ss, sc, _: (t, 0)),
+                pl.BlockSpec((qt, d), lambda t, ss, sc, _, nl: (t, 0)),
                 pl.BlockSpec(
-                    (1, b, d), lambda t, ss, sc, _: (sc[t, ss], 0, 0)
+                    (1, b, d),
+                    lambda t, ss, sc, _, nl: (sc[t, live(t, ss, nl)], 0, 0),
                 ),
                 pl.BlockSpec(
-                    (1, 1, b), lambda t, ss, sc, _: (sc[t, ss], 0, 0)
+                    (1, 1, b),
+                    lambda t, ss, sc, _, nl: (sc[t, live(t, ss, nl)], 0, 0),
                 ),
                 pl.BlockSpec(
-                    (1, qt, 1), lambda t, ss, sc, _: (t * s_len + ss, 0, 0)
+                    (1, qt, 1),
+                    lambda t, ss, sc, _, nl: (
+                        t * s_len + live(t, ss, nl), 0, 0),
                 ),
-                pl.BlockSpec((qt, 1), lambda t, ss, sc, _: (t, 0)),
+                pl.BlockSpec((qt, 1), lambda t, ss, sc, _, nl: (t, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((qt, k_pad), lambda t, ss, sc, _: (t, 0)),
-                pl.BlockSpec((qt, k_pad), lambda t, ss, sc, _: (t, 0)),
+                pl.BlockSpec((qt, k_pad), lambda t, ss, sc, _, nl: (t, 0)),
+                pl.BlockSpec((qt, k_pad), lambda t, ss, sc, _, nl: (t, 0)),
             ],
         ),
         out_shape=[
@@ -219,10 +244,11 @@ def bucket_score_tiled(
     )(
         schedule.astype(jnp.int32),
         scales.astype(jnp.float32),
+        n_live,
         qp,
         bucket_data,
         bucket_ids.astype(jnp.int32)[:, None, :],
-        member.astype(jnp.int32).reshape(n_tiles * s_len, qt, 1),
+        member.reshape(n_tiles * s_len, qt, 1),
         ep[:, None],
     )
     return s[:nq, :k], i[:nq, :k]
@@ -318,8 +344,10 @@ def build_probe_schedule(
     Returns ``(schedule (n_tiles, S) int32, member (n_tiles, S, QT) int32)``
     with ``S`` the max per-tile unique count rounded up to ``pad_multiple``
     (bounds kernel re-tracing across batches). Padded schedule slots point
-    at bucket 0 with an all-zero membership mask; padded query rows
-    (``n_tiles·QT > nq``) have zero membership everywhere.
+    at bucket 0 with an all-zero membership mask and come after every live
+    slot of their tile — the prefix :func:`bucket_score_tiled` relies on to
+    skip them; padded query rows (``n_tiles·QT > nq``) have zero membership
+    everywhere.
 
     This is the data-derived-``S`` variant (and the oracle the device path
     is tested against); serving goes through
@@ -365,9 +393,10 @@ def build_probe_schedule_device(
     ``s_len`` is STATIC — callers size it with :func:`schedule_length`
     (power-of-two bucket of ``min(QT·P, n_buckets)``, an upper bound on any
     tile's unique count, so the scatter can never overflow). Unused slots
-    keep bucket 0 with zero membership, exactly like the host builder —
-    consecutive and equal, so that the Pallas pipeline need not fetch
-    their block again.
+    keep bucket 0 with zero membership, exactly like the host builder, and
+    the cumsum compaction puts every live slot before them: the kernel
+    skips the steps past a tile's live slots, fetching and computing
+    nothing for the padding of the static ``S``.
     """
     nq, p = probes.shape
     qt = int(query_tile)

@@ -138,6 +138,102 @@ def test_build_probe_schedule_device_matches_host(nq, qt, p, nb):
         assert np.all(ds[ti][~d_live] == 0)
 
 
+def _padded(sched, member, layout):
+    """The same schedule with three dead slots (bucket 0, no member query)
+    per live one: after the live slots (``tail``, the builders' layout), or
+    before each of them (``between``, a layout no builder makes)."""
+    n_tiles, s_len = sched.shape
+    qt = member.shape[-1]
+    ps = np.zeros((n_tiles, 4 * s_len), np.int32)
+    pm = np.zeros((n_tiles, 4 * s_len, qt), np.int32)
+    at = np.arange(s_len) if layout == "tail" else 4 * np.arange(s_len) + 3
+    ps[:, at], pm[:, at] = sched, member
+    return ps, pm
+
+
+@pytest.mark.parametrize("layout", ["tail", "between"])
+@pytest.mark.parametrize(
+    "pack", [None, jnp.bfloat16, jnp.int8],
+    ids=["float32", "bfloat16", "int8"],
+)
+def test_bucket_score_tiled_padded_slots_change_no_answer(pack, layout):
+    """Dead schedule slots are skipped, not scored, and the answer is the
+    tight schedule's bit for bit: every pack dtype, a per-query exclude, a
+    ragged last tile, and a live slot after a dead one still scored."""
+    K, B, D, P, k, nq, qt = 12, 24, 64, 3, 8, 13, 8
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    docs = jax.random.normal(ks[0], (K * B, D)) / np.sqrt(D)
+    buckets = jax.random.permutation(ks[1], K * B).reshape(K, B)
+    buckets = jnp.where(
+        jax.random.uniform(ks[2], (K, B)) < 0.25, -1, buckets
+    ).astype(jnp.int32)
+    bd, bi, sc = pack_bucket_major(docs, buckets, dtype=pack)
+    q = jax.random.normal(ks[3], (nq, D)) / np.sqrt(D)
+    probes = np.asarray(jax.random.randint(ks[4], (nq, P), 0, K))
+    ex = jnp.asarray(np.where(np.arange(nq) % 2 == 0,
+                              np.asarray(bi)[probes[:, 0], 1], -1),
+                     jnp.int32)
+    sched, member = build_probe_schedule(probes, qt, pad_multiple=1)
+    got = [
+        bucket_score_tiled(q, bd, bi, jnp.asarray(a), jnp.asarray(m), k=k,
+                           exclude=ex, scales=sc)
+        for a, m in [(sched, member), _padded(sched, member, layout)]
+    ]
+    (s0, i0), (s1, i1) = (tuple(map(np.asarray, g)) for g in got)
+    assert np.array_equal(s0, s1) and np.array_equal(i0, i1)
+    assert np.isfinite(s0).all() and (i0 >= 0).all()
+    # the exclusion held: no query's excluded document is in its answer
+    assert not (i0 == np.asarray(ex)[:, None]).any()
+
+
+def test_bucket_score_tiled_tile_with_no_live_slot():
+    """A tile none of whose queries probes anything (query padding, probes
+    all -1) comes back ``(-inf, -1)`` in every rank; the tile before it is
+    answered as the oracle answers."""
+    K, B, D, P, k, qt = 10, 16, 32, 3, 4, 8
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    bd = jax.random.normal(ks[0], (K, B, D))
+    bi = jnp.arange(K * B, dtype=jnp.int32).reshape(K, B)
+    q = jax.random.normal(ks[1], (2 * qt, D))
+    probes = jnp.concatenate([
+        jax.random.randint(ks[2], (qt, P), 0, K),
+        jnp.full((qt, P), -1, jnp.int32),
+    ])
+    sched, member = build_probe_schedule_device(
+        probes, query_tile=qt, s_len=schedule_length(qt, P, K))
+    assert not np.asarray(member)[1].any()
+    s, i = (np.asarray(x) for x in bucket_score_tiled(
+        q, bd, bi, sched, member, k=k))
+    assert np.all(s[qt:] == -np.inf) and np.all(i[qt:] == -1)
+    rs, ri = bucket_score_ref(q[:qt], bd, bi, probes[:qt], k)
+    np.testing.assert_allclose(s[:qt], np.asarray(rs), atol=1e-5, rtol=1e-6)
+    assert np.array_equal(np.sort(i[:qt]), np.sort(np.asarray(ri)))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_probe_schedules_put_live_slots_first(seed):
+    """Both schedule builders put every live slot (one some query of the
+    tile probes) before every dead one: the prefix the tiled kernel skips
+    the rest of. Random probe lists with ``-1`` entries, ragged tiles."""
+    rng = np.random.default_rng(seed)
+    nq, qt, p, nb = int(rng.integers(1, 40)), 8, 5, 30
+    probes = rng.integers(0, nb, (nq, p)).astype(np.int32)
+    probes[rng.random((nq, p)) < 0.4] = -1
+    probes[: min(nq, 3)] = -1                  # queries that probe nothing
+    built = [
+        build_probe_schedule(probes, qt),
+        build_probe_schedule_device(jnp.asarray(probes), query_tile=qt,
+                                    s_len=schedule_length(qt, p, nb)),
+    ]
+    for _, member in built:
+        live = np.asarray(member).any(axis=-1)            # (n_tiles, S)
+        n_live = live.sum(axis=1)
+        prefix = np.arange(live.shape[1])[None, :] < n_live[:, None]
+        assert np.array_equal(live, prefix)
+    # both builders find the same live count per tile
+    assert np.array_equal(*(np.asarray(m).any(-1).sum(1) for _, m in built))
+
+
 def test_quantize_bucket_major_error_bound():
     """Property test for the symmetric per-bucket int8 quantiser: every
     element round-trips within scale/2 (round-to-nearest), scales are

@@ -5,6 +5,10 @@ carry the same number, a compile leaves its marker inside the span that
 compiled, and the backend pick leaves its marker with what it chose by."""
 
 import asyncio
+import json
+import os
+import subprocess
+import sys
 import tempfile
 from pathlib import Path
 
@@ -15,7 +19,10 @@ import pytest
 
 from repro import tracing
 from repro.core import FieldSpec, Retriever, SearchRequest, normalize_fields
+from repro.kernels import schedule_length
 from repro.serving import SearchServer
+
+ROOT = Path(__file__).resolve().parents[1]
 
 SPANS = {v for k, v in vars(tracing).items()
          if k.isupper() and isinstance(v, str) and v.startswith("repro.")}
@@ -145,3 +152,57 @@ def test_pick_marker_carries_what_it_chose_by(events):
     # the 400-document index: T=3 clusterings of 6 buckets, 64 dims, fp32
     assert stats["pack_bytes"] > 0 and stats["pack_bytes"] % (3 * 6 * 64 * 4) == 0
     assert stats["bytes_limit"] == 0          # the CPU reports no limit
+
+
+def test_score_span_carries_the_grid_steps_launched(events):
+    """``slots`` on the fused engine's score span is the kernel's grid,
+    ``n_tiles · S``: every search of the trace is one tile of 8 queries
+    with 4 probes over the 3·6 buckets."""
+    scores = _named(events, tracing.ENGINE_SCORE)
+    assert scores
+    for e in scores:
+        assert "shards" not in e[4]
+        assert e[4]["slots"] == 1 * schedule_length(8, 4, 3 * 6), e[4]
+
+
+SHARDED = r"""
+import json, tempfile
+from pathlib import Path
+import jax
+from jax.profiler import ProfileData
+from repro import tracing
+from repro.core import FieldSpec, get_engine, normalize_fields
+from repro.core.index import ClusterPruneIndex
+
+assert jax.device_count() == 4
+spec = FieldSpec(names=("a", "b", "c"), dims=(16, 16, 32))
+docs = normalize_fields(
+    jax.random.normal(jax.random.PRNGKey(0), (300, spec.total_dim)), spec)
+index = ClusterPruneIndex.build(docs, spec, 6, key=jax.random.PRNGKey(1))
+engine = get_engine(index, "sharded")
+d = tempfile.mkdtemp()
+jax.profiler.start_trace(d)
+try:
+    s, _, _ = engine.search(docs[:19], probes=4, k=5)
+    s.block_until_ready()
+finally:
+    jax.profiler.stop_trace()
+data = ProfileData.from_file(str(sorted(Path(d).rglob("*.xplane.pb"))[-1]))
+print(json.dumps([dict(e.stats) for p in data.planes if p.name == "/host:CPU"
+                  for line in p.lines for e in line.events
+                  if e.name == tracing.ENGINE_SCORE]))
+"""
+
+
+def test_sharded_score_span_carries_slots_and_shards():
+    """On four (virtual CPU) devices the sharded engine's score span
+    carries the grid it launched on every shard and the shard count: 19
+    queries are one tile of 24, of 4 probes over the 3·6 buckets."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "PYTHONPATH": str(ROOT / "src")}
+    out = subprocess.run([sys.executable, "-c", SHARDED], capture_output=True,
+                         text=True, timeout=600, env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    stats, = json.loads(out.stdout.strip().splitlines()[-1])
+    assert stats == {"shards": 4, "slots": 1 * schedule_length(24, 4, 18)}
