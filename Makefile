@@ -20,18 +20,18 @@ bench:
 bench-preprocess:
 	PYTHONPATH=src python -m benchmarks.table1_preprocessing --scale quick
 
-# Serving QPS vs batch size: every backend, fused AND sharded swept over
-# the fp32/bf16/int8 bucket-major packs (labelled entries; interpret off-TPU)
+# Serving QPS vs batch size: every backend, fused swept over the
+# fp32/bf16/int8 bucket-major packs (labelled entries; interpret off-TPU)
 bench-throughput:
 	PYTHONPATH=src python -m benchmarks.throughput --scale quick
 
-# Sharded-fused path on a forced 4-device CPU mesh: per-shard bucket-major
+# The fused path over a forced 4-device CPU mesh: per-shard bucket-major
 # packs, QPS per pack dtype, and the bf16=1/2 / int8=1/4 packed-bytes-per-
 # query ratio checks (on TPU pods, drop XLA_FLAGS to use the real mesh)
 bench-sharded:
 	XLA_FLAGS="--xla_force_host_platform_device_count=4" \
 	PYTHONPATH=src python -m benchmarks.throughput --scale quick \
-		--backend sharded --batches 8
+		--backend fused --batches 8
 
 # Async serving tier under load: closed-loop (fixed concurrency) + open-loop
 # (fixed arrival rate) vs the sequential one-by-one baseline

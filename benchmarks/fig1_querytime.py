@@ -12,8 +12,8 @@ decomposition — not just the kernel. The CellDec baseline predates the
 engine seam and keeps its own direct path.
 
 Since the engine refactor, every probe budget is also timed across all
-registered search backends on the SAME built index (reference / fused /
-sharded) by tagging requests with ``backend=``, so the layout/mechanism
+registered search backends on the SAME built index (reference / fused) by
+tagging requests with ``backend=``, so the layout/mechanism
 cost is measured apples-to-apples. Note: off-TPU the fused backend runs the
 Pallas kernel in interpret mode — its wall time there is a correctness
 check, not a speed claim.
@@ -60,7 +60,7 @@ def run(scale: str = "quick", seed: int = 0, probe_grid=(3, 6, 9, 12, 18),
     key = jax.random.PRNGKey(seed)
 
     ours = ClusterPruneIndex.build(docs, spec, kc, n_clusterings=3,
-                                   method="fpf", key=key, pack_major=True)
+                                   method="fpf", key=key)
     retriever = Retriever(ours, backend="reference")
     if calibrate:
         # Annotate each timed probe budget with the calibrated planner's

@@ -134,8 +134,8 @@ def run_engines():
 
     Unlike the per-kernel rows above this times the full serving hot path
     (probe -> gather -> score -> merge) through the SearchEngine seam, so a
-    backend's layout cost (doc-major gather vs bucket-major block read vs
-    sharded local scoring) shows up end to end. Off-TPU the fused backend is
+    backend's layout cost (doc-major gather vs shard-local bucket-major
+    block reads) shows up end to end. Off-TPU the fused backend is
     interpret-mode Pallas — agreement is the signal there, not wall time.
     """
     from repro.core import (
@@ -146,8 +146,7 @@ def run_engines():
     key = jax.random.PRNGKey(1)
     spec = FieldSpec(names=("a", "b", "c"), dims=(64, 64, 128))
     docs = normalize_fields(jax.random.normal(key, (4096, 256)), spec)
-    idx = ClusterPruneIndex.build(docs, spec, 64, n_clusterings=3,
-                                  pack_major=True)
+    idx = ClusterPruneIndex.build(docs, spec, 64, n_clusterings=3)
     qw = docs[:16]
     ex = jnp.arange(16, dtype=jnp.int32)
 

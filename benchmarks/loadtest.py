@@ -46,7 +46,7 @@ import time
 import jax
 import numpy as np
 
-from repro.core import Retriever, SearchRequest
+from repro.core import Retriever, SearchRequest, get_engine
 from repro.launch.serve import build_retriever
 from repro.serving import (
     DeadlineExceeded,
@@ -633,9 +633,9 @@ def run(scale: str = "quick", seed: int = 0, *, backend: str = "auto",
         modes=("closed", "open")) -> list[dict]:
     """Build, load-test, return labelled entries for BENCH_query.json.
 
-    ``pack_dtype`` sets the bucket-major storage precision the fused and
-    sharded backends serve from (bf16/int8 shrink the packed bytes); every
-    entry is labelled with it (plus ``n_shards`` for the sharded backend)
+    ``pack_dtype`` sets the bucket-major storage precision the fused
+    backend serves from (bf16/int8 shrink the packed bytes); every entry is
+    labelled with it (plus the engine's ``n_shards`` where it has a mesh)
     so quantised serving rows never masquerade as fp32 ones.
     """
     sz = LOADTEST_SIZES[scale]
@@ -645,15 +645,13 @@ def run(scale: str = "quick", seed: int = 0, *, backend: str = "auto",
     retriever, docs, spec = build_retriever(
         n_docs, backend=backend, seed=seed, pack_dtype=pack_dtype,
     )
-    if retriever.backend == "fused":
-        retriever.index.ensure_bucket_major()
     requests = make_mix(n_docs, spec, n_requests, seed=seed)
     served = retriever.backend
     platform = jax.default_backend()
     print(f"\n# Loadtest — async serving tier vs sequential baseline "
           f"(n={n_docs}, {n_requests} requests, backend={served}, "
           f"pack_dtype={pack_dtype or 'float32'}, "
-          f"platform={platform}; fused/sharded interpret off-TPU)")
+          f"platform={platform}; fused interprets off-TPU)")
 
     # Sequential baseline on a FRESH facade: the served retriever's
     # request/response caches must not answer for the engine.
@@ -692,8 +690,9 @@ def run(scale: str = "quick", seed: int = 0, *, backend: str = "auto",
                   f"rejected={e['rejected']}")
     labels = {"backend": served, "platform": platform,
               "pack_dtype": pack_dtype or "float32"}
-    if served == "sharded":
-        labels["n_shards"] = jax.device_count()
+    engine = get_engine(retriever.index, served, **retriever.engine_opts)
+    if hasattr(engine, "n_shards"):
+        labels["n_shards"] = engine.n_shards
     entries.insert(0, seq)
     for e in entries:
         for key, val in labels.items():
@@ -715,7 +714,7 @@ def main():
                     help="--chaos: comma-separated fault profile names")
     ap.add_argument("--pack-dtype", default=None,
                     choices=[None, "float32", "bfloat16", "int8"],
-                    help="bucket-major storage precision the fused/sharded "
+                    help="bucket-major storage precision the fused "
                          "backend serves from (bf16 halves, int8 quarters "
                          "the packed bytes)")
     ap.add_argument("--docs", type=int, default=None,

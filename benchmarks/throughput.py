@@ -52,27 +52,15 @@ def _pad_to(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _query_tile_of(index, k: int) -> int | None:
-    """The tile the fused engine will pick for this index/k — None when the
-    bucket-major pack is absent (non-fused backends don't tile)."""
-    if index.bucket_data is None:
-        return None
-    t, kc, b, d = index.bucket_data.shape
-    return pick_query_tile(
-        d, b, k_pad=_pad_to(k, 8),
-        pack_itemsize=index.bucket_data.dtype.itemsize,
-    )
-
-
-def _sharded_pack_stats(engine, qw, probes: int, k: int):
-    """Per-query packed HBM bytes the sharded-fused path reads, plus the
-    serving tile. The byte count fixes the SCHEDULE to the fp32-sized tile
-    so rows differ only in storage itemsize — the controlled comparison
-    that makes an int8 pack read exactly ¼ the bytes of fp32 (the engine's
-    own tile can only be larger for a reduced-precision pack, i.e. fewer
-    duplicate block reads, so real traffic is at or below the reported
-    number). Every shard reads ITS (B_l, D) slice of each scheduled
-    bucket, hence the ``n_shards`` factor."""
+def _pack_stats(engine, qw, probes: int, k: int):
+    """Per-query packed HBM bytes the fused path reads, plus the serving
+    tile. The byte count fixes the SCHEDULE to the fp32-sized tile so rows
+    differ only in storage itemsize — the controlled comparison that makes
+    an int8 pack read exactly ¼ the bytes of fp32 (the engine's own tile
+    can only be larger for a reduced-precision pack, i.e. fewer duplicate
+    block reads, so real traffic is at or below the reported number).
+    Every shard reads ITS (B_l, D) slice of each scheduled bucket, hence
+    the ``n_shards`` factor."""
     from repro.kernels.bucket_score.ops import (
         build_probe_schedule_device, schedule_block_reads, schedule_length,
     )
@@ -81,13 +69,9 @@ def _sharded_pack_stats(engine, qw, probes: int, k: int):
     n_shards, n_buckets, b_l, d = (int(x) for x in data.shape)
     nq = int(qw.shape[0])
     flat = engine._flat_probes(qw, engine._probes_t(probes))
-    qt_serve = engine.query_tile
-    if qt_serve is None:
-        qt_serve = min(
-            pick_query_tile(d, b_l, k_pad=_pad_to(k, 8),
-                            pack_itemsize=data.dtype.itemsize),
-            _pad_to(nq, 8),
-        )
+    qt_serve = engine.tile_for(k)
+    if engine.query_tile is None:
+        qt_serve = min(qt_serve, _pad_to(nq, 8))
     qt_sched = min(
         pick_query_tile(d, b_l, k_pad=_pad_to(k, 8), pack_itemsize=4),
         _pad_to(nq, 8),
@@ -104,12 +88,12 @@ def _sharded_pack_stats(engine, qw, probes: int, k: int):
 def run(scale: str = "quick", seed: int = 0, batch_sizes=BATCH_SIZES,
         backends=None, pack_dtypes=(None, "bfloat16", "int8"),
         rescore=None):
-    """Returns a list of labelled throughput entries. The fused AND sharded
-    backends are measured once per pack dtype (re-packing the SAME index,
-    so clustering is held fixed); sharded rows additionally carry
-    ``n_shards`` and ``packed_bytes_per_query`` (the shard-local block
-    bytes the probe-dedup schedule reads — bf16 exactly ½, int8 exactly ¼
-    of the fp32 row, asserted). Reference scores fp32 docs, one row."""
+    """Returns a list of labelled throughput entries. The fused backend is
+    measured once per pack dtype (re-packing the SAME index, so clustering
+    is held fixed); its rows carry ``n_shards`` and
+    ``packed_bytes_per_query`` (the shard-local block bytes the probe-dedup
+    schedule reads — bf16 exactly ½, int8 exactly ¼ of the fp32 row,
+    asserted). Reference scores fp32 docs, one row."""
     sz = bench_sizes(scale)
     docs_np, spec, _ = make_corpus(CorpusConfig(
         n_docs=sz["n_docs"], field_dims=sz["field_dims"],
@@ -120,7 +104,7 @@ def run(scale: str = "quick", seed: int = 0, batch_sizes=BATCH_SIZES,
     docs = jnp.asarray(docs_np)
     index = ClusterPruneIndex.build(
         docs, spec, sz["k_clusters"], n_clusterings=3, method="fpf",
-        key=jax.random.PRNGKey(seed), pack_major=True,
+        key=jax.random.PRNGKey(seed),
     )
     # One query draw PER BATCH SIZE, shared by every backend × pack-dtype
     # row — rows at the same batch are measured on identical queries (and
@@ -142,29 +126,18 @@ def run(scale: str = "quick", seed: int = 0, batch_sizes=BATCH_SIZES,
           "p50_ms_per_query,p99_ms_per_query")
     entries = []
     for name in backends:
-        # BOTH tiled backends sweep the pack dtypes — the sharded path
-        # scores from shard-local bf16/int8 packs exactly like fused does
-        # from the global one; reference scores fp32 docs and gets one row.
-        dtypes = pack_dtypes if name in ("fused", "sharded") else (None,)
-        for pd in dtypes:
-            if pd is None:
-                idx = index
-            else:
-                idx = dataclasses.replace(
-                    index, bucket_data=None, bucket_scales=None,
-                    pack_dtype=pd,
-                )
-                if name == "fused":
-                    idx.ensure_bucket_major()
+        # the tiled backend sweeps the pack dtypes; reference scores fp32
+        # docs and gets one row
+        tiled = get_engine(index, name).tile_for(K_NN) is not None
+        for pd in pack_dtypes if tiled else (None,):
+            idx = (index if pd is None
+                   else dataclasses.replace(index, pack_dtype=pd))
             engine = get_engine(idx, name)
-            qt = _query_tile_of(idx, K_NN) if name == "fused" else None
             label = pd or "float32"
             # Off-TPU the tiled kernel interprets (a correctness smoke, not
             # a speed claim) — two repeats bound the wall cost of the
-            # sharded sweep without changing what the entries verify.
-            reps = (
-                2 if name == "sharded" and platform != "tpu" else REPEATS
-            )
+            # sweep without changing what the entries verify.
+            reps = 2 if tiled and platform != "tpu" else REPEATS
             for bs in batch_sizes:
                 qids = qids_by_bs[bs]
                 qw = docs[jnp.asarray(qids)]
@@ -186,14 +159,12 @@ def run(scale: str = "quick", seed: int = 0, batch_sizes=BATCH_SIZES,
                         float(np.percentile(per_query_ms, 50)), 3),
                     "p99_ms_per_query": round(
                         float(np.percentile(per_query_ms, 99)), 3),
-                    "pack_dtype": label, "query_tile": qt,
+                    "pack_dtype": label, "query_tile": None,
                     "rescore": rescore, "platform": platform,
                 }
-                if name == "sharded":
-                    per_q, qt_s = _sharded_pack_stats(
-                        engine, qw, PROBES, K_NN
-                    )
-                    entry["query_tile"] = qt_s
+                if tiled:
+                    per_q, qt = _pack_stats(engine, qw, PROBES, K_NN)
+                    entry["query_tile"] = qt
                     entry["n_shards"] = engine.n_shards
                     entry["packed_bytes_per_query"] = per_q
                 entries.append(entry)
@@ -201,18 +172,17 @@ def run(scale: str = "quick", seed: int = 0, batch_sizes=BATCH_SIZES,
                       f"{entry['qps']:.1f},"
                       f"{entry['p50_ms_per_query']:.3f},"
                       f"{entry['p99_ms_per_query']:.3f}")
-    _check_sharded_pack_ratio(entries)
+    _check_pack_ratio(entries)
     return entries
 
 
-def _check_sharded_pack_ratio(entries):
-    """Regression gate: at the same batch, a sharded int8 pack must read
-    exactly ¼ (and bf16 exactly ½) the packed bytes of sharded fp32 — the
-    schedule is held fixed, so only the storage itemsize may differ."""
+def _check_pack_ratio(entries):
+    """Regression gate: at the same batch, an int8 pack must read exactly
+    ¼ (and bf16 exactly ½) the packed bytes of fp32 — the schedule is held
+    fixed, so only the storage itemsize may differ."""
     by = {
         (e["batch"], e["pack_dtype"]): e["packed_bytes_per_query"]
-        for e in entries
-        if e["backend"] == "sharded" and "packed_bytes_per_query" in e
+        for e in entries if "packed_bytes_per_query" in e
     }
     checked = 0
     for (bs, pd), v in by.items():
@@ -221,12 +191,12 @@ def _check_sharded_pack_ratio(entries):
             continue
         want = {"bfloat16": 2.0, "int8": 4.0}[pd]
         assert abs(base / v - want) < 1e-6, (
-            f"sharded {pd} packed bytes/query {v} is not 1/{want:.0f} of "
+            f"{pd} packed bytes/query {v} is not 1/{want:.0f} of "
             f"fp32 ({base}) at batch {bs}"
         )
         checked += 1
     if checked:
-        print(f"# sharded pack-dtype byte ratios verified "
+        print(f"# pack-dtype byte ratios verified "
               f"({checked} entries: bf16=1/2, int8=1/4 of fp32)")
 
 
@@ -244,10 +214,10 @@ if __name__ == "__main__":
              "prices the fp32 gather+matmul re-rank into the QPS numbers")
     parser.add_argument(
         "--backend", default=None,
-        choices=[None, "reference", "fused", "sharded"],
+        choices=[None, "reference", "fused"],
         help="measure ONE backend (default sweeps all registered ones); "
              "combine with XLA_FLAGS=--xla_force_host_platform_device_count"
-             "=N to exercise the sharded-fused path on a forced CPU mesh")
+             "=N to run the fused path over an N-device CPU mesh")
     parser.add_argument(
         "--batches", default=None,
         help="comma-separated batch sizes (default 1,8,64) — smokes trim "

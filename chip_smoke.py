@@ -21,9 +21,9 @@ platform picks (``fpf_fused`` builds, ``fused`` serves), then:
 
 ``--chips 4`` runs only the multi-chip path and what it is compared with:
 TS2 (100,000 docs, K=1000; its fp32 pack does not fit one chip) built with
-``backend="auto"``, which has to resolve to ``sharded``, then phases (a)-(e)
-as above, with each device's share of the pack and its bytes in use
-printed.
+``backend="auto"``, which has to resolve to ``fused`` over all four chips,
+then phases (a)-(e) as above, with each device's share of the pack and its
+bytes in use printed.
 
 Any failed check raises, so the script exits non-zero. It also exits
 non-zero, printing no result, where JAX finds no TPU. The last line of a
@@ -231,8 +231,9 @@ def run_phases(
     ]
     out.update(compile_s=clock.seconds, cache_hits=clock.cache_hits,
                bytes_in_use=in_use)
-    if retriever.backend == "sharded":
-        n_sh = get_engine(index, "sharded").n_shards
+    engine = get_engine(index, retriever.backend, **retriever.engine_opts)
+    n_sh = out["shards"] = getattr(engine, "n_shards", 1)
+    if n_sh > 1:
         data = index.ensure_local_bucket_major(n_sh)[0]
         shares = sorted(
             (str(s.device), int(s.data.nbytes)) for s in data.addressable_shards
@@ -243,7 +244,7 @@ def run_phases(
             f"pack shards {shares} are not one {n_sh}th each",
         )
         out["pack_share_bytes"] = shares[0][1]
-        log(f"[e] sharded pack: {data.nbytes} bytes, {shares[0][1]} on "
+        log(f"[e] pack: {data.nbytes} bytes, {shares[0][1]} on "
             f"each of {n_sh} devices")
     log(f"[e] device={devs[0].device_kind} x{len(devs)} compile "
         f"{clock.seconds:.2f} s ({clock.cache_hits} persistent-cache hits) "
@@ -254,7 +255,7 @@ def run_phases(
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="1: TS1 on the platform defaults; 4: TS2 sharded")
+                    help="1: TS1 on the platform defaults; 4: TS2 over four")
     args = ap.parse_args(argv)
     try:
         from repro.launch.compile_cache import enable_compile_cache
@@ -276,14 +277,15 @@ def main(argv=None) -> int:
         return 1
     if args.chips == 1:
         out = run_phases(corpus_config("ts1"))
-        _check(out["clusterer"] == "fpf_fused" and out["backend"] == "fused",
-               f"platform picked {out['clusterer']} + {out['backend']}, "
-               "not fpf_fused + fused")
+        _check(out["clusterer"] == "fpf_fused" and out["backend"] == "fused"
+               and out["shards"] == 1,
+               f"platform picked {out['clusterer']} + {out['backend']} over "
+               f"{out['shards']} devices, not fpf_fused + fused over one")
     else:
         out = run_phases(corpus_config("ts2"))
-        _check(out["backend"] == "sharded",
-               f"TS2 on {len(devs)} chips resolved to {out['backend']}, "
-               "not sharded")
+        _check(out["backend"] == "fused" and out["shards"] == len(devs),
+               f"TS2 on {len(devs)} chips resolved to {out['backend']} over "
+               f"{out['shards']} devices, not fused over {len(devs)}")
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs),

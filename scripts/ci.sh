@@ -72,11 +72,11 @@ if [[ "$FAST" == 0 ]]; then
   PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
     python -m benchmarks.throughput --scale quick --backend fused \
       --pack-dtype int8 --rescore 20
-  echo "[ci] smoke: sharded-fused throughput (4-device forced CPU mesh; hard-"
+  echo "[ci] smoke: fused throughput over a 4-device forced CPU mesh (hard-"
   echo "      checks the bf16=1/2 / int8=1/4 packed-bytes-per-query ratios)"
   XLA_FLAGS="--xla_force_host_platform_device_count=4" \
     PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m benchmarks.throughput --scale quick --backend sharded --batches 8
+    python -m benchmarks.throughput --scale quick --backend fused --batches 8
   echo "[ci] smoke: async serving tier (micro-batching, parity vs one-by-one)"
   PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
     python -m repro.launch.serve --serve --docs 2000 --queries 64

@@ -9,9 +9,9 @@ Layers (top first — the typed API is the public surface):
               incremental add_documents/remove_documents maintenance
   celldec     CellDec weight-region baseline [Singitham et al. VLDB'04]
   metrics     competitive recall, NAG, brute-force ground truth
-  engine      pluggable SearchEngine backends: reference / fused / sharded
+  engine      pluggable SearchEngine backends: reference / fused (1..N devices)
   calibrate   per-index recall->probes ladder (sample -> sweep -> isotonic fit)
-  distributed shard_map substrate consumed by the "sharded" backend
+  distributed shard_map substrate of the "fused" backend's mesh
 """
 
 from .fields import FieldSpec, concat_fields, normalize_fields, split_fields
@@ -41,7 +41,7 @@ from .cluster import (
 )
 from .index import (
     LADDER_DRIFT_THRESHOLD, SUPPORTED_PACK_DTYPES, ClusterPruneIndex,
-    CorruptIndexError, pack_buckets, pack_buckets_major, validate_pack_dtype,
+    CorruptIndexError, pack_buckets, validate_pack_dtype,
 )
 from .engine import (
     BACKENDS,
@@ -50,6 +50,7 @@ from .engine import (
     get_engine,
     pick_backend,
     register_backend,
+    resolve_backend,
     split_probes,
     sweep_probes,
 )
@@ -87,10 +88,10 @@ __all__ = [
     "CLUSTERERS", "Clusterer", "assign_refine", "available_clusterers",
     "get_clusterer", "pick_clusterer", "register_clusterer",
     "ClusterPruneIndex", "CorruptIndexError", "LADDER_DRIFT_THRESHOLD",
-    "pack_buckets",
-    "pack_buckets_major", "validate_pack_dtype", "SUPPORTED_PACK_DTYPES",
+    "pack_buckets", "validate_pack_dtype", "SUPPORTED_PACK_DTYPES",
     "BACKENDS", "SearchEngine", "available_backends", "get_engine",
-    "pick_backend", "register_backend", "split_probes", "sweep_probes",
+    "pick_backend", "register_backend", "resolve_backend", "split_probes",
+    "sweep_probes",
     "ProbeLadder", "calibrate_index", "isotonic_fit",
     "CellDecIndex", "region_of", "region_weights",
     "brute_force_bottomk", "brute_force_topk", "competitive_recall",

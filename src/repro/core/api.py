@@ -189,13 +189,12 @@ def exec_shape(
     at all (no calibrated ladder) only the exact tier can state the
     guarantee, so the request resolves there.
     """
+    from .engine import resolve_backend
+
     backend = req.backend or default_backend
     if backend == "auto":
         backend = default_backend
-    if backend in (None, "auto"):
-        from .engine import pick_backend
-
-        backend = pick_backend(index)
+    backend = resolve_backend(index, backend)
     if req.exact:
         if total_probes is None:
             raise ValueError(
@@ -272,16 +271,15 @@ class SearchRequest:
     lets :func:`plan_probes` choose it; setting both is an error, setting
     neither uses the retriever's default. ``backend`` overrides the
     retriever's engine choice for this request only (``"auto"`` picks
-    ``fused`` on TPU, ``sharded`` on any multi-device host — the latter
-    scores shard-local quantised packs and merges one top-k collective).
+    ``fused`` on a TPU or a multi-device host, with the shard count by
+    size, else ``reference``).
     ``rescore`` (>= k)
     opts into the exact-rescore tail: the pruned search runs at that depth
     and the surviving candidates are re-scored against the fp32 corpus
     before the final top-k cut — bounding quantised-storage noise
     (``pack_dtype="bfloat16"``/``"int8"``) at the cost of one extra
-    gather+matmul, honestly charged to ``n_scored`` (on the sharded
-    backend the rescore itself is distributed over the row-sharded
-    corpus).
+    gather+matmul, honestly charged to ``n_scored`` (on ``fused`` the
+    rescore itself is distributed over the row-sharded corpus).
 
     Two tiered modes turn predictions into guarantees. ``exact=True``
     sweeps ALL T·K buckets (the clustered exact pass) — the answer is the
@@ -557,12 +555,10 @@ class Retriever:
                  default_probes: int = 12, calibrate: bool = False,
                  calibrate_opts: Mapping | None = None,
                  engine_opts: Mapping | None = None):
-        from .engine import pick_backend
+        from .engine import resolve_backend
 
         self.index = index
-        self.backend = (
-            pick_backend(index) if backend in (None, "auto") else backend
-        )
+        self.backend = resolve_backend(index, backend)
         self.default_probes = default_probes
         # Engine construction knobs for the DEFAULT backend (e.g.
         # ``{"query_tile": 16}`` for the fused backend's v2 tiling, or

@@ -224,7 +224,7 @@ def calibrate_index(
     removed-mask bookkeeping is needed (and the curve stays unbiased).
     """
     from .engine import (
-        _SWEEP_GATHER_BYTES, get_engine, pick_backend, sweep_probes,
+        _SWEEP_GATHER_BYTES, get_engine, resolve_backend, sweep_probes,
     )
     from .metrics import recall_fraction
     from .weights import weighted_query
@@ -271,7 +271,7 @@ def calibrate_index(
     # walk can never surface a tombstoned doc. The reference backend's
     # query chunk shrinks like sweep_probes' per-level rule, at the T·K
     # budget, so the (qchunk, candidates, D) gather stays bounded.
-    name = pick_backend(index) if backend in (None, "auto") else backend
+    name = resolve_backend(index, backend)
     gt_opts = dict(engine_opts or {})
     if name == "reference" and "qchunk" not in gt_opts:
         b = int(index.buckets.shape[-1])

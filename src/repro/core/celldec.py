@@ -98,9 +98,6 @@ class CellDecIndex:
                 n_clusterings=n_clusterings,
                 method=method,
                 key=sub,
-                # Region indexes are searched via the reference path only —
-                # never pay for the fused backend's bucket-major layout.
-                pack_major=False,
                 **clusterer_kwargs,
             )
             # Faithful to [18]: the region index stores ONLY the squeezed

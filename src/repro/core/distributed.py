@@ -1,46 +1,36 @@
-"""``shard_map`` substrate of the **sharded** search backend.
+"""``shard_map`` substrate of the ``fused`` engine's mesh.
 
-This module is no longer a parallel, self-standing search API: it supplies
-the collective primitives, the shard-local bucket-major packing, and the
-doc-sharded search kernels that :class:`repro.core.engine.ShardedEngine`
-wraps. Consumers should go through ``get_engine(index, "sharded")`` (or
-``backend="sharded"`` on ``ClusterPruneIndex.search``), which layers the
-shared probe-splitting, exclude-masking, and ``n_scored`` accounting on
-top; the functions here stay public for the distributed tests and for the
-exact brute-force baseline used by the ``retrieval_cand`` serving cells.
+The collective primitives, the shard-local bucket-major pack and the
+shard-local scoring that :class:`repro.core.engine.FusedEngine` runs over a
+mesh of S >= 1 devices. Go through ``get_engine(index, "fused")``, which adds
+probe splitting, exclude masking and ``n_scored`` accounting. The functions
+here stay public for the distributed tests and for the exact brute-force
+baseline.
 
-Layout (DESIGN.md §4/§6):
+Layout:
 
-* **docs** row-sharded over the ``shard_axes`` (``("pod", "data")`` on the
-  production mesh) — every device owns an ``n/devices`` slice. Corpora that
-  do not divide evenly are padded with sentinel rows (zero vectors that no
-  bucket ever references), so ANY corpus size shards cleanly.
+* **docs** row-sharded over the ``shard_axes``: every device owns an
+  ``n / devices`` slice. Corpora that do not divide evenly pad with sentinel
+  rows (zero vectors no bucket references), so any corpus size shards.
 * **leaders** replicated: ``T*K`` representatives are tiny (K ~ sqrt(n)).
-* **buckets** are *local*: each device packs its own slice of every cluster,
-  so probing cluster ``c`` touches every shard's local members of ``c`` —
-  search work stays embarrassingly parallel and perfectly balanced. The
-  fused path additionally packs each shard's slice **bucket-major**
-  (:func:`pack_local_bucket_major`): a ``(S, T·K, B_l, D)`` tensor in the
-  index's ``pack_dtype`` storage precision (bf16 halves, int8 quarters the
-  per-shard HBM bytes, with per-``(shard, bucket)`` dequantisation scales),
-  so a probed bucket is a contiguous device-local block DMA feeding a
-  ``(QT, D)×(D, B_l)`` MXU matmul — the single-device fused v2 hot path,
-  run shard-locally (:func:`distributed_bucket_score`).
-* **navigation is replicated and runs ONCE**: leaders are global, so the
-  probe sets (and the fused path's probe-dedup schedule) are identical on
-  every shard — they are computed outside the ``shard_map`` body and passed
-  in, never re-derived per shard.
-* the only collective is the final **top-k merge**: ``all_gather`` of
-  ``(k,)`` scores+ids per device (2·k·4 bytes each — collective-light by
-  construction), then a replicated merge.
+* **buckets** local: each device packs its own slice of every cluster
+  bucket-major (:func:`pack_local_bucket_major`), a ``(S, T·K, B_l, D)``
+  tensor in the index's ``pack_dtype`` (int8 with per-``(shard, bucket)``
+  scales). A probed bucket is a contiguous device-local block feeding a
+  ``(QT, D)×(D, B_l)`` MXU matmul (:func:`distributed_bucket_score`). At
+  S=1 this is the whole corpus's pack.
+* **navigation runs once, replicated**: leaders are global, so probe sets
+  and the probe-dedup schedule are the same on every shard. They are
+  computed outside the ``shard_map`` body and passed in.
+* the only collective is the final **top-k merge**: ``all_gather`` of the
+  per-shard ``(k,)`` scores and ids, then a replicated merge.
 
-The same module provides the brute-force distributed top-k used by the
-``retrieval_cand`` serving cells and as the exact baseline, plus the
-sharded exact-rescore tail (:func:`distributed_exact_rescore`): candidates
-are re-scored against the row-sharded fp32 corpus — each shard scores the
-candidates it owns, a single ``pmax`` all-reduce merges the score matrix —
-so quantised sharded packs meet the same quality floors as single-device
-packs without ever gathering the corpus.
+The module also holds the brute-force distributed top-k (the exact
+baseline) and the sharded exact-rescore tail
+(:func:`distributed_exact_rescore`): candidates are re-scored against the
+row-sharded fp32 corpus, each shard scoring the candidates it owns, and one
+``pmax`` all-reduce merges the score matrix, so the corpus is never
+gathered.
 """
 
 from __future__ import annotations
@@ -295,10 +285,12 @@ def distributed_index_search(
     return jax.jit(fn)(*args)
 
 
-# ------------------------------------------------- fused shard-local scoring
+# ------------------------------------------------------ shard-local scoring
 @functools.lru_cache(maxsize=128)
 def _bucket_score_fn(mesh, axes, k, k_out, n_local, interpret):
-    """Build (once per static config) the jitted shard_map fused scorer.
+    """Build (once per static config) the jitted shard_map scorer, the
+    program ``jit_shard_score_merge``: each shard's kernel, the all-gather
+    and the merge.
 
     Caching the callable is what makes the hot path trace-stable: ``jit``
     keys on function identity, so a fresh closure per search would retrace
@@ -307,7 +299,8 @@ def _bucket_score_fn(mesh, axes, k, k_out, n_local, interpret):
     """
     from ..kernels.bucket_score import bucket_score_tiled
 
-    def kernel(data_l, ids_l, sc_l, qw_r, sched_r, member_r, ex_r):
+    def shard_score_merge(data_l, ids_l, sc_l, qw_r, sched_r, member_r,
+                          ex_r):
         sidx = jax.lax.axis_index(axes)
         offset = (sidx * n_local).astype(jnp.int32)
         # global -> local exclusion: only the shard owning the excluded id
@@ -324,7 +317,7 @@ def _bucket_score_fn(mesh, axes, k, k_out, n_local, interpret):
         return merge_topk(s_all, i_all, k_out)
 
     return jax.jit(jax.shard_map(
-        kernel,
+        shard_score_merge,
         mesh=mesh,
         in_specs=(
             P(axes, None, None, None), P(axes, None, None), P(axes, None),
@@ -350,7 +343,7 @@ def distributed_bucket_score(
     exclude: jnp.ndarray | None = None,
     interpret: bool | None = None,
 ):
-    """Fused v2 scoring run shard-locally: the multi-device fused hot path.
+    """The ``fused`` engine's scoring step, shard-locally on S >= 1 devices.
 
     Each shard runs :func:`~repro.kernels.bucket_score.ops
     .bucket_score_tiled` over ITS slice of every probed bucket — the same
@@ -361,7 +354,7 @@ def distributed_bucket_score(
     identical across shards — navigation is global), so the only collective
     is the 2k-word ``all_gather`` + merge. A per-shard candidate union is
     exactly the shard's slice of the global candidate set, so the merged
-    top-k equals the single-device fused answer.
+    top-k equals the one-device answer.
 
     ``scales`` carries the per-``(shard, bucket)`` dequantisation factors
     of an int8 pack (quantised shard-locally — see
@@ -503,16 +496,13 @@ def build_local_buckets(assign_global, n, n_shards, k_clusters):
 def _pack_shard(docs_l, ids_l, *, dtype):
     """One shard's slice of the pack: ``(n_local, D)`` rows + ``(T·K, B_l)``
     local ids -> ``(1, T·K, B_l, D)`` data and ``(1, T·K)`` int8 scales |
-    None, with the shard axis already in place so the slice can join a
-    global array without a copy."""
-    from ..kernels.bucket_score.ops import quantize_bucket_major
+    None (:func:`~repro.kernels.bucket_score.ops.pack_bucket_major`), with
+    the shard axis already in place so the slice can join a global array
+    without a copy."""
+    from ..kernels.bucket_score.ops import pack_bucket_major
 
-    data = docs_l[jnp.where(ids_l >= 0, ids_l, 0)][None]
-    if dtype == "int8":
-        return quantize_bucket_major(data)
-    if dtype is not None:
-        data = data.astype(jnp.dtype(dtype))
-    return data, None
+    data, _, scales = pack_bucket_major(docs_l, ids_l[None], dtype=dtype)
+    return data, scales
 
 
 def pack_local_bucket_major(
@@ -524,7 +514,7 @@ def pack_local_bucket_major(
     dtype=None,
     sharding: NamedSharding | None = None,
 ):
-    """Shard-local bucket-major pack: the fused v2 layout, one slice per shard.
+    """Shard-local bucket-major pack: the kernel's layout, one slice per shard.
 
     Reuses :func:`build_local_buckets`' each-device-owns-its-slice-of-every-
     cluster layout, then materialises each shard's slice bucket-major:
@@ -544,7 +534,10 @@ def pack_local_bucket_major(
 
     ``B_l`` is the max local bucket size over all shards (sublane-padded),
     typically ``~B / n_shards`` — a smaller per-shard block, which buys the
-    fused kernel a LARGER query tile out of the same VMEM budget.
+    kernel a LARGER query tile out of the same VMEM budget. At one shard
+    local ids are global ids, and on a freshly built index the pack is
+    :func:`~repro.kernels.bucket_score.ops.pack_bucket_major` of its
+    buckets.
 
     ``sharding`` (a ``NamedSharding`` of the 4-D data over the shard axes)
     builds each shard's slice ON the device that holds it and returns

@@ -1,76 +1,48 @@
-"""Pluggable search-engine layer — ONE seam over the three search paths.
+"""The search engines: one seam, two backends.
 
-The paper's hot path (probe T clusterings, score the probed buckets, merge a
-deduplicated top-k) historically existed three times: a pure-JAX gather path,
-a fused Pallas kernel that was never wired into serving, and a ``shard_map``
-distributed path with its own API. This module unifies them behind a single
-:class:`SearchEngine` protocol with three registered backends:
+Both run the paper's hot path: probe the T clusterings, score the probed
+buckets, and merge a deduplicated top-k.
 
 ``reference``
-    Pure-JAX doc-major gather (:func:`_search_block`) — the single-host
-    portable path and the semantics oracle for the other two.
+    Pure-JAX doc-major gather (:func:`_search_block`), the semantics
+    oracle.
 ``fused``
-    The query-tiled Pallas ``bucket_score`` v2 kernel over the bucket-major
-    ``(T*K, B, D)`` corpus materialised at index build time (interpret-mode
-    off-TPU): probes are contiguous block DMAs instead of row gathers, a
-    per-tile probe-dedup schedule — built ON DEVICE under ``jit``
-    (:func:`~repro.kernels.bucket_score.ops.build_probe_schedule_device`,
-    no host round-trip in the hot path) — reads each shared bucket from HBM
-    once per query tile, and each block is scored against the whole tile as
-    one ``(QT, D)×(D, B)`` MXU matmul (optionally over bf16 or int8 bucket
-    storage with fp32 accumulation; int8 packs dequantise per bucket via the
-    index's ``bucket_scales``).
-``sharded``
-    The ``shard_map`` doc-sharded path of :mod:`repro.core.distributed`,
-    running the SAME fused v2 kernel shard-locally: each device holds a
-    bucket-major ``(T*K, B_local, D)`` pack of its slice of every cluster
-    (``pack_dtype`` bf16/int8 supported, per-``(shard, bucket)`` scales),
-    navigation and the probe-dedup schedule are computed once (replicated —
-    probed buckets are identical across shards), and the only collective is
-    the 2k-word per-shard top-k merge. Any corpus size shards cleanly
-    (sentinel-row padding); the exact-rescore tail re-ranks against the
-    row-sharded fp32 corpus without gathering it.
+    The query-tiled Pallas ``bucket_score`` kernel over a mesh of S >= 1
+    devices. Each device holds a bucket-major ``(T*K, B_l, D)`` pack of its
+    row slice of every bucket, in the index's ``pack_dtype``. Navigation and
+    the probe-dedup schedule run once, replicated. Each shard scores its
+    blocks, and one ``all_gather`` of the per-shard top-k merges the answer.
+    At S=1 the pack is the whole corpus's and the merge is over one shard.
 
-All backends share *identical* probe semantics (:func:`split_probes` divides
-the budget evenly over the T clusterings), navigation-vs-scoring query split,
-duplicate suppression across overlapping clusterings, ``exclude`` masking,
-and the paper's Fig-1 ``n_scored`` distance-computation accounting — so
-every consumer (serving, benchmarks, examples) measures the same algorithm
-and differs only in the execution mechanism.
+Both backends share the probe semantics (:func:`split_probes` divides the
+budget evenly over the T clusterings), the navigation-vs-scoring query
+split, duplicate suppression across overlapping clusterings, ``exclude``
+masking, and the paper's Fig-1 ``n_scored`` accounting.
 
-All backends also share the opt-in **exact-rescore tail**
+Both also share the opt-in **exact-rescore tail**
 (``search(..., rescore=R)``, ``R >= k``): the pruned search runs at depth
-``R``, the surviving candidates are re-scored against the fp32 doc-major
-corpus in one gather+matmul (:func:`_exact_rescore`), and the final top-k
-cut happens on those exact scores. This bounds whatever noise a reduced
-storage precision injected — the returned ORDER and SCORES are exact for
-the candidate set the pruned search surfaced — and the re-scored
-candidates are honestly charged to ``n_scored``.
+``R``, the candidates are re-scored against the fp32 corpus, and the final
+top-k is cut on those exact scores. The re-scored candidates are charged to
+``n_scored``.
 
 On top of the budgeted path every backend exposes the **tiered exact
-path** (``search_exact``): probe ALL T·K buckets, so every live document
-is a candidate and the result is the true top-k — the same clustering
-that prunes approximate search also organises exact search into
-best-first bucket blocks (Dimond & Sanders). Backends that score from a
+path** (``search_exact``): probe all T·K buckets, so every live document is
+a candidate and the result is the true top-k. Backends that score from a
 reduced-precision pack (``uses_packed_storage``) finish the exact tier
-through the fp32 rescore tail so returned ids/scores stay exact. The
-**escalation driver** (``search_escalating``) makes a calibrated recall
-floor a guarantee instead of a prediction: run the planned budget, and
-while the ladder's ``predicted_recall`` sits below the floor, re-run at
-the next calibrated rung — ultimately the exact tier — charging every
-tier's candidates cumulatively to ``n_scored``.
+through the fp32 rescore tail. The **escalation driver**
+(``search_escalating``) makes a calibrated recall floor a guarantee: run the
+planned budget, and while the ladder's ``predicted_recall`` sits below the
+floor, re-run at the next calibrated rung, ultimately the exact tier,
+charging every tier's candidates to ``n_scored``.
 
-Select a backend by name or let :func:`pick_backend` choose from the
-platform and the index's size (TPU -> ``fused`` while the pack fits one
-device, ``sharded`` over the host's devices where it does not;
-multi-device -> ``sharded``, else ``reference``)::
+Select a backend by name or let :func:`pick_backend` choose it and the
+shard count from the platform and the index's size::
 
     engine = get_engine(index, "auto")
     scores, ids, n_scored = engine.search(qw, probes=12, k=10)
 
 Adding a backend = subclass :class:`_EngineBase`, implement ``search``, and
-decorate with ``@register_backend("name")`` (see ROADMAP.md, "Architecture:
-search backends").
+decorate with ``@register_backend("name")``.
 """
 
 from __future__ import annotations
@@ -92,6 +64,7 @@ __all__ = [
     "register_backend",
     "available_backends",
     "pick_backend",
+    "resolve_backend",
     "get_engine",
     "split_probes",
     "sweep_probes",
@@ -145,11 +118,16 @@ def available_backends() -> tuple[str, ...]:
     return tuple(BACKENDS)
 
 
-# Share of one device's memory the fused backend's serving state (the
-# bucket-major pack at its dtype, plus the fp32 corpus) may take. The rest
-# holds the kernel's working set, the probe schedule and XLA's temporaries;
-# an index above it is sharded where the host has several devices.
+# Share of one device's memory the kernel path's serving state (the
+# bucket-major pack at its dtype, plus the fp32 corpus) may take on one
+# device. The rest holds the kernel's working set, the probe schedule and
+# XLA's temporaries; an index above it is sharded where the host has several
+# devices.
 _FUSED_MEMORY_SHARE = 0.75
+
+# The name ``bench/`` still gives the kernel path over all of the host's
+# devices. ``get_engine`` resolves it to ``fused`` on that mesh.
+_ALL_DEVICES_ALIAS = "sharded"
 
 
 def pack_bytes(index) -> int:
@@ -168,18 +146,9 @@ def _host() -> tuple[str, int, int]:
             int(stats.get("bytes_limit", 0)))
 
 
-def pick_backend(index=None) -> str:
-    """The backend ``"auto"`` resolves to, by platform and by size.
-
-    On a TPU: ``fused`` while ``index``'s serving state (its bucket-major
-    pack at ``pack_dtype`` plus the fp32 corpus) fits one device within
-    :data:`_FUSED_MEMORY_SHARE` of its byte limit, else ``sharded`` over all
-    of the host's devices where it has several. Elsewhere: ``sharded`` on a
-    multi-device host, else ``reference``. Without an index, or where the
-    device reports no limit, the state counts as fitting. Leaves a
-    :data:`~repro.tracing.ENGINE_PICK` marker with the backend, the pack's
-    bytes, the device's byte limit and the device count.
-    """
+def _pick(index) -> tuple[str, int, int, int, int]:
+    """``(backend, shards, pack bytes, byte limit, devices)``: see
+    :func:`pick_backend`."""
     platform, n_devices, limit = _host()
     pack = state = 0
     if index is not None:
@@ -187,15 +156,38 @@ def pick_backend(index=None) -> str:
         pack = pack_bytes(index)
         state = pack + math.prod(docs.shape) * docs.dtype.itemsize
     if platform != "tpu":
-        name = "sharded" if n_devices > 1 else "reference"
-    elif n_devices > 1 and limit and state > _FUSED_MEMORY_SHARE * limit:
-        name = "sharded"
+        name = "fused" if n_devices > 1 else "reference"
+        shards = n_devices
     else:
         name = "fused"
-    with span(tracing.ENGINE_PICK, backend=name, pack_bytes=pack,
-              bytes_limit=limit, devices=n_devices):
+        fits = not limit or state <= _FUSED_MEMORY_SHARE * limit
+        shards = 1 if fits else n_devices
+    return name, shards, pack, limit, n_devices
+
+
+def pick_backend(index=None) -> tuple[str, int]:
+    """The backend ``"auto"`` resolves to and the shard count of its mesh,
+    by platform and by size.
+
+    On a TPU: ``fused`` on one device while ``index``'s serving state (its
+    bucket-major pack at ``pack_dtype`` plus the fp32 corpus) fits
+    :data:`_FUSED_MEMORY_SHARE` of the device's byte limit, else over all of
+    the host's devices. Elsewhere: ``fused`` over all devices on a
+    multi-device host, else ``reference``. Without an index, or where the
+    device reports no limit, the state counts as fitting. Leaves a
+    :data:`~repro.tracing.ENGINE_PICK` marker with the backend, the shard
+    count, the pack's bytes, the device's byte limit and the device count.
+    """
+    name, shards, pack, limit, n_devices = _pick(index)
+    with span(tracing.ENGINE_PICK, backend=name, shards=shards,
+              pack_bytes=pack, bytes_limit=limit, devices=n_devices):
         pass
-    return name
+    return name, shards
+
+
+def resolve_backend(index, backend: str | None) -> str:
+    """``backend`` as named, or the pick's where it is None or ``"auto"``."""
+    return pick_backend(index)[0] if backend in (None, "auto") else backend
 
 
 def get_engine(index, backend: str = "auto", **opts) -> SearchEngine:
@@ -203,13 +195,15 @@ def get_engine(index, backend: str = "auto", **opts) -> SearchEngine:
 
     Keying the per-index cache by the opts (not just the backend name)
     means variant engines — a sweep's per-level ``qchunk``, an explicit
-    ``query_tile`` or ``interpret`` override — are constructed and traced
-    ONCE and then reused, instead of rebuilt per call (an L-level
-    ``sweep_probes`` used to re-instantiate and re-trace the reference
-    engine at every level). Unhashable opts (e.g. a ``mesh`` object) fall
-    back to an uncached construction.
+    ``query_tile``, ``interpret`` or ``mesh`` — are constructed and traced
+    once and then reused. Unhashable opts fall back to an uncached
+    construction.
     """
-    name = pick_backend(index) if backend in (None, "auto") else backend
+    name = resolve_backend(index, backend)
+    if name == _ALL_DEVICES_ALIAS:
+        name = "fused"
+        opts = {"mesh": jax.make_mesh((jax.device_count(),), ("data",)),
+                **opts}
     if name not in BACKENDS:
         raise ValueError(
             f"unknown backend {name!r}; available: {sorted(BACKENDS)}"
@@ -251,8 +245,8 @@ def sweep_probes(
 ) -> list[tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]]:
     """Run ONE engine over a probe grid — the planner-calibration sweep.
 
-    The engine (and with it the bucket-major pack, the sharded layout, every
-    per-index cache) is resolved once and reused across all probe levels, so
+    The engine (and with it the bucket-major pack and every per-index
+    cache) is resolved once and reused across all probe levels, so
     an L-level sweep costs L searches, not L index preparations. For the
     ``reference`` backend the query-chunk size is adapted per level so the
     ``(qchunk, candidates, D)`` gather stays within a fixed memory budget —
@@ -268,7 +262,7 @@ def sweep_probes(
     Returns one ``(scores, ids, n_scored)`` tuple per grid entry, in grid
     order.
     """
-    name = pick_backend(index) if backend in (None, "auto") else backend
+    name = resolve_backend(index, backend)
     grid = [int(p) for p in probe_grid]
     if not grid:
         return []
@@ -307,6 +301,11 @@ class _EngineBase:
 
     def __init__(self, index):
         self.index = index
+
+    def tile_for(self, k: int = 10) -> int | None:
+        """The query tile a batch of top-``k`` searches is cut into, or None
+        for a backend that does not tile."""
+        return None
 
     # Every backend reduces (query, weights) identically (paper §4 theorem).
     # Query rank passes through so a 1-D query keeps the squeezed (k,) result
@@ -498,8 +497,8 @@ class _EngineBase:
     def _rescore_candidates(self, qw, ids, k):
         """Exact fp32 re-rank of candidate ids — the rescore tail's scoring
         step, overridable per backend. The default gathers from the local
-        doc-major corpus; the sharded backend re-ranks against the
-        row-sharded corpus without gathering it
+        doc-major corpus; ``fused`` re-ranks against the row-sharded corpus
+        without gathering it
         (:func:`repro.core.distributed.distributed_exact_rescore`)."""
         return _exact_rescore(self.index.docs, qw, ids, k)
 
@@ -627,129 +626,40 @@ def _search_block(
 # ---------------------------------------------------------------------- fused
 @register_backend("fused")
 class FusedEngine(_EngineBase):
-    """Query-tiled Pallas ``bucket_score`` v2 over the bucket-major corpus.
+    """The Pallas ``bucket_score`` kernel over a mesh of S >= 1 devices.
 
-    Queries are grouped into tiles of ``query_tile`` (default: sized from
-    the kernel's VMEM budget by
-    :func:`repro.kernels.bucket_score.ops.pick_query_tile`); for each tile
-    the engine builds a **probe-dedup schedule** — the union of the tile's
-    flat probe lists with every shared bucket appearing once
-    (:func:`~repro.kernels.bucket_score.ops.build_probe_schedule`) — and the
-    kernel scores each DMA'd bucket block against the whole tile as one
-    ``(QT, D)×(D, B)`` MXU matmul with per-query membership masking. A
-    bucket probed by many queries of a tile is read from HBM once per tile
-    instead of once per query, so batched throughput scales with the MXU
-    rather than with redundant block reads; ragged batch tails are padded
-    to the tile and sliced off. The in-kernel running top-k suppresses
-    duplicates across overlapping clusterings exactly like the reference
-    path, and the bucket-major tensor may be stored bf16 or int8
-    (``ClusterPruneIndex`` ``pack_dtype``) with fp32 accumulation — the
-    int8 pack's per-bucket ``bucket_scales`` ride along and dequantise each
-    score block inside the kernel.
-
-    The schedule is built ON DEVICE
+    Each device holds a bucket-major ``(T*K, B_l, D)`` pack of its row slice
+    of every bucket (:meth:`ClusterPruneIndex.ensure_local_bucket_major`),
+    in ``pack_dtype`` precision with per-``(shard, bucket)`` int8 scales. A
+    search navigates once on the replicated fp32 leaders and builds the
+    probe-dedup schedule once on device
     (:func:`~repro.kernels.bucket_score.ops.build_probe_schedule_device`):
-    a jitted segmented dedup over a *bucketed static* schedule length
-    ``S = pow2ceil(min(QT·P, n_buckets))``
-    (:func:`~repro.kernels.bucket_score.ops.schedule_length`), so the hot
-    path never synchronises the probe tensor HBM→host→HBM. Padded schedule
-    slots all target bucket 0 with zero membership and follow the live
-    ones; the kernel skips their grid steps (no DMA, no matmul, no merge),
-    so the static upper bound costs only a predicate a step. The
-    ``repro.engine.score`` span carries ``slots``, the grid steps launched
-    (``n_tiles · S``), beside which the dispatch's live buckets give the
-    share skipped. Runs interpreted off-TPU (tests only).
-    """
-
-    uses_packed_storage = True
-
-    def __init__(
-        self,
-        index,
-        *,
-        interpret: bool | None = None,
-        query_tile: int | None = None,
-    ):
-        super().__init__(index)
-        self.interpret = interpret
-        self.query_tile = query_tile
-
-    def search(self, qw, *, probes, k, exclude=None, nav_query=None,
-               rescore=None):
-        if rescore is not None:
-            return self._search_rescored(
-                qw, probes=probes, k=k, rescore=rescore, exclude=exclude,
-                nav_query=nav_query,
-            )
-        from ..kernels.bucket_score import bucket_score_tiled
-        from ..kernels.bucket_score.ops import (
-            build_probe_schedule_device, pick_query_tile, schedule_length,
-        )
-        from ..kernels.common import pad_to
-
-        qw, nav, exclude, single = self._canonical(qw, nav_query, exclude)
-        # (T, K, B, D), (T*K, B), (T*K,) | None
-        data, ids, scales = self.index.ensure_bucket_major()
-        with span(tracing.ENGINE_NAVIGATE):
-            flat = self._flat_probes(nav, self._probes_t(probes))
-        with span(tracing.ENGINE_SCHEDULE):
-            n_buckets, b = (int(x) for x in ids.shape)
-            d = int(data.shape[-1])
-            qt = self.query_tile
-            if qt is None:
-                # VMEM budget caps the tile (a reduced-precision pack
-                # shrinks the bucket block and buys a larger tile); the
-                # batch floors it — a small batch padded to a large tile
-                # would matmul and top-k mostly dead rows per scheduled
-                # bucket.
-                qt = min(
-                    pick_query_tile(
-                        d, b, k_pad=pad_to(k, 8),
-                        pack_itemsize=data.dtype.itemsize,
-                    ),
-                    pad_to(qw.shape[0], 8),
-                )
-            # Jitted dedup with bucketed static S — no host numpy round-trip.
-            s_len = schedule_length(qt, int(flat.shape[1]), n_buckets)
-            sched, member = build_probe_schedule_device(
-                flat, query_tile=qt, s_len=s_len
-            )
-        with span(tracing.ENGINE_SCORE, slots=sched.size):
-            s, i = bucket_score_tiled(
-                qw, data, ids, sched, member,
-                k=k, exclude=exclude, scales=scales, interpret=self.interpret,
-            )
-            i = jnp.where(jnp.isfinite(s), i, -1)
-            return self._finish(single, s, i, self._n_scored(flat))
-
-
-# -------------------------------------------------------------------- sharded
-@register_backend("sharded")
-class ShardedEngine(_EngineBase):
-    """Sharded-fused backend: the fused v2 hot path run shard-locally.
-
-    Each device of the mesh holds a bucket-major ``(T*K, B_local, D)`` pack
-    of ITS row-slice of every cluster (``ClusterPruneIndex.
-    ensure_local_bucket_major`` — ``pack_dtype`` bf16 halves, int8 quarters
-    the per-shard HBM bytes via per-``(shard, bucket)`` scales). A search
-    navigates ONCE on the replicated fp32 leaders, builds the probe-dedup
-    schedule ONCE on device (probed buckets are identical across shards, so
-    schedule and membership masks replicate), then every shard runs
+    the union of a query tile's flat probe lists, each shared bucket once.
+    Probed buckets are the same on every shard, so schedule and membership
+    masks replicate. Every shard then runs
     :func:`~repro.kernels.bucket_score.ops.bucket_score_tiled` over its
-    local blocks — the same ``(QT, D)×(D, B_l)`` MXU tiles as the
-    single-device fused path, on a smaller ``B_l`` block (which buys a
-    LARGER query tile out of the same VMEM budget). The only collective is
-    the 2k-word per-shard top-k ``all_gather`` + merge; the same flat probe
-    tensor drives ``n_scored`` accounting, so navigation never runs twice.
+    blocks, one ``(QT, D)×(D, B_l)`` MXU matmul a scheduled bucket, and one
+    ``all_gather`` of the per-shard top-k merges the answer
+    (:func:`~repro.core.distributed.distributed_bucket_score`). The same
+    flat probe tensor drives ``n_scored``.
 
-    Any corpus size shards cleanly: rows pad to ``ceil(n / shards)`` per
-    shard with sentinel rows no bucket references (never scored, never in
-    ``n_scored``). Mutations invalidate lazily — the pack re-materialises
-    on the first search after an ``index.version`` bump. The exact-rescore
-    tail (and with it the quantised exact tier) re-ranks candidates
-    against the row-sharded fp32 corpus via a ``pmax`` all-reduce
-    (:func:`~repro.core.distributed.distributed_exact_rescore`) — the
-    corpus is never gathered onto one device.
+    The mesh is ``mesh``/``shard_axes`` where given, else the pick's
+    (:func:`pick_backend`): one device while the index fits it, all of the
+    host's devices where it does not. At S=1 the pack is the whole corpus's
+    and local ids are global ids.
+
+    The schedule's static length ``S = pow2ceil(min(QT·P, n_buckets))``
+    (:func:`~repro.kernels.bucket_score.ops.schedule_length`) pads each tile
+    with slots no query probes; the kernel skips their grid steps. The
+    ``repro.engine.score`` span carries ``slots`` (``n_tiles · S``) and
+    ``shards``.
+
+    Any corpus size shards: rows pad to ``ceil(n / shards)`` per shard with
+    sentinel rows no bucket references. Mutations repack lazily on the first
+    search after an ``index.version`` bump. The exact-rescore tail re-ranks
+    candidates against the row-sharded fp32 corpus with a ``pmax``
+    all-reduce (:func:`~repro.core.distributed.distributed_exact_rescore`),
+    built the first time a rescore needs it. Runs interpreted off-TPU.
     """
 
     uses_packed_storage = True
@@ -765,36 +675,32 @@ class ShardedEngine(_EngineBase):
     ):
         super().__init__(index)
         if mesh is None:
-            mesh = jax.make_mesh((jax.device_count(),), ("data",))
+            shards = _pick(index)[1]
+            mesh = jax.make_mesh((shards,), ("data",),
+                                 devices=jax.devices()[:shards])
             shard_axes = ("data",)
         self.mesh = mesh
         self.shard_axes = tuple(
             shard_axes if shard_axes is not None else mesh.axis_names
         )
-        n_shards = 1
-        for a in self.shard_axes:
-            n_shards *= mesh.shape[a]
-        self.n_shards = n_shards
+        self.n_shards = math.prod(mesh.shape[a] for a in self.shard_axes)
         self.interpret = interpret
         self.query_tile = query_tile
         self._pack_version = None   # index.version the placed pack reflects
+        self._docs_sh = None        # row-sharded corpus, for the rescore tail
 
     def _ensure_placed(self):
-        """Device-resident shard-local state, repacked lazily on mutation.
-
-        Returns ``(data, ids, scales, n_local)`` placed shard-major on the
-        mesh plus the row-sharded fp32 corpus for the rescore tail. Keyed
-        on ``index.version``: the first search after an add/remove pays the
-        repack + placement once, steady-state searches touch nothing.
-        """
+        """The shard-local pack ``(data, ids, scales, n_local)`` placed
+        shard-major on the mesh, repacked lazily on mutation: keyed on
+        ``index.version``, so the first search after an add/remove pays the
+        repack once and steady-state searches touch nothing. fp32 and bf16
+        packs carry unit scales."""
         if self._pack_version == self.index.version:
             return self._placed
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from .distributed import shard_docs
-
-        mesh, axes = self.mesh, self.shard_axes
-        sh = lambda *spec: NamedSharding(mesh, P(*spec))
+        sh = lambda *spec: NamedSharding(self.mesh, P(*spec))
+        axes = self.shard_axes
         # each device builds its own slice: the pack never sits whole on one
         # device (the puts below are no-ops for a pack placed that way)
         data, ids, scales, n_local = self.index.ensure_local_bucket_major(
@@ -802,24 +708,52 @@ class ShardedEngine(_EngineBase):
         )
         data = jax.device_put(data, sh(axes, None, None, None))
         ids = jax.device_put(ids, sh(axes, None, None))
-        if scales is not None:
-            scales = jax.device_put(scales, sh(axes, None))
-        self._docs_sh = shard_docs(self.index.docs, mesh, axes)
-        self._n_local = n_local
+        if scales is None:
+            scales = jnp.ones(ids.shape[:2], jnp.float32)
+        scales = jax.device_put(scales, sh(axes, None))
         self._placed = (data, ids, scales, n_local)
+        self._docs_sh = None
         self._pack_version = self.index.version
         return self._placed
+
+    def tile_for(self, k: int = 10) -> int:
+        """The query tile for top-``k``: ``query_tile`` where given, else
+        the largest the kernel's VMEM budget allows for this engine's bucket
+        block. The block is the placed pack's, or before packing ``B`` split
+        over the shards (``ceil(B / S)`` rows, sublane-padded) in
+        ``pack_dtype``."""
+        if self.query_tile:
+            return int(self.query_tile)
+        from ..kernels.bucket_score.ops import pick_query_tile
+        from ..kernels.common import pad_to
+
+        if self._pack_version == self.index.version:
+            data = self._placed[0]
+            b, d, itemsize = (int(data.shape[2]), int(data.shape[3]),
+                              data.dtype.itemsize)
+        else:
+            rows = -(-int(self.index.buckets.shape[-1]) // self.n_shards)
+            b = max(8, pad_to(rows, 8))
+            d = int(self.index.docs.shape[-1])
+            itemsize = jnp.dtype(
+                self.index.pack_dtype or self.index.docs.dtype
+            ).itemsize
+        return pick_query_tile(d, b, k_pad=pad_to(k, 8),
+                               pack_itemsize=itemsize)
 
     def _rescore_candidates(self, qw, ids, k):
         # fp32 re-rank against the row-sharded corpus: each shard scores
         # the candidates it owns, one pmax all-reduce merges — nq·R words
         # of communication, corpus never gathered.
-        from .distributed import distributed_exact_rescore
+        from .distributed import distributed_exact_rescore, shard_docs
 
-        self._ensure_placed()
+        n_local = self._ensure_placed()[3]
+        if self._docs_sh is None:
+            self._docs_sh = shard_docs(self.index.docs, self.mesh,
+                                       self.shard_axes)
         return distributed_exact_rescore(
             self.mesh, self._docs_sh, qw, ids,
-            k=k, n_local=self._n_local, shard_axes=self.shard_axes,
+            k=k, n_local=n_local, shard_axes=self.shard_axes,
         )
 
     def search(self, qw, *, probes, k, exclude=None, nav_query=None,
@@ -830,7 +764,7 @@ class ShardedEngine(_EngineBase):
                 nav_query=nav_query,
             )
         from ..kernels.bucket_score.ops import (
-            build_probe_schedule_device, pick_query_tile, schedule_length,
+            build_probe_schedule_device, schedule_length,
         )
         from ..kernels.common import pad_to
         from .distributed import distributed_bucket_score
@@ -842,17 +776,13 @@ class ShardedEngine(_EngineBase):
         with span(tracing.ENGINE_NAVIGATE):
             flat = self._flat_probes(nav, self._probes_t(probes))
         with span(tracing.ENGINE_SCHEDULE):
-            _, n_buckets, b_l, d = (int(x) for x in data.shape)
-            qt = self.query_tile
-            if qt is None:
-                qt = min(
-                    pick_query_tile(
-                        d, b_l, k_pad=pad_to(k, 8),
-                        pack_itemsize=data.dtype.itemsize,
-                    ),
-                    pad_to(qw.shape[0], 8),
-                )
-            s_len = schedule_length(qt, int(flat.shape[1]), n_buckets)
+            qt = self.tile_for(k)
+            if not self.query_tile:
+                # a small batch padded to a large tile would matmul and
+                # top-k mostly dead rows per scheduled bucket
+                qt = min(qt, pad_to(qw.shape[0], 8))
+            s_len = schedule_length(qt, int(flat.shape[1]),
+                                    int(ids.shape[1]))
             sched, member = build_probe_schedule_device(
                 flat, query_tile=qt, s_len=s_len
             )

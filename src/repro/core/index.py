@@ -9,14 +9,14 @@ the ``b/T`` clusters with the most similar representatives in *each*
 clustering, exhaustively score the union of their buckets, return the top-k.
 
 This module owns the *data structure only*: the padded ``(T, K, B)`` bucket-id
-tensor (sentinel = ``n``), the per-clustering assignment vectors, and — new
-with the engine layer — the bucket-major ``(T, K, B, D)`` corpus tensor that
-the fused Pallas backend consumes, materialised **once at build time** (or
-lazily on first fused search when the build deferred it for memory). An index
-may additionally carry a fitted :class:`~repro.core.calibrate.ProbeLadder`
-(``ladder``, opt-in ``calibrate=`` at build or lazily on the first
-``recall_target=`` request) mapping recall targets to probe budgets measured
-on *this* index; it round-trips through :meth:`ClusterPruneIndex.save` /
+tensor (sentinel = ``n``), the per-clustering assignment vectors, and the
+shard-local bucket-major pack the ``fused`` engine scores from
+(:meth:`ClusterPruneIndex.ensure_local_bucket_major`), made on the first
+search that needs it. An index may additionally carry a fitted
+:class:`~repro.core.calibrate.ProbeLadder` (``ladder``, opt-in
+``calibrate=`` at build or lazily on the first ``recall_target=`` request)
+mapping recall targets to probe budgets measured on *this* index; it
+round-trips through :meth:`ClusterPruneIndex.save` /
 :meth:`ClusterPruneIndex.load`.
 
 The index is no longer frozen at build time: :meth:`add_documents` streams
@@ -26,13 +26,13 @@ primitive the build tail uses and inserts them into the padded buckets
 documents out of every bucket. Mutations bump ``version`` (cache coherence
 for retriever-level memoisation), accumulate into ``n_mutations`` (the
 calibrated ladder is reported stale once drift crosses
-:data:`LADDER_DRIFT_THRESHOLD`), and invalidate the bucket-major tensor and
-cached engines — the bucket-major layout is re-packed *lazily* on the next
-fused search, so a burst of adds pays the layout conversion once.
+:data:`LADDER_DRIFT_THRESHOLD`), and invalidate the pack and cached engines —
+the pack is re-made *lazily* on the next search, so a burst of adds pays the
+layout conversion once.
 
-Search *execution* lives in :mod:`repro.core.engine`: three interchangeable
-backends (``reference`` pure-JAX gather, ``fused`` Pallas ``bucket_score``,
-``sharded`` ``shard_map``) share identical probe/dedup/exclude/cost
+Search *execution* lives in :mod:`repro.core.engine`: two backends
+(``reference`` pure-JAX gather, ``fused`` Pallas ``bucket_score`` over a
+mesh of one or more devices) share identical probe/dedup/exclude/cost
 semantics. :meth:`ClusterPruneIndex.search` is a thin delegation kept for
 backward compatibility — pass ``backend=`` to pick a path explicitly.
 """
@@ -53,7 +53,7 @@ from .weights import weighted_query
 
 __all__ = [
     "ClusterPruneIndex", "CorruptIndexError", "pack_buckets",
-    "pack_buckets_major", "validate_pack_dtype", "SUPPORTED_PACK_DTYPES",
+    "validate_pack_dtype", "SUPPORTED_PACK_DTYPES",
     "LADDER_DRIFT_THRESHOLD",
 ]
 
@@ -70,8 +70,8 @@ class CorruptIndexError(Exception):
 
 # Storage precisions the bucket-major pack (and the fused scoring kernel)
 # support. fp32 = corpus dtype; bf16 halves the packed bytes (plain cast);
-# int8 quarters them (symmetric per-bucket quantisation, scales carried in
-# ``bucket_scales``). Validated in ONE place (:func:`validate_pack_dtype`)
+# int8 quarters them (symmetric per-bucket quantisation, the scales carried
+# beside the pack). Validated in ONE place (:func:`validate_pack_dtype`)
 # so build / load / lazy re-pack all fail with the same clear error.
 SUPPORTED_PACK_DTYPES = ("float32", "bfloat16", "int8")
 
@@ -82,7 +82,7 @@ def validate_pack_dtype(pack_dtype) -> str | None:
     Accepts None (keep the corpus dtype), a dtype-like, or a string; returns
     the canonical dtype name or None. Raises ``ValueError`` listing the
     supported precisions for anything else — the single choke point for
-    build, ``load``, and every lazy re-pack (``ensure_bucket_major``).
+    build, ``load``, and every lazy re-pack (``ensure_local_bucket_major``).
     """
     if pack_dtype is None:
         return None
@@ -105,13 +105,6 @@ def validate_pack_dtype(pack_dtype) -> str | None:
 # the pre-mutation clustering, and past this drift the promise is no longer
 # trustworthy (Retriever re-calibrates or warns — see api._plan_target).
 LADDER_DRIFT_THRESHOLD = 0.1
-
-# Auto-materialise the bucket-major tensor at build (TPU only, where the
-# fused backend serves by default) when it costs less than this; otherwise
-# defer to the first fused search (ensure_bucket_major). The bound lies far
-# below the size at which pick_backend shards an index, so a build that
-# resolves to ``sharded`` never holds the whole pack on one device.
-_PACK_MAJOR_AUTO_BYTES = 256 * 2**20
 
 
 def pack_buckets(
@@ -141,37 +134,6 @@ def pack_buckets(
     return ids, counts
 
 
-def pack_buckets_major(
-    docs: jnp.ndarray, buckets: jnp.ndarray, n: int, dtype=None
-) -> tuple[jnp.ndarray, jnp.ndarray | None]:
-    """Bucket-major layout: (n, D) corpus + (T, K, B) ids -> (T, K, B, D).
-
-    Sentinel slots (id == ``n``) point at row 0; consumers mask them via the
-    id tensor, so the data tensor itself needs no sentinel handling. This is
-    the one-time layout conversion that lets the fused backend read a probed
-    bucket as a contiguous block instead of a row gather. Delegates to the
-    kernel-side :func:`repro.kernels.bucket_score.ops.pack_bucket_major`
-    after normalising this module's sentinel-``n`` padding to its ``-1``.
-
-    ``dtype`` selects the storage precision of the packed tensor —
-    ``"bfloat16"`` halves the HBM bytes and the scoring bandwidth,
-    ``"int8"`` quarters them via symmetric per-bucket quantisation; the
-    fused kernel accumulates fp32 regardless, and navigation keeps the fp32
-    leaders. The doc-major corpus and every other consumer stay fp32.
-
-    Returns ``(data (T, K, B, D), scales (T, K) fp32 | None)`` — scales are
-    non-None only for the int8 pack.
-    """
-    from ..kernels.bucket_score.ops import pack_bucket_major
-
-    dtype = validate_pack_dtype(dtype)
-    data, _, scales = pack_bucket_major(
-        docs, jnp.where(buckets < n, buckets, -1),
-        dtype=None if dtype is None else jnp.dtype(dtype),
-    )
-    return data, scales
-
-
 @dataclasses.dataclass
 class ClusterPruneIndex:
     """The paper's index: T independent clusterings over a weight-free corpus."""
@@ -183,8 +145,6 @@ class ClusterPruneIndex:
     counts: jnp.ndarray     # (T, K) int32 LIVE members per bucket
     method: str = "fpf"
     assign: np.ndarray | None = None        # (T, n) cluster of each doc (-1 = removed)
-    bucket_data: jnp.ndarray | None = None  # (T, K, B, D) bucket-major corpus
-    bucket_scales: jnp.ndarray | None = None  # (T, K) fp32 int8 dequant scales
     pack_dtype: str | None = None           # bucket-major storage dtype (None = docs')
     ladder: object | None = None            # fitted ProbeLadder (or None)
     removed: np.ndarray | None = None       # (n,) bool tombstones (or None)
@@ -202,13 +162,12 @@ class ClusterPruneIndex:
         n_clusterings: int = 3,
         method: str = "auto",
         key: jax.Array | None = None,
-        pack_major: bool | None = None,
         pack_dtype=None,
         calibrate: bool | dict = False,
         **clusterer_kwargs,
     ) -> "ClusterPruneIndex":
-        """Cluster T ways, pack buckets, and materialise the bucket-major
-        tensor for the fused backend where that backend will actually serve.
+        """Cluster T ways and pack the buckets. The bucket-major pack the
+        ``fused`` engine scores from is made by its first search.
 
         ``method`` names a registered clusterer
         (:func:`repro.core.cluster.available_clusterers`); the default
@@ -219,17 +178,12 @@ class ClusterPruneIndex:
         ``clusterer_kwargs`` pass through to the clusterer's constructor
         (e.g. ``iters=`` for ``kmeans``).
 
-        ``pack_major``: True forces the (T, K, B, D) tensor now, False defers
-        it to the first fused search, None (default) materialises it only on
-        TPU (the fused auto-pick platform) and within a modest memory budget
-        — either way the layout conversion happens exactly once per index.
-
-        ``pack_dtype``: storage dtype of the bucket-major tensor only
+        ``pack_dtype``: storage dtype of the bucket-major pack only
         (:data:`SUPPORTED_PACK_DTYPES`). ``"bfloat16"`` halves its HBM
         footprint and the bandwidth the fused scoring matmul must hide;
-        ``"int8"`` quarters them via symmetric per-bucket quantisation
-        (scales land in ``bucket_scales`` and persist with the index) —
-        quadruple the corpus per pack budget. Either way the kernel
+        ``"int8"`` quarters them via symmetric per-``(shard, bucket)``
+        quantisation, the scales re-derived with every pack — quadruple the
+        corpus per pack budget. Either way the kernel
         accumulates fp32 (``preferred_element_type``) and navigation keeps
         the fp32 leaders, so probe sets and ``n_scored`` are bit-identical
         across pack dtypes. Persisted with the index, honoured by every
@@ -266,23 +220,6 @@ class ClusterPruneIndex:
                 for ids in ids_l
             ]
             buckets = jnp.asarray(np.stack(ids_l))
-        pack_dtype = validate_pack_dtype(pack_dtype)
-        if pack_major is None:
-            itemsize = (
-                docs.dtype.itemsize if pack_dtype is None
-                else jnp.dtype(pack_dtype).itemsize
-            )
-            pack_major = (
-                jax.default_backend() == "tpu"
-                and buckets.size * docs.shape[1] * itemsize
-                <= _PACK_MAJOR_AUTO_BYTES
-            )
-        bucket_data, bucket_scales = None, None
-        if pack_major:
-            with span(tracing.INDEX_PACK):
-                bucket_data, bucket_scales = pack_buckets_major(
-                    docs, buckets, n, dtype=pack_dtype
-                )
         index = cls(
             spec=spec,
             docs=docs,
@@ -291,9 +228,7 @@ class ClusterPruneIndex:
             counts=jnp.asarray(np.stack(counts_l)),
             method=clusterer.name,
             assign=np.stack(assign_l).astype(np.int64),
-            bucket_data=bucket_data,
-            bucket_scales=bucket_scales,
-            pack_dtype=pack_dtype,
+            pack_dtype=validate_pack_dtype(pack_dtype),
         )
         from collections.abc import Mapping
 
@@ -350,12 +285,9 @@ class ClusterPruneIndex:
     # ---------------------------------------------------------- maintenance
     def _invalidate(self) -> None:
         """After a mutation: drop every derived/cached view and bump the
-        version. The bucket-major tensor is re-packed LAZILY (next fused
-        search), cached engines re-materialise on next ``get_engine`` —
-        retriever-level caches key off ``version``."""
-        self.bucket_data = None
-        self.bucket_scales = None
-        self.__dict__.pop("_bucket_major_flat", None)
+        version. The pack is re-made LAZILY (next fused search), cached
+        engines re-materialise on next ``get_engine`` — retriever-level
+        caches key off ``version``."""
         self.__dict__.pop("_local_bucket_major", None)
         self.__dict__.pop("_engines", None)
         self.version += 1
@@ -496,55 +428,21 @@ class ClusterPruneIndex:
         self._invalidate()
         return int(fresh.size)
 
-    def ensure_bucket_major(
-        self,
-    ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray | None]:
-        """Bucket-major view for the fused backend: ``((T, K, B, D) data,
-        (T*K, B) ids with -1 padding, (T*K,) fp32 scales | None)``.
-        Materialises the data tensor if the build deferred it — in
-        ``pack_dtype`` storage precision when the index carries one (bf16
-        halves the packed HBM bytes, int8 quarters them and fills the
-        per-bucket dequantisation scales) — and caches the flattened ids and
-        scales so the serving hot path pays no per-query layout work. The
-        data keeps its ``(T, K)`` axes: flattening it here would copy the
-        pack, and :func:`~repro.kernels.bucket_score.ops.bucket_score_tiled`
-        flattens it inside its jit for free."""
-        cached = getattr(self, "_bucket_major_flat", None)
-        if cached is not None:
-            return cached
-        self.pack_dtype = validate_pack_dtype(self.pack_dtype)
-        if self.bucket_data is None:
-            with span(tracing.INDEX_PACK):
-                self.bucket_data, self.bucket_scales = pack_buckets_major(
-                    self.docs, self.buckets, self.n_docs,
-                    dtype=self.pack_dtype,
-                )
-        t, k_clusters, b, _ = self.bucket_data.shape
-        ids = jnp.where(self.buckets < self.n_docs, self.buckets, -1)
-        self._bucket_major_flat = (
-            self.bucket_data,
-            ids.reshape(t * k_clusters, b).astype(jnp.int32),
-            (
-                None if self.bucket_scales is None
-                else self.bucket_scales.reshape(t * k_clusters)
-            ),
-        )
-        return self._bucket_major_flat
-
     def ensure_local_bucket_major(
         self, n_shards: int, *, sharding=None
     ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray | None, int]:
-        """Shard-local bucket-major pack for the sharded-fused backend:
-        ``((S, T*K, B_l, D) data, (S, T*K, B_l) LOCAL ids with -1 padding,
-        (S, T*K) fp32 scales | None, n_local rows per shard)``.
+        """The index's bucket-major pack, as the ``fused`` engine scores it
+        over ``n_shards`` devices: ``((S, T*K, B_l, D) data, (S, T*K, B_l)
+        LOCAL ids with -1 padding, (S, T*K) fp32 scales | None, n_local rows
+        per shard)``.
 
         Each shard's slice of every bucket, in ``pack_dtype`` storage
         precision (int8 quantises per ``(shard, bucket)`` — each shard's
-        absmax over its own slice). Cached per shard count and dropped by
-        :meth:`_invalidate`, so mutations trigger a lazy repack on the next
-        sharded-fused search — same coherence contract as
-        :meth:`ensure_bucket_major`. Corpora whose size does not divide
-        ``n_shards`` pad with sentinel rows no bucket references.
+        absmax over its own slice). At one shard local ids are global ids.
+        Cached per shard count and dropped by :meth:`_invalidate`, so
+        mutations trigger a lazy repack on the next search. Corpora whose
+        size does not divide ``n_shards`` pad with sentinel rows no bucket
+        references.
         ``sharding`` places the pack on a mesh as it is built, one slice per
         device (:func:`~repro.core.distributed.pack_local_bucket_major`).
         """
@@ -568,12 +466,11 @@ class ClusterPruneIndex:
     def save(self, path) -> None:
         """Serialize the index — calibrated ladder and mutation state
         (tombstones, ladder-drift counter) included — to one ``.npz``. The
-        bucket-major tensor is NOT stored (it is a pure layout transform,
-        re-derived lazily on load in ``pack_dtype`` precision); the tiny
-        per-bucket int8 ``bucket_scales`` ARE, as is the ladder, so a loaded
-        index keeps its honest ``recall_target=`` planning without re-paying
-        the calibration sweep — and keeps knowing when that ladder went
-        stale.
+        bucket-major pack is NOT stored: it is a pure layout transform,
+        re-derived lazily after load in ``pack_dtype`` precision, int8
+        scales included. The ladder IS, so a loaded index keeps its honest
+        ``recall_target=`` planning without re-paying the calibration sweep
+        — and keeps knowing when that ladder went stale.
 
         The write is CRASH-SAFE: bytes go to a temp file in the target
         directory first and only an atomic ``os.replace`` publishes them
@@ -629,11 +526,6 @@ class ClusterPruneIndex:
             ),
             n_mutations=np.int64(self.n_mutations),
             pack_dtype=np.str_(self.pack_dtype or ""),
-            bucket_scales=(
-                np.asarray(self.bucket_scales)
-                if self.bucket_scales is not None
-                else np.zeros((0, 0), np.float32)
-            ),
         )
 
     @classmethod
@@ -685,8 +577,6 @@ class ClusterPruneIndex:
         ladder_json = str(member("ladder"))
         removed = member("removed", required=False,
                          default=np.zeros(0, bool))
-        scales = member("bucket_scales", required=False,
-                        default=np.zeros((0, 0), np.float32))
         try:
             ladder = (
                 ProbeLadder.from_dict(json.loads(ladder_json))
@@ -732,7 +622,6 @@ class ClusterPruneIndex:
             pack_dtype=validate_pack_dtype(
                 str(member("pack_dtype", required=False, default="")) or None
             ),
-            bucket_scales=jnp.asarray(scales) if scales.size else None,
         )
 
     # ----------------------------------------------------------------- search
@@ -771,8 +660,8 @@ class ClusterPruneIndex:
         validation, per-field score decomposition) or use ``get_engine``
         directly for raw tuples.
 
-        ``backend`` picks the execution path (``"reference"``, ``"fused"``,
-        ``"sharded"`` or ``"auto"``). ``nav_query``: optional separate query
+        ``backend`` picks the execution path (``"reference"``, ``"fused"``
+        or ``"auto"``). ``nav_query``: optional separate query
         for LEADER navigation (the CellDec baseline navigates with the
         region-squeezed composite while scoring exactly — [18] §5.4);
         defaults to ``qw``. ``qchunk`` (None = backend default) is honoured
@@ -783,9 +672,9 @@ class ClusterPruneIndex:
         ``n_scored`` counts true distance computations (leaders + candidates)
         for the paper's Fig-1 cost accounting.
         """
-        from .engine import get_engine, pick_backend
+        from .engine import get_engine, resolve_backend
 
-        name = pick_backend(self) if backend in (None, "auto") else backend
+        name = resolve_backend(self, backend)
         if qchunk is not None and name != "reference":
             raise ValueError(
                 f"qchunk={qchunk} is only honoured by the 'reference' "

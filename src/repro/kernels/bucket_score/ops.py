@@ -439,9 +439,8 @@ def schedule_block_reads(member: jnp.ndarray) -> int:
     schedule padding whose repeat DMA the pipeline skips, so the number of
     slots with ANY member is exactly the bucket blocks the kernel reads
     from HBM. Benchmarks multiply by the per-shard block size
-    ``B · D · itemsize`` (and by the shard count for the sharded path —
-    every shard reads ITS slice of each scheduled bucket) to report
-    packed bytes per query.
+    ``B · D · itemsize`` and by the shard count (every shard reads ITS
+    slice of each scheduled bucket) to report packed bytes per query.
     """
     return int(jnp.asarray(member).any(axis=-1).sum())
 

@@ -9,8 +9,8 @@ brute force:
     PYTHONPATH=src python -m repro.launch.serve --docs 20000 --queries 64 \
         --probes 12 --k 10 --backend fused
 
-``--backend`` selects the execution path (``auto`` picks fused on TPU,
-sharded on multi-device hosts, reference otherwise); ``--compare`` serves the
+``--backend`` selects the execution path (``auto`` picks fused on a TPU or a
+multi-device host, reference otherwise); ``--compare`` serves the
 same request batch through every runnable backend on the same index and
 prints a per-backend latency/recall table. ``--recall-target 0.9`` replaces
 the fixed ``--probes`` budget with a recall target served by the per-index
@@ -67,8 +67,7 @@ __all__ = ["build_index", "build_retriever", "make_requests",
 
 
 def build_index(n_docs: int = 20_000, *, k_clusters: int | None = None,
-                n_clusterings: int = 3, seed: int = 0,
-                pack_major: bool | None = None, pack_dtype=None):
+                n_clusterings: int = 3, seed: int = 0, pack_dtype=None):
     from repro.core import ClusterPruneIndex
 
     docs_np, spec, _ = make_corpus(CorpusConfig(n_docs=n_docs, seed=seed))
@@ -77,28 +76,26 @@ def build_index(n_docs: int = 20_000, *, k_clusters: int | None = None,
         k_clusters = max(16, int(np.sqrt(n_docs)))
     index = ClusterPruneIndex.build(
         docs, spec, k_clusters, n_clusterings=n_clusterings,
-        key=jax.random.PRNGKey(seed), pack_major=pack_major,
-        pack_dtype=pack_dtype,
+        key=jax.random.PRNGKey(seed), pack_dtype=pack_dtype,
     )
     return index, docs, spec
 
 
 def build_retriever(n_docs: int = 20_000, *, backend: str = "auto",
                     k_clusters: int | None = None, n_clusterings: int = 3,
-                    seed: int = 0, pack_major: bool | None = None,
-                    pack_dtype=None, calibrate: bool = False,
+                    seed: int = 0, pack_dtype=None, calibrate: bool = False,
                     calibrate_opts=None):
     """Corpus + index + facade in one call -> (retriever, docs, spec).
 
     ``calibrate=True`` arms lazy planner calibration: the first
     ``recall_target=`` request fits the per-index probe ladder
     (``calibrate_opts`` passes sampling options through). ``pack_dtype``
-    sets the bucket-major storage precision (fused AND sharded backends
-    score from it — bf16 halves, int8 quarters the packed bytes).
+    sets the bucket-major storage precision the fused backend scores from
+    (bf16 halves, int8 quarters the packed bytes).
     """
     index, docs, spec = build_index(
         n_docs, k_clusters=k_clusters, n_clusterings=n_clusterings,
-        seed=seed, pack_major=pack_major, pack_dtype=pack_dtype,
+        seed=seed, pack_dtype=pack_dtype,
     )
     retriever = Retriever(index, backend=backend, calibrate=calibrate,
                           calibrate_opts=calibrate_opts)
@@ -258,19 +255,13 @@ def main():
         args.serve = True
         args.replicas = max(args.replicas, 4)
 
-    # Materialise the bucket-major layout at build time whenever the fused
-    # backend may serve — the engine would otherwise do it on first search.
     t0 = time.time()
     retriever, docs, spec = build_retriever(
         args.docs, backend=args.backend, seed=args.seed,
-        pack_major=True if args.compare else None,
     )
     index = retriever.index
-    if retriever.backend == "fused":
-        index.ensure_bucket_major()
     print(f"[serve] index built in {time.time() - t0:.1f}s "
-          f"(K={index.leaders.shape[1]}, T={index.leaders.shape[0]}"
-          f"{', bucket-major packed' if index.bucket_data is not None else ''})")
+          f"(K={index.leaders.shape[1]}, T={index.leaders.shape[0]})")
 
     if args.recall_target is not None or args.min_recall is not None:
         from repro.core import calibrate_index

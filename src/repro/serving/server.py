@@ -86,75 +86,17 @@ def _call(rep: Replica, requests: list[SearchRequest], dispatch: int):
         return rep.call(requests)
 
 
-def _engine_query_tile(retriever: Retriever) -> int | None:
-    """The fused kernel's query tile for this retriever, or None when the
-    serving backend does not tile (reference).
-
-    Both tiling backends are sized: ``fused`` from the global bucket block,
-    ``sharded`` from the shard-local block (``B_l ~ B / shards`` — smaller,
-    so the same VMEM budget buys a LARGER tile)."""
-    if retriever.backend not in ("fused", "sharded"):
-        return None
-    opt = retriever.engine_opts.get("query_tile")
-    if opt:
-        return int(opt)
-    from ..kernels.bucket_score.ops import pick_query_tile
-    from ..kernels.common import pad_to
-
-    index = retriever.index
-    data = index.bucket_data
-    if retriever.backend == "sharded":
-        import jax
-
-        mesh = retriever.engine_opts.get("mesh")
-        if mesh is not None:
-            axes = tuple(
-                retriever.engine_opts.get("shard_axes") or mesh.axis_names
-            )
-            n_shards = 1
-            for a in axes:
-                n_shards *= mesh.shape[a]
-        else:
-            n_shards = jax.device_count()
-        cached = (getattr(index, "_local_bucket_major", None) or {}).get(
-            n_shards
-        )
-        if cached is not None:  # placed pack: exact shard-local block shape
-            _, _, b, d = (int(x) for x in cached[0].shape)
-            return pick_query_tile(
-                d, b, k_pad=pad_to(10, 8),
-                pack_itemsize=cached[0].dtype.itemsize,
-            )
-        # not packed yet: estimate B_l from the global B (members spread
-        # ~evenly over shards; the flush trigger tolerates the estimate)
-        b_est = -(-int(index.buckets.shape[-1]) // n_shards)
-        b = max(8, -(-b_est // 8) * 8)
-        d = int(index.docs.shape[-1])
-        itemsize = {"bfloat16": 2, "int8": 1}.get(
-            getattr(index, "pack_dtype", None) or "float32", 4
-        )
-        return pick_query_tile(
-            d, b, k_pad=pad_to(10, 8), pack_itemsize=itemsize
-        )
-    if data is not None:
-        _, _, b, d = (int(x) for x in data.shape)
-        itemsize = data.dtype.itemsize
-    else:  # pack not materialised yet: size from the index's metadata
-        b = int(index.buckets.shape[-1])
-        d = int(index.docs.shape[-1])
-        itemsize = {"bfloat16": 2, "int8": 1}.get(
-            getattr(index, "pack_dtype", None) or "float32", 4
-        )
-    # k varies per request; size the tile for the default k=10 padded to
-    # the sublane multiple — max_batch is a flush trigger, not a contract.
-    return pick_query_tile(d, b, k_pad=pad_to(10, 8), pack_itemsize=itemsize)
-
-
 def default_max_batch(retriever: Retriever, floor: int = 64) -> int:
     """Size-flush trigger: >= ``floor`` requests, rounded UP to a multiple
-    of the fused engine's query tile so a size-triggered flush dispatches
-    full MXU tiles (non-tiling backends just use the floor)."""
-    qt = _engine_query_tile(retriever)
+    of the serving engine's query tile so a size-triggered flush dispatches
+    full MXU tiles (a backend that does not tile just uses the floor). ``k``
+    varies per request; the tile is the one for the default ``k=10`` —
+    ``max_batch`` is a flush trigger, not a contract."""
+    from ..core.engine import get_engine
+
+    engine = get_engine(retriever.index, retriever.backend,
+                        **retriever.engine_opts)
+    qt = engine.tile_for(10)
     if not qt:
         return floor
     return max(qt, -(-floor // qt) * qt)

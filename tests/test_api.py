@@ -40,7 +40,7 @@ def retriever(api_corpus):
     docs, spec = api_corpus
     return Retriever.build(
         docs, spec, 16, n_clusterings=3, method="fpf",
-        key=jax.random.PRNGKey(0), pack_major=True, backend="reference",
+        key=jax.random.PRNGKey(0), backend="reference",
     )
 
 
@@ -181,8 +181,11 @@ def test_recall_target_maps_to_probes(retriever):
 
 # ----------------------------------------------------- parity (acceptance)
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_retriever_parity_with_raw_engine(retriever, api_corpus, backend):
+def test_retriever_parity_with_raw_engine(
+    case_retriever, retriever, api_corpus, backend
+):
     """Retriever hits == raw engine.search tuples (ids, scores, n_scored)."""
+    retriever, backend = case_retriever(retriever, backend)
     docs, spec = api_corpus
     rng = np.random.default_rng(3)
     qids = rng.choice(docs.shape[0], 12, replace=False)
@@ -195,7 +198,8 @@ def test_retriever_parity_with_raw_engine(retriever, api_corpus, backend):
     responses = retriever.search(reqs)
 
     qw = weighted_query(docs[qids], jnp.asarray(wmat), spec)
-    s, i, n = get_engine(retriever.index, backend).search(
+    opts = retriever.engine_opts if backend == retriever.backend else {}
+    s, i, n = get_engine(retriever.index, backend, **opts).search(
         qw, probes=6, k=10, exclude=jnp.asarray(qids, jnp.int32)
     )
     assert np.array_equal(
@@ -213,10 +217,13 @@ def test_retriever_parity_with_raw_engine(retriever, api_corpus, backend):
 # -------------------------------------------------------------- decomposition
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("form", ("keyword", "mlt"))
-def test_field_decomposition(retriever, api_corpus, backend, form):
+def test_field_decomposition(
+    case_retriever, retriever, api_corpus, backend, form
+):
     """Per-field contributions sum to the aggregate score and rank hits
     identically to the definitional aggregate_similarity — for keyword and
     more-like-this requests on every runnable backend."""
+    retriever, backend = case_retriever(retriever, backend)
     docs, spec = api_corpus
     w = {"title": 0.5, "authors": 0.2, "abstract": 0.3}
     if form == "mlt":
@@ -290,10 +297,13 @@ def test_rescore_request_validation():
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_rescore_through_retriever(retriever, api_corpus, backend):
+def test_rescore_through_retriever(
+    case_retriever, retriever, api_corpus, backend
+):
     """SearchRequest(rescore=...) reaches the engine on every backend: on
     the fp32 pack it's an id/score identity that deepens n_scored, and the
     response scores equal exact fp32 dot products of the returned ids."""
+    retriever, backend = case_retriever(retriever, backend)
     docs, spec = api_corpus
     plain = retriever.search(
         SearchRequest(like=7, probes=6, k=5, backend=backend))
@@ -504,13 +514,16 @@ def test_planning_runs_no_per_row_device_work(fresh_retriever, monkeypatch):
 
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("with_vector", (True, False))
-def test_batched_resolve_matches_solo(fresh_retriever, backend, with_vector):
+def test_batched_resolve_matches_solo(
+    case_retriever, fresh_retriever, backend, with_vector
+):
     """Every miss of a batch resolves in one step: a repeated (like,
     weights) pair under two budgets, another weight draw and (optionally)
     a raw vector, planned together across two engine groups, get the doc
     ids and scores each request gets alone; the pair still caches two
     responses."""
     retriever, docs, spec = fresh_retriever
+    retriever, backend = case_retriever(retriever, backend)
     w = (0.2, 0.3, 0.5)
     reqs = [
         SearchRequest(like=5, weights=w, probes=6, k=4, backend=backend),
@@ -630,11 +643,14 @@ def test_tier_request_validation():
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_exact_tier_through_retriever(retriever, api_corpus, backend):
+def test_exact_tier_through_retriever(
+    case_retriever, retriever, api_corpus, backend
+):
     """SearchRequest(exact=True): brute-force-identical answers on every
     backend, tier/probes/predicted_recall stamped honestly."""
     from repro.core import brute_force_topk
 
+    retriever, backend = case_retriever(retriever, backend)
     docs, spec = api_corpus
     rng = np.random.default_rng(5)
     qids = rng.choice(docs.shape[0], 8, replace=False)
@@ -682,10 +698,13 @@ def test_exact_tier_shape_and_batching(retriever):
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_oversized_probes_clamped(retriever, api_corpus, backend):
+def test_oversized_probes_clamped(
+    case_retriever, retriever, api_corpus, backend
+):
     """Regression: probes= past T*K used to die in the engine with an
     opaque XLA error; it now clamps to the probe-everything budget at
     shape resolution on every backend."""
+    retriever, backend = case_retriever(retriever, backend)
     t, kc = retriever._tk
     sh = retriever.exec_shape(SearchRequest(like=1, probes=10_000))
     assert sh.probes == t * kc and sh.tier == "approx"

@@ -47,7 +47,7 @@ def test_phases_pass_on_a_tiny_corpus():
 
 def test_sharded_phases_pass_on_four_cpu_devices():
     """The ``--chips 4`` path at a tiny size: ``auto`` resolves to the
-    sharded backend on a forced four-device CPU mesh (a fresh process, so
+    fused backend over a forced four-device CPU mesh (a fresh process, so
     the device count can be set), its pack built one slice per device, and
     the async tier answers as the synchronous path does."""
     code = (
@@ -56,7 +56,7 @@ def test_sharded_phases_pass_on_four_cpu_devices():
         "m = importlib.util.module_from_spec(spec); spec.loader.exec_module(m)\n"
         f"out = m.run_phases({dict(TINY, n_docs=601)!r}, n_requests=8)\n"
         "print(json.dumps({k: out[k] for k in\n"
-        "      ('backend', 'exact_mismatches', 'pack_share_bytes',\n"
+        "      ('backend', 'shards', 'exact_mismatches', 'pack_share_bytes',\n"
         "       'async_s')}))\n"
     )
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
@@ -65,7 +65,7 @@ def test_sharded_phases_pass_on_four_cpu_devices():
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["backend"] == "sharded"
+    assert out["backend"] == "fused" and out["shards"] == 4
     assert out["exact_mismatches"] == 0
     assert out["pack_share_bytes"] > 0 and out["async_s"] > 0
 

@@ -209,14 +209,14 @@ def test_add_documents_ids_and_state(built_index):
     assert idx.version == v0 + 1
     assert idx.n_mutations == 100
     assert idx.assign.shape == (3, 1100)
-    assert idx.bucket_data is None                    # lazily re-packed
+    assert "_local_bucket_major" not in vars(idx)     # lazily re-packed
     # counts stay consistent with bucket contents
     bk = np.asarray(idx.buckets)
     assert int((bk < 1100).sum()) == 3 * 1100
     assert int(np.asarray(idx.counts).sum()) == 3 * 1100
 
 
-def test_added_docs_retrievable_on_every_backend(built_index):
+def test_added_docs_retrievable_on_every_backend(case_engine, built_index):
     """A copy of doc q is q's true nearest neighbour: after adding copies,
     every backend must return the copy as hit #1 for like=q."""
     idx, docs, spec = built_index
@@ -224,7 +224,7 @@ def test_added_docs_retrievable_on_every_backend(built_index):
     new_ids = idx.add_documents(docs[src])
     qw = weighted_query(docs[src], jnp.full((4, 3), 1 / 3), spec)
     for backend in BACKENDS:
-        s, ids, _ = get_engine(idx, backend).search(
+        s, ids, _ = case_engine(idx, backend).search(
             qw, probes=12, k=5, exclude=jnp.asarray(src, jnp.int32)
         )
         top = np.asarray(ids)[:, 0]
@@ -264,7 +264,7 @@ def test_add_rejects_bad_dim(built_index):
 
 
 # --------------------------------------------------- incremental: removes
-def test_removed_docs_never_returned(built_index):
+def test_removed_docs_never_returned(case_engine, built_index):
     idx, docs, spec = built_index
     qw = weighted_query(docs[10:14], jnp.ones((4, 3)) / 3, spec)
     _, ids0, _ = idx.search(qw, probes=12, k=5)
@@ -274,7 +274,7 @@ def test_removed_docs_never_returned(built_index):
     assert n_removed == len(victims)
     assert idx.n_live == 1000 - n_removed
     for backend in BACKENDS:
-        _, ids, _ = get_engine(idx, backend).search(qw, probes=48, k=10)
+        _, ids, _ = case_engine(idx, backend).search(qw, probes=48, k=10)
         live = np.asarray(ids).reshape(-1)
         assert not set(victims.tolist()) & set(live[live >= 0].tolist())
     # double-remove is a no-op, out-of-range raises
@@ -362,7 +362,7 @@ def test_calibrate_masks_removed_docs(built_index):
 
 # --------------------------------------------- incremental: quality floors
 @pytest.mark.slow
-def test_incremental_add_quality_floors():
+def test_incremental_add_quality_floors(case_engine, ):
     """Acceptance bar: after ingesting >=10% new docs WITHOUT a rebuild,
     every engine backend stays within the tests/test_quality.py CR/NAG
     floors (same corpus recipe and metrics; fixed seeds — the pipeline is
@@ -401,7 +401,7 @@ def test_incremental_add_quality_floors():
 
     added_seen = 0
     for backend in BACKENDS:
-        engine = get_engine(index, backend)
+        engine = case_engine(index, backend)
         for probes, cr_floor, nag_floor in floors:
             for wi, (qw, gt_s, gt_i, far_s) in enumerate(cells):
                 s, ids, _ = engine.search(qw, probes=probes, k=10,

@@ -104,7 +104,7 @@ from repro.core import (ClusterPruneIndex, FieldSpec, brute_force_topk,
 from repro.core.engine import get_engine, pick_backend
 
 assert jax.device_count() == 8
-assert pick_backend() == "sharded"   # multi-device auto-pick, any n_docs
+assert pick_backend() == ("fused", 8)   # multi-device auto-pick, any n_docs
 
 spec = FieldSpec(names=("a", "b"), dims=(32, 32))
 n = 1019                             # deliberately NOT divisible by 8
@@ -113,7 +113,8 @@ idx = ClusterPruneIndex.build(docs, spec, 16, n_clusterings=3, method="fpf")
 w = jnp.tile(jnp.asarray([[0.7, 0.3]]), (5, 1))
 qw = weighted_query(docs[10:15], w, spec)
 ref = get_engine(idx, "reference")
-sh = get_engine(idx, "sharded", interpret=True)
+sh = get_engine(idx, "fused", interpret=True)
+assert sh.n_shards == 8
 
 def ids_scores_n(a, b, tag, atol=1e-5):
     assert np.array_equal(np.asarray(a[1]), np.asarray(b[1])), tag + " ids"
@@ -152,9 +153,8 @@ assert np.array_equal(np.asarray(en), np.asarray(rn)), "exact tier n"
 # with it the exact tier) recovers exact fp32 ids/scores.
 r = ref.search(qw, probes=6, k=10)
 for dt, floor in (("bfloat16", 0.9), ("int8", 0.9)):
-    q = dataclasses.replace(idx, bucket_data=None, bucket_scales=None,
-                            pack_dtype=dt)
-    shq = get_engine(q, "sharded", interpret=True)
+    q = dataclasses.replace(idx, pack_dtype=dt)
+    shq = get_engine(q, "fused", interpret=True)
     sq = shq.search(qw, probes=6, k=10)
     assert np.array_equal(np.asarray(sq[2]), np.asarray(r[2])), dt + " n"
     ov = np.mean([len(set(a.tolist()) & set(b.tolist())) / 10

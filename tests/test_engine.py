@@ -1,6 +1,8 @@
-"""Backend parity: reference / fused (interpret) / sharded must be ONE
-algorithm executed three ways — identical top-k ids, scores (to float
-tolerance), and n_scored cost accounting on the same built index."""
+"""Backend parity: reference and fused (interpret) must be ONE algorithm
+executed two ways — identical top-k ids, scores (to float tolerance), and
+n_scored cost accounting on the same built index. The fused engine runs as
+two cases: over the mesh the pick derives (``"fused"``) and over an
+explicit ``mesh=`` of the host's devices (``"sharded"``)."""
 
 import types
 
@@ -37,7 +39,7 @@ def built_index(engine_corpus):
     docs, spec = engine_corpus
     return ClusterPruneIndex.build(
         docs, spec, 16, n_clusterings=3, method="fpf",
-        key=jax.random.PRNGKey(0), pack_major=True,
+        key=jax.random.PRNGKey(0),
     )
 
 
@@ -50,16 +52,20 @@ def _assert_parity(ref, other, name):
 
 
 @pytest.mark.parametrize("backend", BACKENDS[1:])
-def test_backend_parity_plain(built_index, engine_corpus, backend):
+def test_backend_parity_plain(
+    case_engine, built_index, engine_corpus, backend
+):
     docs, spec = engine_corpus
     qw = docs[20:36]
     ref = get_engine(built_index, "reference").search(qw, probes=6, k=10)
-    out = get_engine(built_index, backend).search(qw, probes=6, k=10)
+    out = case_engine(built_index, backend).search(qw, probes=6, k=10)
     _assert_parity(ref, out, backend)
 
 
 @pytest.mark.parametrize("backend", BACKENDS[1:])
-def test_backend_parity_exclude(built_index, engine_corpus, backend):
+def test_backend_parity_exclude(
+    case_engine, built_index, engine_corpus, backend
+):
     """Self-exclusion must mask the same doc in every backend."""
     docs, spec = engine_corpus
     qids = jnp.arange(8, dtype=jnp.int32)
@@ -67,7 +73,7 @@ def test_backend_parity_exclude(built_index, engine_corpus, backend):
     ref = get_engine(built_index, "reference").search(
         qw, probes=6, k=10, exclude=qids
     )
-    out = get_engine(built_index, backend).search(
+    out = case_engine(built_index, backend).search(
         qw, probes=6, k=10, exclude=qids
     )
     _assert_parity(ref, out, backend)
@@ -75,7 +81,9 @@ def test_backend_parity_exclude(built_index, engine_corpus, backend):
 
 
 @pytest.mark.parametrize("backend", BACKENDS[1:])
-def test_backend_parity_weighted(built_index, engine_corpus, backend):
+def test_backend_parity_weighted(
+    case_engine, built_index, engine_corpus, backend
+):
     """The dynamically-weighted path (the paper's setting)."""
     docs, spec = engine_corpus
     rng = np.random.default_rng(0)
@@ -84,7 +92,7 @@ def test_backend_parity_weighted(built_index, engine_corpus, backend):
     ref = get_engine(built_index, "reference").search_weighted(
         q, w, probes=9, k=7
     )
-    out = get_engine(built_index, backend).search_weighted(
+    out = case_engine(built_index, backend).search_weighted(
         q, w, probes=9, k=7
     )
     _assert_parity(ref, out, backend)
@@ -95,16 +103,16 @@ def test_index_search_delegates_to_backends(built_index, engine_corpus):
     docs, spec = engine_corpus
     qw = docs[5:9]
     ref = built_index.search(qw, probes=6, k=5)
-    for backend in BACKENDS[1:]:
+    for backend in available_backends()[1:]:
         out = built_index.search(qw, probes=6, k=5, backend=backend)
         _assert_parity(ref, out, backend)
 
 
-def test_single_query_shape(built_index, engine_corpus):
+def test_single_query_shape(case_engine, built_index, engine_corpus):
     docs, spec = engine_corpus
     w1 = jnp.ones((spec.s,)) / spec.s
     for backend in BACKENDS:
-        eng = get_engine(built_index, backend)
+        eng = case_engine(built_index, backend)
         s, i, n = eng.search(docs[3], probes=6, k=5)
         assert s.shape == (5,) and i.shape == (5,) and n.shape == ()
         # 1-D weighted queries keep the squeezed shape too (matches the
@@ -113,7 +121,7 @@ def test_single_query_shape(built_index, engine_corpus):
         assert s.shape == (5,) and i.shape == (5,) and n.shape == ()
 
 
-def test_nav_query_routes_probing(built_index, engine_corpus):
+def test_nav_query_routes_probing(case_engine, built_index, engine_corpus):
     """All backends navigate with nav_query but score with qw (CellDec
     semantics) — so they must still agree with each other."""
     docs, _ = engine_corpus
@@ -123,13 +131,13 @@ def test_nav_query_routes_probing(built_index, engine_corpus):
         qw, probes=6, k=10, nav_query=nav
     )
     for backend in BACKENDS[1:]:
-        out = get_engine(built_index, backend).search(
+        out = case_engine(built_index, backend).search(
             qw, probes=6, k=10, nav_query=nav
         )
         _assert_parity(ref, out, backend)
 
 
-def test_n_scored_counts_probed_buckets(built_index):
+def test_n_scored_counts_probed_buckets(case_engine, built_index):
     """n_scored == members of probed buckets (dups included) + T*K leaders."""
     idx = built_index
     qw = idx.docs[7:8]
@@ -141,13 +149,13 @@ def test_n_scored_counts_probed_buckets(built_index):
         _, top_c = jax.lax.top_k(lsims[:, ti, :], p)
         expected += int(jnp.sum(idx.counts[ti][top_c[0]]))
     for backend in BACKENDS:
-        _, _, n = get_engine(built_index, backend).search(qw, probes=6, k=5)
+        _, _, n = case_engine(built_index, backend).search(qw, probes=6, k=5)
         assert int(n[0]) == expected, backend
 
 
 def test_registry_and_autopick():
-    assert set(BACKENDS) <= set(available_backends())
-    assert pick_backend() in available_backends()
+    assert available_backends() == ("reference", "fused")
+    assert pick_backend()[0] in available_backends()
     with pytest.raises(ValueError, match="unknown backend"):
         get_engine(object(), "no-such-backend")
 
@@ -171,16 +179,16 @@ TS2_INT8 = _meta(100_000, 1000, 568, "int8")
 
 
 @pytest.mark.parametrize("platform, devices, limit, index, expected", [
-    ("tpu", 1, V5E_LIMIT, TS1, "fused"),
-    ("tpu", 1, V5E_LIMIT, TS2, "fused"),       # nothing to shard over
-    ("tpu", 4, V5E_LIMIT, TS1, "fused"),       # fits one chip
-    ("tpu", 4, V5E_LIMIT, TS2, "sharded"),
-    ("tpu", 4, V5E_LIMIT, TS2_INT8, "fused"),  # a quarter of the bytes
-    ("tpu", 4, V5E_LIMIT, None, "fused"),
-    ("tpu", 4, 0, TS2, "fused"),               # no limit reported
-    ("cpu", 1, 0, TS2, "reference"),
-    ("cpu", 4, 0, TS1, "sharded"),
-    ("cpu", 4, 0, None, "sharded"),
+    ("tpu", 1, V5E_LIMIT, TS1, ("fused", 1)),
+    ("tpu", 1, V5E_LIMIT, TS2, ("fused", 1)),       # nothing to shard over
+    ("tpu", 4, V5E_LIMIT, TS1, ("fused", 1)),       # fits one chip
+    ("tpu", 4, V5E_LIMIT, TS2, ("fused", 4)),
+    ("tpu", 4, V5E_LIMIT, TS2_INT8, ("fused", 1)),  # a quarter of the bytes
+    ("tpu", 4, V5E_LIMIT, None, ("fused", 1)),
+    ("tpu", 4, 0, TS2, ("fused", 1)),               # no limit reported
+    ("cpu", 1, 0, TS2, ("reference", 1)),
+    ("cpu", 4, 0, TS1, ("fused", 4)),
+    ("cpu", 4, 0, None, ("fused", 4)),
 ])
 def test_pick_by_platform_and_size(monkeypatch, platform, devices, limit,
                                    index, expected):
@@ -198,7 +206,9 @@ def test_exec_shape_picks_through_the_index(monkeypatch):
     req = SearchRequest(query=np.ones(4096, np.float32))
     shape = lambda index: exec_shape(req, default_backend="auto",
                                      default_probes=12, index=index).backend
-    assert (shape(TS1), shape(TS2)) == ("fused", "sharded")
+    assert (shape(TS1), shape(TS2)) == ("fused", "fused")
+    assert (pick_backend(TS1), pick_backend(TS2)) == (("fused", 1),
+                                                      ("fused", 4))
 
 
 def test_pick_leaves_its_marker(monkeypatch):
@@ -219,10 +229,11 @@ def test_pick_leaves_its_marker(monkeypatch):
 
     monkeypatch.setattr(engine, "_host", lambda: ("tpu", 4, V5E_LIMIT))
     monkeypatch.setattr(engine, "span", Recorder)
-    assert pick_backend(TS2) == "sharded"
+    assert pick_backend(TS2) == ("fused", 4)
     assert engine.pack_bytes(TS2) == 3 * 1000 * 568 * 4096 * 4
     assert marks == [(tracing.ENGINE_PICK, {
-        "backend": "sharded", "pack_bytes": 3 * 1000 * 568 * 4096 * 4,
+        "backend": "fused", "shards": 4,
+        "pack_bytes": 3 * 1000 * 568 * 4096 * 4,
         "bytes_limit": V5E_LIMIT, "devices": 4})]
 
 
@@ -311,14 +322,12 @@ def bf16_index(built_index):
     buckets are shared, so probing is identical)."""
     import dataclasses
 
-    return dataclasses.replace(
-        built_index, bucket_data=None, pack_dtype="bfloat16"
-    )
+    return dataclasses.replace(built_index, pack_dtype="bfloat16")
 
 
 def test_bf16_pack_halves_bucket_major_bytes(built_index, bf16_index):
-    d32, i32, sc32 = built_index.ensure_bucket_major()
-    d16, i16, sc16 = bf16_index.ensure_bucket_major()
+    d32, i32, sc32, _ = built_index.ensure_local_bucket_major(1)
+    d16, i16, sc16, _ = bf16_index.ensure_local_bucket_major(1)
     assert d16.dtype == jnp.bfloat16
     assert d16.nbytes * 2 == d32.nbytes
     assert np.array_equal(np.asarray(i16), np.asarray(i32))
@@ -367,19 +376,17 @@ def int8_index(built_index):
     stored vector precision drops."""
     import dataclasses
 
-    return dataclasses.replace(
-        built_index, bucket_data=None, bucket_scales=None, pack_dtype="int8"
-    )
+    return dataclasses.replace(built_index, pack_dtype="int8")
 
 
 def test_int8_pack_quarters_bucket_major_bytes(built_index, int8_index):
-    d32, i32, sc32 = built_index.ensure_bucket_major()
-    d8, i8, sc8 = int8_index.ensure_bucket_major()
+    d32, i32, sc32, _ = built_index.ensure_local_bucket_major(1)
+    d8, i8, sc8, _ = int8_index.ensure_local_bucket_major(1)
     assert d8.dtype == jnp.int8
     assert d8.nbytes * 4 == d32.nbytes
     assert np.array_equal(np.asarray(i8), np.asarray(i32))
     assert sc32 is None
-    assert sc8 is not None and sc8.shape == (i8.shape[0],)
+    assert sc8 is not None and sc8.shape == i8.shape[:2]
     assert np.all(np.asarray(sc8) > 0)
 
 
@@ -413,7 +420,9 @@ def test_int8_pack_parity(built_index, int8_index, engine_corpus, nq):
 # ---------------------------------------------------------- rescore tail
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("nq", [1, QT - 1, QT + 1])
-def test_rescore_fp32_identity(built_index, engine_corpus, backend, nq):
+def test_rescore_fp32_identity(
+    case_engine, built_index, engine_corpus, backend, nq
+):
     """On an fp32 pack the exact-rescore tail re-scores already-exact
     candidates: ids and scores are IDENTICAL to the plain search on every
     backend and ragged shape, and only n_scored grows (the re-scored
@@ -421,7 +430,7 @@ def test_rescore_fp32_identity(built_index, engine_corpus, backend, nq):
     docs, _ = engine_corpus
     qw = docs[100:100 + nq]
     ex = jnp.arange(100, 100 + nq, dtype=jnp.int32)
-    eng = get_engine(built_index, backend)
+    eng = case_engine(built_index, backend)
     s0, i0, n0 = eng.search(qw, probes=6, k=10, exclude=ex)
     s1, i1, n1 = eng.search(qw, probes=6, k=10, exclude=ex, rescore=25)
     assert np.array_equal(np.asarray(i0), np.asarray(i1)), backend
@@ -506,7 +515,8 @@ def test_device_schedule_matches_host_schedule_end_to_end(
     qw = docs[100:100 + nq]
     ex = jnp.arange(100, 100 + nq, dtype=jnp.int32)
     eng = get_engine(built_index, "fused", query_tile=QT)
-    data, ids, scales = built_index.ensure_bucket_major()
+    data, ids, scales, _ = built_index.ensure_local_bucket_major(1)
+    data, ids = data[0], ids[0]
     flat = eng._flat_probes(qw, eng._probes_t(6))
     hs, hm = build_probe_schedule(np.asarray(flat), QT)
     s_len = schedule_length(QT, int(flat.shape[1]), int(ids.shape[0]))
@@ -547,16 +557,14 @@ def test_fused_search_builds_schedule_under_jit(built_index, engine_corpus,
 
 
 def test_lazy_bucket_major(engine_corpus):
-    """A build that defers packing still serves fused via lazy conversion."""
+    """The build packs nothing; the first fused search packs, once."""
     docs, spec = engine_corpus
-    idx = ClusterPruneIndex.build(
-        docs, spec, 16, n_clusterings=2, pack_major=False,
-    )
-    assert idx.bucket_data is None
+    idx = ClusterPruneIndex.build(docs, spec, 16, n_clusterings=2)
+    assert "_local_bucket_major" not in vars(idx)  # the build packs nothing
     qw = docs[10:14]
     ref = get_engine(idx, "reference").search(qw, probes=4, k=5)
     out = get_engine(idx, "fused").search(qw, probes=4, k=5)
-    assert idx.bucket_data is not None            # cached after first use
+    assert vars(idx)["_local_bucket_major"]       # cached after first use
     _assert_parity(ref, out, "fused-lazy")
 
 
@@ -569,13 +577,15 @@ def _gt(index, qw, k, exclude):
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_exact_tier_matches_brute_force(built_index, engine_corpus, backend):
+def test_exact_tier_matches_brute_force(
+    case_engine, built_index, engine_corpus, backend
+):
     """search_exact sweeps all T*K buckets: ids identical to brute force,
     scores to float tolerance, on every backend."""
     docs, _ = engine_corpus
     qw = docs[20:36]
     ex = jnp.arange(20, 36, dtype=jnp.int32)
-    s, i, n = get_engine(built_index, backend).search_exact(
+    s, i, n = case_engine(built_index, backend).search_exact(
         qw, k=10, exclude=ex
     )
     gt_s, gt_i = _gt(built_index, qw, 10, ex)
@@ -617,13 +627,15 @@ def test_exact_tier_quantised_packs(built_index, bf16_index, int8_index,
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_oversized_probes_clamp(built_index, engine_corpus, backend):
+def test_oversized_probes_clamp(
+    case_engine, built_index, engine_corpus, backend
+):
     """Regression: an explicit probes= budget past T*K used to push
     jax.lax.top_k(lsims, p) past K and die with an opaque XLA error; it
     now clamps to the documented probe-everything = exact semantics."""
     docs, _ = engine_corpus
     qw = docs[50:58]
-    eng = get_engine(built_index, backend)
+    eng = case_engine(built_index, backend)
     t, kc = built_index.counts.shape
     total = int(t * kc)
     s_all, i_all, n_all = eng.search(qw, probes=total, k=10)
@@ -727,14 +739,13 @@ def test_escalation_validates_floor(built_index, engine_corpus):
         )
 
 
-# ------------------------------------------------------- sharded-fused path
-def test_sharded_navigation_runs_once(built_index, engine_corpus):
-    """The sharded engine computes leader top-p ONCE per search: the same
-    flat probe tensor feeds the replicated probe-dedup schedule and the
-    n_scored accounting (the old path navigated in the shard_map body AND
-    again on host for the cost numbers)."""
+# ------------------------------------------------- fused over an explicit mesh
+def test_sharded_navigation_runs_once(case_engine, built_index, engine_corpus):
+    """The engine computes leader top-p ONCE per search: the same flat
+    probe tensor feeds the replicated probe-dedup schedule and the
+    n_scored accounting."""
     docs, _ = engine_corpus
-    eng = get_engine(built_index, "sharded", interpret=True)
+    eng = case_engine(built_index, "sharded", interpret=True)
     calls = {"n": 0}
     orig = type(eng)._flat_probes
 
@@ -750,7 +761,7 @@ def test_sharded_navigation_runs_once(built_index, engine_corpus):
     assert calls["n"] == 1
 
 
-def test_sharded_lazy_repack_on_mutation(engine_corpus):
+def test_sharded_lazy_repack_on_mutation(case_engine, engine_corpus):
     """One engine object across add/remove: the shard-local pack re-places
     itself on the first search after a version bump and stays in parity
     with a fresh reference engine."""
@@ -759,7 +770,7 @@ def test_sharded_lazy_repack_on_mutation(engine_corpus):
         docs, spec, 16, n_clusterings=3, method="fpf",
         key=jax.random.PRNGKey(0),
     )
-    eng = get_engine(idx, "sharded", interpret=True)
+    eng = case_engine(idx, "sharded", interpret=True)
     qw = docs[20:28]
     eng.search(qw, probes=6, k=10)
     v0 = eng._pack_version
@@ -775,19 +786,19 @@ def test_sharded_lazy_repack_on_mutation(engine_corpus):
     _assert_parity(ref, out, "post-remove sharded")
 
 
-def test_sharded_engine_cached_and_opts_keyed(built_index):
-    """Sharded engines cache on the index like every backend, keyed by
-    opts (the default mesh is constructed inside __init__, so the opts
-    key stays hashable)."""
-    e1 = get_engine(built_index, "sharded", interpret=True)
-    e2 = get_engine(built_index, "sharded", interpret=True)
-    e3 = get_engine(built_index, "sharded", interpret=True, query_tile=8)
+def test_sharded_engine_cached_and_opts_keyed(case_engine, built_index):
+    """Engines over an explicit mesh cache on the index like every
+    backend, keyed by opts: equal meshes are one key."""
+    e1 = case_engine(built_index, "sharded", interpret=True)
+    e2 = case_engine(built_index, "sharded", interpret=True)
+    e3 = case_engine(built_index, "sharded", interpret=True, query_tile=8)
     assert e1 is e2 and e1 is not e3
 
 
 @pytest.mark.parametrize("nq", [1, 5])
-def test_sharded_quantised_rescore_recovers_fp32(built_index, int8_index,
-                                                 engine_corpus, nq):
+def test_sharded_quantised_rescore_recovers_fp32(
+    case_engine, built_index, int8_index, engine_corpus, nq
+):
     """int8 shard-local storage + the sharded rescore tail returns the
     fp32 reference's exact ids and scores — the distributed rescore
     (ownership masks + pmax all-reduce) is score-identical to the
@@ -797,7 +808,44 @@ def test_sharded_quantised_rescore_recovers_fp32(built_index, int8_index,
     ref = get_engine(built_index, "reference").search(
         qw, probes=6, k=5, rescore=25
     )
-    out = get_engine(int8_index, "sharded", interpret=True).search(
+    out = case_engine(int8_index, "sharded", interpret=True).search(
         qw, probes=6, k=5, rescore=25
     )
     _assert_parity(ref, out, "sharded int8 rescore")
+
+
+# ------------------------------------------------------ the one-shard pack
+@pytest.mark.parametrize("pack_dtype", [None, "bfloat16", "int8"])
+def test_one_shard_pack_is_the_bucket_major_pack(built_index, pack_dtype):
+    """On one device the engine scores the index's bucket-major pack
+    itself: its data and ids equal ``pack_bucket_major`` of the buckets,
+    flattened to ``(T·K, B, D)``, bit for bit, and an int8 pack's scales
+    equal its per-bucket scales (fp32 and bf16 packs carry unit scales).
+    This is what keeps a one-chip deployment's answers unchanged."""
+    import dataclasses
+
+    from repro.kernels.bucket_score.ops import pack_bucket_major
+
+    idx = dataclasses.replace(built_index, pack_dtype=pack_dtype)
+    eng = get_engine(idx, "fused")
+    assert eng.n_shards == 1
+    data, ids, scales, n_local = eng._ensure_placed()
+    # under jit, as the engine packs: XLA turns the int8 scale's division
+    # by a constant into a multiply, a last-bit change from eager dispatch
+    pack = jax.jit(pack_bucket_major, static_argnames="dtype")
+    want, want_ids, want_scales = pack(
+        idx.docs, jnp.where(idx.buckets < idx.n_docs, idx.buckets, -1),
+        dtype=pack_dtype,
+    )
+    t, kc, b, d = (int(x) for x in want.shape)
+    assert n_local == idx.n_docs
+    assert data.shape == (1, t * kc, b, d) and data.dtype == want.dtype
+    assert np.asarray(data).tobytes() == np.asarray(want).tobytes()
+    assert np.array_equal(np.asarray(ids).reshape(t, kc, b),
+                          np.asarray(want_ids))
+    if pack_dtype == "int8":
+        assert (np.asarray(scales).tobytes()
+                == np.asarray(want_scales).tobytes())
+    else:
+        assert want_scales is None
+        assert np.all(np.asarray(scales) == 1.0)

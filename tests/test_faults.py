@@ -255,7 +255,7 @@ def retriever():
     docs = normalize_fields(x, spec)
     r = Retriever.build(
         docs, spec, 16, n_clusterings=3, method="fpf",
-        key=jax.random.PRNGKey(0), pack_major=True, backend="reference",
+        key=jax.random.PRNGKey(0), backend="reference",
     )
     # warm the trace so live fault tests measure dispatch policy, not XLA
     r.search([SearchRequest(like=i, probes=6, k=5) for i in range(4)])

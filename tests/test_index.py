@@ -142,8 +142,8 @@ def test_celldec_search(small_corpus):
 
 # ---------------------------------------------- pack_dtype + int8 scales
 def test_validate_pack_dtype_single_gate(random_corpus):
-    """One gate, one error message: build / ensure_bucket_major / load all
-    reject unsupported dtypes through validate_pack_dtype."""
+    """One gate, one error message: build / ensure_local_bucket_major /
+    load all reject unsupported dtypes through validate_pack_dtype."""
     import dataclasses
 
     from repro.core import (
@@ -159,30 +159,31 @@ def test_validate_pack_dtype_single_gate(random_corpus):
         with pytest.raises(ValueError, match="unsupported pack_dtype"):
             validate_pack_dtype(bad)
     with pytest.raises(ValueError, match="unsupported pack_dtype"):
-        ClusterPruneIndex.build(docs, spec, 8, pack_major=True,
-                                pack_dtype="float16")
+        ClusterPruneIndex.build(docs, spec, 8, pack_dtype="float16")
     # a twin mutated to a bad dtype is caught at the lazy re-pack, before
     # the fused engine ever sees malformed bucket storage
-    idx = ClusterPruneIndex.build(docs, spec, 8, pack_major=False)
+    idx = ClusterPruneIndex.build(docs, spec, 8)
     bad = dataclasses.replace(idx, pack_dtype="float64")
     with pytest.raises(ValueError, match="unsupported pack_dtype"):
-        bad.ensure_bucket_major()
+        bad.ensure_local_bucket_major(1)
 
 
 def test_int8_build_quarters_bytes_and_searches(random_corpus):
-    """build(pack_dtype='int8') stores the bucket-major tensor at a quarter
+    """build(pack_dtype='int8') stores the bucket-major pack at a quarter
     of fp32 bytes, carries per-bucket scales, and serves searches."""
     docs, spec = random_corpus
     f32 = ClusterPruneIndex.build(docs, spec, 12, n_clusterings=3,
-                                  key=jax.random.PRNGKey(0), pack_major=True)
+                                  key=jax.random.PRNGKey(0))
     i8 = ClusterPruneIndex.build(docs, spec, 12, n_clusterings=3,
-                                 key=jax.random.PRNGKey(0), pack_major=True,
+                                 key=jax.random.PRNGKey(0),
                                  pack_dtype="int8")
-    assert i8.bucket_data.dtype == jnp.int8
-    assert i8.bucket_data.nbytes * 4 == f32.bucket_data.nbytes
-    assert i8.bucket_scales is not None
-    assert i8.bucket_scales.shape == i8.bucket_data.shape[:2]
-    assert bool(jnp.all(i8.bucket_scales > 0))
+    d32 = f32.ensure_local_bucket_major(1)[0]
+    d8, _, sc8, _ = i8.ensure_local_bucket_major(1)
+    assert d8.dtype == jnp.int8
+    assert d8.nbytes * 4 == d32.nbytes
+    assert sc8 is not None
+    assert sc8.shape == d8.shape[:2]
+    assert bool(jnp.all(sc8 > 0))
     q = weighted_query(docs[3:9], jnp.ones((6, 3)) / 3, spec)
     _, gt_i = brute_force_topk(docs, q, 5)
     _, ids, _ = i8.search(q, probes=8, k=5, backend="fused")
@@ -191,37 +192,40 @@ def test_int8_build_quarters_bytes_and_searches(random_corpus):
 
 
 def test_int8_scales_survive_save_load(tmp_path, random_corpus):
-    """Quantised pack + per-bucket scales round-trip through save/load
-    bit-exactly; a loaded int8 index answers identically to the original."""
+    """A quantised index round-trips through save/load: the archive holds
+    no pack and no scales, the (deterministic) lazy re-pack reproduces the
+    int8 tensor and its per-bucket scales bit-exactly, and a loaded int8
+    index answers identically to the original. An archive that still
+    carries ``bucket_scales`` loads."""
     docs, spec = random_corpus
     idx = ClusterPruneIndex.build(docs, spec, 12, n_clusterings=3,
-                                  key=jax.random.PRNGKey(0), pack_major=True,
+                                  key=jax.random.PRNGKey(0),
                                   pack_dtype="int8")
     path = tmp_path / "int8.npz"
     idx.save(path)
+    members = dict(np.load(path))
+    assert "bucket_scales" not in members
     loaded = ClusterPruneIndex.load(path)
     assert loaded.pack_dtype == "int8"
-    # scales come back bit-exact from the archive; the (deterministic)
-    # lazy re-pack then reproduces the identical int8 tensor against them
-    np.testing.assert_array_equal(np.asarray(loaded.bucket_scales),
-                                  np.asarray(idx.bucket_scales))
-    loaded.ensure_bucket_major()
-    assert loaded.bucket_data.dtype == jnp.int8
-    assert np.array_equal(np.asarray(loaded.bucket_data),
-                          np.asarray(idx.bucket_data))
-    np.testing.assert_array_equal(np.asarray(loaded.bucket_scales),
-                                  np.asarray(idx.bucket_scales))
+    d0, _, sc0, _ = idx.ensure_local_bucket_major(1)
+    d1, _, sc1, _ = loaded.ensure_local_bucket_major(1)
+    assert d1.dtype == jnp.int8
+    assert np.array_equal(np.asarray(d1), np.asarray(d0))
+    np.testing.assert_array_equal(np.asarray(sc1), np.asarray(sc0))
+    legacy = tmp_path / "legacy.npz"
+    np.savez(legacy, **members, bucket_scales=np.asarray(sc0)[0])
+    assert ClusterPruneIndex.load(legacy).pack_dtype == "int8"
     q = weighted_query(docs[11:15], jnp.ones((4, 3)) / 3, spec)
     s0, i0, n0 = idx.search(q, probes=8, k=6, backend="fused")
     s1, i1, n1 = loaded.search(q, probes=8, k=6, backend="fused")
     assert np.array_equal(np.asarray(i0), np.asarray(i1))
     np.testing.assert_allclose(np.asarray(s0), np.asarray(s1), atol=1e-6)
     assert np.array_equal(np.asarray(n0), np.asarray(n1))
-    # fp32/bf16 indexes persist WITHOUT scales and load back scale-free
-    f32 = ClusterPruneIndex.build(docs, spec, 12, pack_major=True)
+    # fp32/bf16 packs carry no scales, before and after a round-trip
+    f32 = ClusterPruneIndex.build(docs, spec, 12)
     p2 = tmp_path / "f32.npz"
     f32.save(p2)
-    assert ClusterPruneIndex.load(p2).bucket_scales is None
+    assert ClusterPruneIndex.load(p2).ensure_local_bucket_major(1)[2] is None
 
 
 def test_paper_ordering_on_structured_corpus(small_corpus):
@@ -289,8 +293,7 @@ def test_ensure_local_bucket_major_cache_and_invalidate(random_corpus):
     assert (np.asarray(ids2) >= 0).sum() == t * (n + 1)
 
     # int8: quarter the packed bytes, scales per (shard, bucket)
-    i8 = dataclasses.replace(idx, bucket_data=None, bucket_scales=None,
-                             pack_dtype="int8")
+    i8 = dataclasses.replace(idx, pack_dtype="int8")
     d8, ids8, sc8, _ = i8.ensure_local_bucket_major(4)
     assert d8.dtype == jnp.int8 and sc8.shape == d8.shape[:2]
     assert d8.shape == data2.shape and data2.nbytes == 4 * d8.nbytes

@@ -64,7 +64,7 @@ def quality_setup():
     docs = jnp.asarray(docs_np)
     index = ClusterPruneIndex.build(
         docs, spec, 40, n_clusterings=3, method="fpf",
-        key=jax.random.PRNGKey(0), pack_major=True,
+        key=jax.random.PRNGKey(0),
     )
     rng = np.random.default_rng(11)
     qids = jnp.asarray(rng.choice(1500, 32, replace=False), jnp.int32)
@@ -83,11 +83,11 @@ def quality_setup():
 
 @pytest.mark.slow
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_quality_floors(quality_setup, backend):
+def test_quality_floors(case_engine, quality_setup, backend):
     """Mean CR and NAG at fixed probe budgets stay above the pinned floors
     on every backend, for every weight set."""
     index, qids, cells = quality_setup
-    engine = get_engine(index, backend)
+    engine = case_engine(index, backend)
     for probes, cr_floor, nag_floor in QUALITY_FLOORS:
         for wi, (qw, gt_s, gt_i, far_s) in enumerate(cells):
             s, ids, _ = engine.search(qw, probes=probes, k=K_NN, exclude=qids)
@@ -112,8 +112,8 @@ def test_quality_floors_bf16_pack(quality_setup):
     import dataclasses
 
     index, qids, cells = quality_setup
-    bf16 = dataclasses.replace(index, bucket_data=None, pack_dtype="bfloat16")
-    data, _, _ = bf16.ensure_bucket_major()
+    bf16 = dataclasses.replace(index, pack_dtype="bfloat16")
+    data, _, _, _ = bf16.ensure_local_bucket_major(1)
     assert data.dtype == jnp.bfloat16
     engine = get_engine(bf16, "fused")
     for probes, cr_floor, nag_floor in QUALITY_FLOORS:
@@ -142,10 +142,8 @@ def test_quality_floors_int8_pack_with_rescore(quality_setup):
     import dataclasses
 
     index, qids, cells = quality_setup
-    i8 = dataclasses.replace(
-        index, bucket_data=None, bucket_scales=None, pack_dtype="int8"
-    )
-    data, _, scales = i8.ensure_bucket_major()
+    i8 = dataclasses.replace(index, pack_dtype="int8")
+    data, _, scales, _ = i8.ensure_local_bucket_major(1)
     assert data.dtype == jnp.int8 and scales is not None
     engine = get_engine(i8, "fused")
     for probes, cr_floor, nag_floor in QUALITY_FLOORS:
@@ -166,13 +164,13 @@ def test_quality_floors_int8_pack_with_rescore(quality_setup):
 
 @pytest.mark.slow
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_quality_floors_with_rescore(quality_setup, backend):
+def test_quality_floors_with_rescore(case_engine, quality_setup, backend):
     """The exact-rescore tail can only re-rank candidates the pruned search
     already surfaced — on an fp32 pack it must keep every backend above the
     same pinned floors (it is an identity there), so a backend whose
     rescore plumbing dropped candidates fails HERE."""
     index, qids, cells = quality_setup
-    engine = get_engine(index, backend)
+    engine = case_engine(index, backend)
     for probes, cr_floor, nag_floor in QUALITY_FLOORS:
         qw, gt_s, gt_i, far_s = cells[0]
         s, ids, _ = engine.search(
@@ -200,9 +198,7 @@ def test_pack_dtype_topk_overlap_floors(quality_setup):
     index, qids, cells = quality_setup
     f32 = get_engine(index, "fused")
     for pack, floor in OVERLAP_FLOORS.items():
-        twin = dataclasses.replace(
-            index, bucket_data=None, bucket_scales=None, pack_dtype=pack
-        )
+        twin = dataclasses.replace(index, pack_dtype=pack)
         eng = get_engine(twin, "fused")
         for probes, _, _ in QUALITY_FLOORS:
             for wi, (qw, _, _, _) in enumerate(cells):

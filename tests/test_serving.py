@@ -16,6 +16,7 @@ from repro.core import (
     FieldSpec,
     Retriever,
     SearchRequest,
+    get_engine,
     normalize_fields,
 )
 from repro.serving import (
@@ -222,7 +223,7 @@ def retriever(serving_corpus):
     docs, spec = serving_corpus
     return Retriever.build(
         docs, spec, 16, n_clusterings=3, method="fpf",
-        key=jax.random.PRNGKey(0), pack_major=True, backend="reference",
+        key=jax.random.PRNGKey(0), backend="reference",
     )
 
 
@@ -245,21 +246,23 @@ def test_default_max_batch(retriever):
     assert default_max_batch(retriever) == 64     # reference doesn't tile
     fused = Retriever(retriever.index, backend="fused")
     mb = default_max_batch(fused)
-    from repro.serving.server import _engine_query_tile
-
-    qt = _engine_query_tile(fused)
+    qt = get_engine(fused.index, "fused").tile_for(10)
     assert qt and mb >= 64 and mb % qt == 0       # full MXU tiles
+    tiled = Retriever(retriever.index, backend="fused",
+                      engine_opts={"query_tile": 24})
+    assert default_max_batch(tiled) == 72         # the engine's own tile
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_ragged_batch_parity_vs_one_by_one(retriever, backend):
+def test_ragged_batch_parity_vs_one_by_one(case_retriever, retriever, backend):
     """11 concurrent submits against max_batch=8 -> one full batch plus a
     ragged tail of 3; every response must match one-by-one sync search."""
-    requests = mlt_requests(11, seed=1, backend=backend, probes=6, k=5)
+    served, name = case_retriever(retriever, backend)
+    requests = mlt_requests(11, seed=1, backend=name, probes=6, k=5)
 
     async def go():
         async with SearchServer(
-            retriever, window_s=0.01, max_batch=8
+            served, window_s=0.01, max_batch=8
         ) as server:
             return await asyncio.gather(
                 *(server.submit(r) for r in requests)
@@ -269,12 +272,13 @@ def test_ragged_batch_parity_vs_one_by_one(retriever, backend):
     assert snap["completed"] == 11
     assert sorted(r.batch_size for r in responses) == [3] * 3 + [8] * 8
 
-    solo = Retriever(retriever.index, backend=backend)  # fresh: no caches
+    # fresh: no caches
+    solo, _ = case_retriever(Retriever(retriever.index, backend=name), backend)
     for resp, req in zip(responses, requests):
         ref = solo.search(req)
         assert np.array_equal(resp.doc_ids, ref.doc_ids), backend
         np.testing.assert_allclose(resp.scores, ref.scores, atol=1e-6)
-        assert resp.backend == backend
+        assert resp.backend == name
         # the server stamped an honest per-request latency split
         assert resp.queue_wait_s >= 0 and resp.compute_s > 0
         assert resp.latency_s == pytest.approx(

@@ -1,4 +1,4 @@
-"""``SearchServer`` over the sharded engine that ``"auto"`` picks on four
+"""``SearchServer`` over the fused engine that ``"auto"`` picks over four
 (virtual CPU) devices: full batches, answered as the ``reference`` backend
 answers, id for id and score for score, with each request's own document
 left out and ``k`` hits, where the requests' own documents and their
@@ -40,12 +40,14 @@ async def serve():
         return await asyncio.gather(*(server.submit(q) for q in reqs))
 
 got = asyncio.run(serve())
-_, top, _ = get_engine(r.index, "sharded").search(docs[:4], probes=6, k=K)
+engine = get_engine(r.index, r.backend)
+_, top, _ = engine.search(docs[:4], probes=6, k=K)
 ref = Retriever(r.index, backend="reference").search(reqs)
 n_local = shard_rows(N, 4)
 own = [q.like // n_local for q in reqs]
 print(json.dumps({
     "backend": r.backend,
+    "shards": engine.n_shards,
     "served_by": sorted({a.backend for a in got}),
     "batches": sorted({a.batch_size for a in got}),
     "max_batch": MAX_BATCH,
@@ -59,8 +61,8 @@ print(json.dumps({
     "hits_off_own_shard": sum(int(h // n_local != s)
                               for a, s in zip(got, own) for h in a.ids),
     "answer_devices": len(top.devices()),
-    "global_pack": r.index.bucket_data is not None
-                   or "_bucket_major_flat" in vars(r.index),
+    "global_pack": sorted(vars(r.index)["_local_bucket_major"]) != [4]
+                   or len(engine._ensure_placed()[0].devices()) != 4,
 }))
 """
 
@@ -74,7 +76,8 @@ def test_served_sharded_search_equals_reference(max_batch):
                          capture_output=True, text=True, timeout=600, env=env)
     assert out.returncode == 0, out.stderr[-3000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
-    assert res["backend"] == "sharded" and res["served_by"] == ["sharded"]
+    assert res["backend"] == "fused" and res["served_by"] == ["fused"]
+    assert res["shards"] == 4
     assert res["batches"] == [max_batch]
     assert res["ids_equal"] and res["score_gap"] <= 1e-5, res
     assert res["own_left_out"] and res["k_hits"]

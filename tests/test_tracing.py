@@ -147,8 +147,10 @@ def test_compile_marker_lands_in_the_span_that_compiled(events):
 def test_pick_marker_carries_what_it_chose_by(events):
     pick, = _named(events, tracing.ENGINE_PICK)
     stats = pick[4]
-    assert set(stats) == {"backend", "pack_bytes", "bytes_limit", "devices"}
+    assert set(stats) == {"backend", "shards", "pack_bytes", "bytes_limit",
+                          "devices"}
     assert stats["backend"] == "reference" and stats["devices"] == 1
+    assert stats["shards"] == 1
     # the 400-document index: T=3 clusterings of 6 buckets, 64 dims, fp32
     assert stats["pack_bytes"] > 0 and stats["pack_bytes"] % (3 * 6 * 64 * 4) == 0
     assert stats["bytes_limit"] == 0          # the CPU reports no limit
@@ -161,7 +163,7 @@ def test_score_span_carries_the_grid_steps_launched(events):
     scores = _named(events, tracing.ENGINE_SCORE)
     assert scores
     for e in scores:
-        assert "shards" not in e[4]
+        assert e[4]["shards"] == 1
         assert e[4]["slots"] == 1 * schedule_length(8, 4, 3 * 6), e[4]
 
 
@@ -179,7 +181,7 @@ spec = FieldSpec(names=("a", "b", "c"), dims=(16, 16, 32))
 docs = normalize_fields(
     jax.random.normal(jax.random.PRNGKey(0), (300, spec.total_dim)), spec)
 index = ClusterPruneIndex.build(docs, spec, 6, key=jax.random.PRNGKey(1))
-engine = get_engine(index, "sharded")
+engine = get_engine(index, "fused")
 d = tempfile.mkdtemp()
 jax.profiler.start_trace(d)
 try:
@@ -195,7 +197,7 @@ print(json.dumps([dict(e.stats) for p in data.planes if p.name == "/host:CPU"
 
 
 def test_sharded_score_span_carries_slots_and_shards():
-    """On four (virtual CPU) devices the sharded engine's score span
+    """On four (virtual CPU) devices the fused engine's score span
     carries the grid it launched on every shard and the shard count: 19
     queries are one tile of 24, of 4 probes over the 3·6 buckets."""
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
