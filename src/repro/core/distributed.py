@@ -288,9 +288,10 @@ def distributed_index_search(
 # ------------------------------------------------------ shard-local scoring
 @functools.lru_cache(maxsize=128)
 def _bucket_score_fn(mesh, axes, k, k_out, n_local, interpret):
-    """Build (once per static config) the jitted shard_map scorer, the
-    program ``jit_shard_score_merge``: each shard's kernel, the all-gather
-    and the merge.
+    """Build (once per static config) the jitted shard_map scorer
+    ``shard_score_merge``: each shard's kernel, the all-gather and the
+    merge. Called alone it is the program ``jit_shard_score_merge``; the
+    fused engine runs it inside its one search program.
 
     Caching the callable is what makes the hot path trace-stable: ``jit``
     keys on function identity, so a fresh closure per search would retrace
@@ -326,6 +327,22 @@ def _bucket_score_fn(mesh, axes, k, k_out, n_local, interpret):
         out_specs=(P(None, None), P(None, None)),
         check_vma=False,
     ))
+
+
+def _bucket_scorer(mesh, axes, pack_shape, s_len, *, k, n_local, interpret):
+    """The cached ``shard_score_merge`` for a ``(S, T·K, B_l, D)`` pack
+    scored over a schedule of ``s_len`` slots. Its answer has ``k' = min(k,
+    shards · per-shard columns)`` columns: each shard's kernel clips ``k``
+    to ``min(pad_to(k, 8), B_l · s_len)``."""
+    from ..kernels.common import pad_to
+
+    n_shards, _, b_l, _ = (int(x) for x in pack_shape)
+    cols = min(min(pad_to(k, 8), b_l * int(s_len)), k)
+    k_out = min(k, n_shards * cols)
+    return _bucket_score_fn(
+        mesh, tuple(axes), int(k), int(k_out), int(n_local),
+        None if interpret is None else bool(interpret),
+    )
 
 
 def distributed_bucket_score(
@@ -364,22 +381,14 @@ def distributed_bucket_score(
     when it exceeds every candidate the schedule can surface, mirroring the
     single-device kernel's ``k_pad`` clip).
     """
-    from ..kernels.common import pad_to
-
-    axes = tuple(shard_axes)
     nq = qw.shape[0]
     if exclude is None:
         exclude = jnp.full((nq,), -1, jnp.int32)
     if scales is None:
         scales = jnp.ones(data.shape[:2], jnp.float32)
-    n_shards, _, b_l, _ = (int(x) for x in data.shape)
-    s_len = int(schedule.shape[1])
-    # per-shard output columns after the kernel's k_pad clip
-    cols = min(min(pad_to(k, 8), b_l * s_len), k)
-    k_out = min(k, n_shards * cols)
-    fn = _bucket_score_fn(
-        mesh, axes, int(k), int(k_out), int(n_local),
-        None if interpret is None else bool(interpret),
+    fn = _bucket_scorer(
+        mesh, tuple(shard_axes), data.shape, int(schedule.shape[1]), k=k,
+        n_local=n_local, interpret=interpret,
     )
     return fn(
         data, ids, scales.astype(jnp.float32), qw,

@@ -9,10 +9,11 @@ buckets, and merge a deduplicated top-k.
 ``fused``
     The query-tiled Pallas ``bucket_score`` kernel over a mesh of S >= 1
     devices. Each device holds a bucket-major ``(T*K, B_l, D)`` pack of its
-    row slice of every bucket, in the index's ``pack_dtype``. Navigation and
-    the probe-dedup schedule run once, replicated. Each shard scores its
-    blocks, and one ``all_gather`` of the per-shard top-k merges the answer.
-    At S=1 the pack is the whole corpus's and the merge is over one shard.
+    row slice of every bucket, in the index's ``pack_dtype``. A search is
+    one device program: navigation and the probe-dedup schedule run once,
+    replicated, each shard scores its blocks, and one ``all_gather`` of the
+    per-shard top-k merges the answer. At S=1 the pack is the whole
+    corpus's and the merge is over one shard.
 
 Both backends share the probe semantics (:func:`split_probes` divides the
 budget evenly over the T clusterings), the navigation-vs-scoring query
@@ -291,8 +292,43 @@ _EXACT_RESCORE_FACTOR = 4
 
 
 # --------------------------------------------------------------------- shared
+def _canonical(qw, nav_query, exclude):
+    """``(qw (nq, D), nav (nq, D), exclude (nq,) int32, single)``: a 1-D
+    query as a batch of one, navigation on ``qw`` unless ``nav_query`` is
+    given, and ``exclude`` (None, a scalar or ``(nq,)``) with ``-1`` for
+    "none". Pure, so it runs eagerly or inside a trace."""
+    single = qw.ndim == 1
+    qw = jnp.atleast_2d(qw)
+    nav = qw if nav_query is None else jnp.atleast_2d(nav_query)
+    nq = qw.shape[0]
+    if exclude is None:
+        exclude = jnp.full((nq,), -1, jnp.int32)
+    exclude = jnp.broadcast_to(
+        jnp.atleast_1d(exclude), (nq,)
+    ).astype(jnp.int32)
+    return qw, nav, exclude, single
+
+
+def _finish(single, scores, ids, n_scored):
+    if single:
+        return scores[0], ids[0], n_scored[0]
+    return scores, ids, n_scored
+
+
+def _n_scored(counts, flat_probes):
+    """Fig-1 accounting: every member of a probed bucket is one distance
+    computation (dups across clusterings included — they really are
+    scored), plus the T*K leader comparisons. ``counts`` is the index's
+    ``(T·K,)`` bucket sizes, flattened as the probes are."""
+    return (
+        jnp.sum(counts[flat_probes], axis=-1).astype(jnp.int32)
+        + counts.shape[0]
+    )
+
+
 class _EngineBase:
-    """Shared canonicalisation, probe selection and cost accounting."""
+    """Shared probe selection, the exact tier, escalation and the rescore
+    tail."""
 
     # True for backends that score from the (possibly bf16/int8) bucket-major
     # pack rather than the fp32 doc-major corpus; the exact tier then routes
@@ -314,24 +350,6 @@ class _EngineBase:
         qw = weighted_query(q, w, self.index.spec)
         return self.search(qw, probes=probes, k=k, exclude=exclude)
 
-    def _canonical(self, qw, nav_query, exclude):
-        single = qw.ndim == 1
-        qw = jnp.atleast_2d(qw)
-        nav = qw if nav_query is None else jnp.atleast_2d(nav_query)
-        nq = qw.shape[0]
-        if exclude is None:
-            exclude = jnp.full((nq,), -1, jnp.int32)
-        exclude = jnp.broadcast_to(
-            jnp.atleast_1d(exclude), (nq,)
-        ).astype(jnp.int32)
-        return qw, nav, exclude, single
-
-    @staticmethod
-    def _finish(single, scores, ids, n_scored):
-        if single:
-            return scores[0], ids[0], n_scored[0]
-        return scores, ids, n_scored
-
     def _total_probes(self) -> int:
         """T·K — the budget at which pruned search degenerates to exact."""
         t, k_clusters = (int(x) for x in self.index.counts.shape)
@@ -345,28 +363,9 @@ class _EngineBase:
 
     def _flat_probes(self, nav, probes_t):
         """Navigate: (nq, P) flattened (t*K + cluster) probe list."""
-        leaders = self.index.leaders                       # (T, K, D)
-        k_clusters = leaders.shape[1]
-        lsims = jnp.einsum("tkd,qd->qtk", leaders, nav,
-                           precision=jax.lax.Precision.HIGHEST)
-        parts = []
-        for t, p in enumerate(probes_t):
-            if p == 0:
-                continue
-            _, top_c = jax.lax.top_k(lsims[:, t, :], p)
-            parts.append(top_c + t * k_clusters)
-        return jnp.concatenate(parts, axis=-1).astype(jnp.int32)
+        from .distributed import _navigate
 
-    def _n_scored(self, flat_probes):
-        """Fig-1 accounting: every member of a probed bucket is one distance
-        computation (dups across clusterings included — they really are
-        scored), plus the T*K leader comparisons."""
-        t, k_clusters = self.index.counts.shape
-        counts = self.index.counts.reshape(-1)
-        return (
-            jnp.sum(counts[flat_probes], axis=-1).astype(jnp.int32)
-            + t * k_clusters
-        )
+        return _navigate(self.index.leaders, nav, probes_t)
 
     def search_exact(self, qw, *, k, exclude=None, nav_query=None,
                      rescore=None):
@@ -417,7 +416,7 @@ class _EngineBase:
             )
         ladder = getattr(self.index, "ladder", None)
         total = self._total_probes()
-        qw2, nav, excl, single = self._canonical(qw, nav_query, exclude)
+        qw2, nav, excl, single = _canonical(qw, nav_query, exclude)
         p = min(int(probes), total)
         escalations = 0
         n_total = None
@@ -464,7 +463,7 @@ class _EngineBase:
             "probes": int(p),
             "predicted_recall": float(predicted),
         }
-        s, i, n_total = self._finish(single, s, i, n_total)
+        s, i, n_total = _finish(single, s, i, n_total)
         return s, i, n_total, info
 
     def _search_rescored(
@@ -486,13 +485,13 @@ class _EngineBase:
             raise ValueError(
                 f"rescore depth {rescore} must be >= k ({k})"
             )
-        qw2, nav, exclude, single = self._canonical(qw, nav_query, exclude)
+        qw2, nav, exclude, single = _canonical(qw, nav_query, exclude)
         s, ids, n_scored = self.search(
             qw2, probes=probes, k=rescore, exclude=exclude, nav_query=nav
         )
         with span(tracing.ENGINE_RESCORE):
             rs, ri, extra = self._rescore_candidates(qw2, ids, k)
-            return self._finish(single, rs, ri, n_scored + extra)
+            return _finish(single, rs, ri, n_scored + extra)
 
     def _rescore_candidates(self, qw, ids, k):
         """Exact fp32 re-rank of candidate ids — the rescore tail's scoring
@@ -544,7 +543,7 @@ class ReferenceEngine(_EngineBase):
                 nav_query=nav_query,
             )
         index = self.index
-        qw, nav, exclude, single = self._canonical(qw, nav_query, exclude)
+        qw, nav, exclude, single = _canonical(qw, nav_query, exclude)
         nq = qw.shape[0]
         probes_t = self._probes_t(probes)
         fn = functools.partial(
@@ -564,7 +563,7 @@ class ReferenceEngine(_EngineBase):
                 ep.reshape(-1, qchunk),
             ),
         )
-        return self._finish(
+        return _finish(
             single,
             scores.reshape(-1, k)[:nq],
             ids.reshape(-1, k)[:nq],
@@ -631,8 +630,9 @@ class FusedEngine(_EngineBase):
     Each device holds a bucket-major ``(T*K, B_l, D)`` pack of its row slice
     of every bucket (:meth:`ClusterPruneIndex.ensure_local_bucket_major`),
     in ``pack_dtype`` precision with per-``(shard, bucket)`` int8 scales. A
-    search navigates once on the replicated fp32 leaders and builds the
-    probe-dedup schedule once on device
+    search is one jitted program (:func:`_fused_search_fn`). It navigates
+    once on the fp32 leaders, replicated on the mesh, and builds the
+    probe-dedup schedule
     (:func:`~repro.kernels.bucket_score.ops.build_probe_schedule_device`):
     the union of a query tile's flat probe lists, each shared bucket once.
     Probed buckets are the same on every shard, so schedule and membership
@@ -640,8 +640,8 @@ class FusedEngine(_EngineBase):
     :func:`~repro.kernels.bucket_score.ops.bucket_score_tiled` over its
     blocks, one ``(QT, D)×(D, B_l)`` MXU matmul a scheduled bucket, and one
     ``all_gather`` of the per-shard top-k merges the answer
-    (:func:`~repro.core.distributed.distributed_bucket_score`). The same
-    flat probe tensor drives ``n_scored``.
+    (:func:`~repro.core.distributed._bucket_score_fn`). The same flat probe
+    tensor drives ``n_scored``.
 
     The mesh is ``mesh``/``shard_axes`` where given, else the pick's
     (:func:`pick_backend`): one device while the index fits it, all of the
@@ -651,8 +651,8 @@ class FusedEngine(_EngineBase):
     The schedule's static length ``S = pow2ceil(min(QT·P, n_buckets))``
     (:func:`~repro.kernels.bucket_score.ops.schedule_length`) pads each tile
     with slots no query probes; the kernel skips their grid steps. The
-    ``repro.engine.score`` span carries ``slots`` (``n_tiles · S``) and
-    ``shards``.
+    ``repro.engine.score`` span, around the program's launch, carries
+    ``slots`` (``n_tiles · S``) and ``shards``.
 
     Any corpus size shards: rows pad to ``ceil(n / shards)`` per shard with
     sentinel rows no bucket references. Mutations repack lazily on the first
@@ -691,9 +691,11 @@ class FusedEngine(_EngineBase):
 
     def _ensure_placed(self):
         """The shard-local pack ``(data, ids, scales, n_local)`` placed
-        shard-major on the mesh, repacked lazily on mutation: keyed on
+        shard-major on the mesh, and beside it ``self._navigation``: the
+        fp32 ``leaders (T, K, D)`` and the flat bucket ``counts (T·K,)``
+        replicated on it. All are made again lazily on mutation: keyed on
         ``index.version``, so the first search after an add/remove pays the
-        repack once and steady-state searches touch nothing. fp32 and bf16
+        repack once and steady-state searches move nothing. fp32 and bf16
         packs carry unit scales."""
         if self._pack_version == self.index.version:
             return self._placed
@@ -711,6 +713,9 @@ class FusedEngine(_EngineBase):
         if scales is None:
             scales = jnp.ones(ids.shape[:2], jnp.float32)
         scales = jax.device_put(scales, sh(axes, None))
+        self._navigation = jax.device_put(
+            (self.index.leaders, self.index.counts.reshape(-1)), sh()
+        )
         self._placed = (data, ids, scales, n_local)
         self._docs_sh = None
         self._pack_version = self.index.version
@@ -763,45 +768,70 @@ class FusedEngine(_EngineBase):
                 qw, probes=probes, k=k, rescore=rescore, exclude=exclude,
                 nav_query=nav_query,
             )
-        from ..kernels.bucket_score.ops import (
-            build_probe_schedule_device, schedule_length,
-        )
+        from ..kernels.bucket_score.ops import schedule_length
         from ..kernels.common import pad_to
-        from .distributed import distributed_bucket_score
+        from .distributed import _bucket_scorer
 
-        qw, nav, exclude, single = self._canonical(qw, nav_query, exclude)
         data, ids, scales, n_local = self._ensure_placed()
-        # Navigate ONCE: the flat probe tensor feeds the (replicated)
-        # schedule AND the n_scored accounting below.
-        with span(tracing.ENGINE_NAVIGATE):
-            flat = self._flat_probes(nav, self._probes_t(probes))
-        with span(tracing.ENGINE_SCHEDULE):
+        leaders, counts = self._navigation
+        with span(tracing.ENGINE_SCORE, shards=self.n_shards) as score_span:
+            probes_t = self._probes_t(probes)
+            nq = 1 if qw.ndim == 1 else int(qw.shape[0])
             qt = self.tile_for(k)
             if not self.query_tile:
                 # a small batch padded to a large tile would matmul and
                 # top-k mostly dead rows per scheduled bucket
-                qt = min(qt, pad_to(qw.shape[0], 8))
-            s_len = schedule_length(qt, int(flat.shape[1]),
-                                    int(ids.shape[1]))
-            sched, member = build_probe_schedule_device(
-                flat, query_tile=qt, s_len=s_len
+                qt = min(qt, pad_to(nq, 8))
+            s_len = schedule_length(qt, sum(probes_t), int(ids.shape[1]))
+            score_span.set_metadata(slots=max(1, -(-nq // qt)) * s_len)
+            scorer = _bucket_scorer(
+                self.mesh, self.shard_axes, data.shape, s_len, k=k,
+                n_local=n_local, interpret=self.interpret,
             )
-        with span(tracing.ENGINE_SCORE, shards=self.n_shards,
-                  slots=sched.size):
-            s, i = distributed_bucket_score(
-                self.mesh, data, ids, scales, qw, sched, member,
-                k=k, n_local=n_local, shard_axes=self.shard_axes,
-                exclude=exclude, interpret=self.interpret,
-            )
-            if s.shape[-1] < k:   # shards × schedule can't surface k
-                pad = k - s.shape[-1]
-                s = jnp.pad(s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
-                i = jnp.pad(i, ((0, 0), (0, pad)), constant_values=-1)
+            program = _fused_search_fn(scorer, probes_t, k, qt, s_len)
+            s, i, n_scored = program(data, ids, scales, leaders, counts, qw,
+                                     nav_query, exclude)
             # The merge leaves the answer replicated over the mesh. Hand it
             # on from the corpus's device: the field decomposition that
             # reads it next would otherwise copy the corpus to every chip.
             docs = self.index.docs
             if isinstance(docs, jax.Array) and len(docs.devices()) == 1:
                 s, i = jax.device_put((s, i), docs.sharding)
-            i = jnp.where(jnp.isfinite(s), i, -1)
-            return self._finish(single, s, i, self._n_scored(flat))
+            return s, i, n_scored
+
+
+@functools.lru_cache(maxsize=128)
+def _fused_search_fn(scorer, probes_t, k, query_tile, s_len):
+    """Build (once per static config) the fused engine's whole search, the
+    program ``jit_fused_search``: canonicalise the exclusions, navigate on
+    the replicated fp32 leaders, build the probe-dedup schedule, run
+    ``scorer`` (the cached ``shard_score_merge`` of
+    :func:`~repro.core.distributed._bucket_score_fn`: each shard's kernel,
+    the all-gather and the merge), pad the answer to ``k``, and count
+    ``n_scored`` from the same flat probe list.
+
+    One program, so a search launches once: the navigation's and the
+    schedule's small steps run on the device inside it instead of as eager
+    dispatches from the host. The key holds the scorer itself (mesh, axes,
+    ``k_out``, ``n_local``, ``interpret``), so a new scorer is a new
+    program; ``jit`` keys the rest on the argument shapes.
+    """
+    from ..kernels.bucket_score.ops import build_probe_schedule_device
+    from .distributed import _navigate
+
+    def fused_search(data, ids, scales, leaders, counts, qw, nav_query,
+                     exclude):
+        qw, nav, exclude, single = _canonical(qw, nav_query, exclude)
+        flat = _navigate(leaders, nav, probes_t)
+        sched, member = build_probe_schedule_device(
+            flat, query_tile=query_tile, s_len=s_len
+        )
+        s, i = scorer(data, ids, scales, qw, sched, member, exclude)
+        if s.shape[-1] < k:   # shards × schedule can't surface k
+            pad = k - s.shape[-1]
+            s = jnp.pad(s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+            i = jnp.pad(i, ((0, 0), (0, pad)), constant_values=-1)
+        i = jnp.where(jnp.isfinite(s), i, -1)
+        return _finish(single, s, i, _n_scored(counts, flat))
+
+    return jax.jit(fused_search)

@@ -76,8 +76,8 @@ microsecond or two each when no profiler runs):
                           replica, n); holds the retriever's spans:
       repro.search.batch    Retriever.search (n, groups), and within it
                             .prepare, .engine, .wait, .fetch, .assemble;
-                            repro.engine.navigate/.schedule/.score/.rescore
-                            inside .engine
+                            repro.engine.score (the fused search's one
+                            program launched) and .rescore inside .engine
     repro.serve.respond   event loop: tickets stamped and resolved
                           (dispatch, the same number as its call)
 

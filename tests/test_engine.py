@@ -740,25 +740,162 @@ def test_escalation_validates_floor(built_index, engine_corpus):
 
 
 # ------------------------------------------------- fused over an explicit mesh
-def test_sharded_navigation_runs_once(case_engine, built_index, engine_corpus):
-    """The engine computes leader top-p ONCE per search: the same flat
-    probe tensor feeds the replicated probe-dedup schedule and the
-    n_scored accounting."""
+def test_sharded_navigation_runs_once(case_engine, built_index, engine_corpus,
+                                      tmp_path):
+    """A fused search is one device program: navigation, the probe
+    schedule, the scorer and ``n_scored`` compile together, so a search at a
+    new shape leaves exactly one ``repro.compile`` marker, inside its
+    ``repro.engine.score`` span, and a repeat at that shape leaves none."""
+    from jax.profiler import ProfileData
+
+    from repro import tracing
+
     docs, _ = engine_corpus
     eng = case_engine(built_index, "sharded", interpret=True)
-    calls = {"n": 0}
-    orig = type(eng)._flat_probes
-
-    def counting(self, nav, probes_t):
-        calls["n"] += 1
-        return orig(self, nav, probes_t)
-
+    eng.search(docs[:8], probes=6, k=10)        # pack and place first
+    first, repeat = docs[100:113], docs[200:213]
+    jax.block_until_ready((first, repeat))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        type(eng)._flat_probes = counting
-        eng.search(docs[20:28], probes=6, k=10)
+        for qw in (first, repeat):       # 13 queries at 7 probes: new shape
+            with tracing.span("test.search"):
+                jax.block_until_ready(eng.search(qw, probes=7, k=10))
     finally:
-        type(eng)._flat_probes = orig
-    assert calls["n"] == 1
+        jax.profiler.stop_trace()
+    data = ProfileData.from_file(
+        str(sorted(tmp_path.rglob("*.xplane.pb"))[-1]))
+    events = [(li, e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for plane in data.planes if plane.name == "/host:CPU"
+              for li, line in enumerate(plane.lines) for e in line.events]
+    marks = [e for e in events if e[1] == tracing.COMPILE]
+
+    def marks_in(name):
+        spans = sorted((e for e in events if e[1] == name),
+                       key=lambda e: e[2])
+        return [sum(m[0] == sp[0] and sp[2] <= m[2] and m[3] <= sp[3]
+                    for m in marks) for sp in spans]
+
+    assert marks_in(tracing.ENGINE_SCORE) == [1, 0]
+    assert marks_in("test.search") == [1, 0]
+
+
+def _eager_search(eng, qw, *, probes, k, exclude=None):
+    """The fused search as the engine launched it step by step from the
+    host before it was one program: navigation, the device schedule, the
+    shard-local scorer, the pad to ``k`` and ``n_scored``, each its own
+    dispatch."""
+    from repro.core.distributed import distributed_bucket_score
+    from repro.core.engine import _n_scored
+    from repro.kernels.bucket_score.ops import (
+        build_probe_schedule_device, schedule_length,
+    )
+    from repro.kernels.common import pad_to
+
+    nq = qw.shape[0]
+    if exclude is None:
+        exclude = jnp.full((nq,), -1, jnp.int32)
+    data, ids, scales, n_local = eng._ensure_placed()
+    flat = eng._flat_probes(qw, eng._probes_t(probes))
+    qt = min(eng.tile_for(k), pad_to(nq, 8))
+    s_len = schedule_length(qt, int(flat.shape[1]), int(ids.shape[1]))
+    sched, member = build_probe_schedule_device(flat, query_tile=qt,
+                                                s_len=s_len)
+    s, i = distributed_bucket_score(
+        eng.mesh, data, ids, scales, qw, sched, member, k=k,
+        n_local=n_local, shard_axes=eng.shard_axes, exclude=exclude,
+        interpret=eng.interpret,
+    )
+    if s.shape[-1] < k:
+        pad = k - s.shape[-1]
+        s = jnp.pad(s, ((0, 0), (0, pad)), constant_values=-jnp.inf)
+        i = jnp.pad(i, ((0, 0), (0, pad)), constant_values=-1)
+    i = jnp.where(jnp.isfinite(s), i, -1)
+    return s, i, _n_scored(eng.index.counts.reshape(-1), flat)
+
+
+# (probes, exclude): one probe, a middle budget and all T·K = 48 buckets, on
+# a ragged batch of 13 queries, with and without self-exclusion
+PARITY_CASES = [(p, ex) for p in (1, 6, 48) for ex in (False, True)]
+
+
+def _parity_bytes(eng, docs, probes, exclude):
+    """``{side: [scores, ids, n_scored] as bytes}`` of the one-program
+    search and of :func:`_eager_search` on the same engine and queries."""
+    qw = docs[100:113]
+    ex = jnp.arange(100, 113, dtype=jnp.int32) if exclude else None
+    sides = {
+        "program": eng.search(qw, probes=probes, k=10, exclude=ex),
+        "eager": _eager_search(eng, qw, probes=probes, k=10, exclude=ex),
+    }
+    return {side: [np.asarray(a).tobytes().hex() for a in out]
+            for side, out in sides.items()}
+
+
+PARITY_FOUR = r"""
+import importlib.util, json, sys
+import jax
+from repro.core import ClusterPruneIndex, FieldSpec, get_engine
+from repro.core import normalize_fields
+
+spec_ = importlib.util.spec_from_file_location("engine_tests", sys.argv[1])
+tests = importlib.util.module_from_spec(spec_)
+spec_.loader.exec_module(tests)
+assert jax.device_count() == 4
+spec = FieldSpec(names=("a", "b", "c"), dims=(32, 32, 64))
+docs = normalize_fields(
+    jax.random.normal(jax.random.PRNGKey(7), (640, spec.total_dim)), spec)
+index = ClusterPruneIndex.build(docs, spec, 16, n_clusterings=3,
+                                method="fpf", key=jax.random.PRNGKey(0))
+eng = get_engine(index, "fused")
+assert eng.n_shards == 4
+print(json.dumps({f"{p}-{ex}": tests._parity_bytes(eng, docs, p, ex)
+                  for p, ex in tests.PARITY_CASES}))
+"""
+
+
+@pytest.fixture(scope="module")
+def four_device_parity():
+    """:func:`_parity_bytes` of every parity case on four virtual CPU
+    devices, from one subprocess."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "PYTHONPATH": str(root / "src")}
+    out = subprocess.run(
+        [sys.executable, "-c", PARITY_FOUR, str(Path(__file__).resolve())],
+        capture_output=True, text=True, timeout=600, env=env,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    import json
+
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("probes,exclude", PARITY_CASES)
+def test_one_program_search_matches_eager_composition(
+    request, built_index, engine_corpus, devices, probes, exclude
+):
+    """The fused search as one jitted program returns bit-equal scores, ids
+    and ``n_scored`` to the same steps launched one by one, on one device
+    and row-sharded over four."""
+    if devices == 1:
+        docs, _ = engine_corpus
+        eng = get_engine(built_index, "fused")
+        got = _parity_bytes(eng, docs, probes, exclude)
+    else:
+        got = request.getfixturevalue("four_device_parity")[
+            f"{probes}-{exclude}"]
+    for name, a, b in zip(("scores", "ids", "n_scored"), got["program"],
+                          got["eager"]):
+        assert a == b, name
 
 
 def test_sharded_lazy_repack_on_mutation(case_engine, engine_corpus):

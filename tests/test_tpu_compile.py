@@ -173,3 +173,46 @@ def test_sharded_bucket_score_compiles(one_chip, topo):
     # each device holds a quarter of the pack, never the whole of it
     pack_bytes = n_shards * T * k_clusters * b_l * D * 4
     assert compiled.memory_analysis().argument_size_in_bytes < pack_bytes / 2
+
+
+@pytest.mark.parametrize("corpus,n_docs,n_shards",
+                         [("ts1", 53_722, 1), ("ts2", 100_000, 4)])
+def test_fused_search_compiles(one_chip, topo, corpus, n_docs, n_shards):
+    """The fused engine's whole search as the one program it launches:
+    navigation on the replicated fp32 leaders, the probe-dedup schedule,
+    each shard's kernel, the all-gather and merge, and ``n_scored``. TS1's
+    fp32 pack on one chip, TS2's row-sharded over four, at the served batch
+    of 128 with self-exclusion."""
+    from repro.core.distributed import _bucket_scorer
+    from repro.core.engine import _fused_search_fn
+
+    mesh = Mesh(np.asarray(topo.devices[:n_shards]), ("data",))
+    k_clusters, b = CORPORA[corpus]
+    b_l = pad_to(-(-b // n_shards), 8)
+    n_buckets = T * k_clusters
+    nq = 128
+    qt = min(pick_query_tile(D, b_l, k_pad=pad_to(K_TOP, 8)), nq)
+    s_len = schedule_length(qt, PROBES, n_buckets)
+    scorer = _bucket_scorer(
+        mesh, ("data",), (n_shards, n_buckets, b_l, D), s_len, k=K_TOP,
+        n_local=-(-n_docs // n_shards), interpret=False,
+    )
+    program = _fused_search_fn(scorer, (4, 4, 4), K_TOP, qt, s_len)
+    S = lambda shape, dtype, *spec: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+    compiled = program.lower(
+        S((n_shards, n_buckets, b_l, D), jnp.float32, "data"),
+        S((n_shards, n_buckets, b_l), jnp.int32, "data"),
+        S((n_shards, n_buckets), jnp.float32, "data"),
+        S((T, k_clusters, D), jnp.float32),
+        S((n_buckets,), jnp.int32),
+        S((nq, D), jnp.float32),
+        None,
+        S((nq,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("all-gather" in text) == (n_shards > 1)
+    pack_bytes = n_shards * n_buckets * b_l * D * 4
+    assert compiled.memory_analysis().argument_size_in_bytes < (
+        pack_bytes / 2 if n_shards > 1 else 16 * 2**30)
